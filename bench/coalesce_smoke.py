@@ -102,7 +102,7 @@ def main() -> None:
 
     from ratelimiter_tpu.utils.compile_cache import enable_compile_cache
 
-    enable_compile_cache(os.path.join(_REPO, ".jax_cache"))
+    enable_compile_cache()
     small = os.environ.get("BENCH_SCALE", "small") == "small"
     n = 1 << 15 if small else 1 << 18
     reps = 2 if small else 4

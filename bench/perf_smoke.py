@@ -1,7 +1,7 @@
 """Fast perf smokes (CPU, small shapes) — CI guards.
 
 ISSUE r6: the virtual-mesh scaling curve silently anti-scaled for two
-rounds (19.5M/s at 1 shard -> 4.3M/s at 8 in BENCH_r05) because nothing
+rounds (19.5M/s at 1 shard -> 4.3M/s at 8 in r05, before PR 1) because nothing
 failed when the sharding machinery regressed.  This smoke runs the TB
 Zipf stream at EVERY shard count of the virtual mesh (1/2/4/8) and
 asserts MONOTONICITY (ISSUE r8): each point must reach at least
@@ -49,10 +49,12 @@ MARGIN = 0.9
 #: ...and the full curve must not sag: 8 shards vs 1 shard.
 MARGIN_END = 0.95
 POINTS = (1, 2, 4, 8)
+# Both child modes measure the CPU backend (a virtual 8-device mesh);
+# they are pinned at spawn.
+_CPU_CHILD_ENV = dict(os.environ, JAX_PLATFORMS="cpu")
 
 
 def run_point(n_shards: int) -> None:
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (
@@ -70,7 +72,7 @@ def run_point(n_shards: int) -> None:
     from ratelimiter_tpu.storage import TpuBatchedStorage
     from ratelimiter_tpu.utils.compile_cache import enable_compile_cache
 
-    enable_compile_cache(os.path.join(_REPO, ".jax_cache"))
+    enable_compile_cache()
     cfg = RateLimitConfig(max_permits=100, window_ms=60_000,
                           refill_rate=50.0)
     clock = lambda: 100_000  # noqa: E731 — frozen: identical decisions
@@ -103,7 +105,6 @@ def run_point(n_shards: int) -> None:
 def run_relay_election() -> None:
     """Relay-election smoke: elected path never slower than XLA on this
     (CPU) backend, and cached election artifacts self-consistent."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     os.environ.setdefault("RATELIMITER_RATE_PROBE", "0")
 
     import functools
@@ -121,11 +122,11 @@ def run_relay_election() -> None:
     from ratelimiter_tpu.ops import relay
     from ratelimiter_tpu.ops.pallas import election, relay_step
     from ratelimiter_tpu.utils.compile_cache import (
-        default_cache_dir,
+        cache_dir,
         enable_compile_cache,
     )
 
-    enable_compile_cache(os.path.join(_REPO, ".jax_cache"))
+    enable_compile_cache()
     out = {"smoke": "relay_election"}
 
     # 1. The fused Pallas path must not be live on a plain CPU backend.
@@ -174,7 +175,7 @@ def run_relay_election() -> None:
     # 3. Cached election artifacts: verdict == what the recorded A/B
     # implies.  (env-off/interpret records carry no timings — skipped.)
     bad_records = []
-    base = jax.config.jax_compilation_cache_dir or default_cache_dir()
+    base = cache_dir()
     for path in sorted(glob.glob(os.path.join(
             base, "pallas_elect_*.json"))):
         try:
@@ -210,7 +211,8 @@ def main() -> int:
     for s in POINTS:
         proc = subprocess.run(
             [sys.executable, os.path.abspath(__file__), "--point", str(s)],
-            capture_output=True, timeout=540, text=True, cwd=_REPO)
+            capture_output=True, timeout=540, text=True, cwd=_REPO,
+            env=_CPU_CHILD_ENV)
         if proc.returncode != 0 or not proc.stdout.strip():
             print(f"PERF SMOKE FAILED: point {s} rc={proc.returncode} "
                   f"stderr={proc.stderr[-400:]!r}", file=sys.stderr)
@@ -226,7 +228,8 @@ def main() -> int:
     # caches must resolve fresh, exactly as a service boot would).
     proc = subprocess.run(
         [sys.executable, os.path.abspath(__file__), "--relay-election"],
-        capture_output=True, timeout=540, text=True, cwd=_REPO)
+        capture_output=True, timeout=540, text=True, cwd=_REPO,
+            env=_CPU_CHILD_ENV)
     relay_ok = proc.returncode == 0 and bool(proc.stdout.strip())
     try:
         relay_out = json.loads(proc.stdout.strip().splitlines()[-1])

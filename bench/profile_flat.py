@@ -11,7 +11,6 @@ import os
 import sys
 import time
 
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "/root/repo/.jax_cache")
 if "--noblock" in sys.argv:
     os.environ["RATELIMITER_BLOCK_SCATTER"] = "0"
 

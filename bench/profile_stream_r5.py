@@ -26,7 +26,7 @@ def main() -> None:
 
     from ratelimiter_tpu.utils.compile_cache import enable_compile_cache
 
-    enable_compile_cache(os.path.join(_REPO, ".jax_cache"))
+    enable_compile_cache()
 
     from ratelimiter_tpu import RateLimitConfig
     from ratelimiter_tpu.algorithms import (

@@ -9,8 +9,9 @@ changed kernel can flip a winner, and a stale verdict silently pins the
 loser.  This sweep:
 
 1. snapshots then DELETES every persisted verdict (``pallas_elect_*``)
-   and device-rate probe (``device_rates_*``) under the repo cache and
-   the user cache, so the next dispatch of each path re-measures;
+   and device-rate probe (``device_rates_*``) in the compile cache
+   directory (utils/compile_cache.py), so the next dispatch of each
+   path re-measures;
 2. re-runs ``bench/sharded_scaling.py`` (a fresh storage per shard
    count re-elects ``sharded.route_elect`` at runtime — that election
    is never disk-cached);
@@ -42,33 +43,23 @@ sys.path.insert(0, _REPO)
 ROUND = 6
 
 
-def _cache_dirs() -> list:
-    """Every directory a verdict or rate probe may persist under."""
-    from ratelimiter_tpu.utils.compile_cache import default_cache_dir
-
-    dirs = [os.path.join(_REPO, ".jax_cache"), default_cache_dir()]
-    extra = os.environ.get("RATELIMITER_REELECT_EXTRA_DIR")
-    if extra:
-        dirs.append(extra)
-    return [d for d in dirs if os.path.isdir(d)]
-
-
 def clear_verdicts() -> dict:
     """Snapshot + delete persisted election/rate files; return the
     snapshot keyed by filename (the pre-clear verdicts, for diffing)."""
+    from ratelimiter_tpu.utils.compile_cache import cache_dir
+
     prior: dict = {}
     removed = []
-    for d in _cache_dirs():
-        for pat in ("pallas_elect_*.json", "device_rates_*.json"):
-            for path in sorted(glob.glob(os.path.join(d, pat))):
-                name = os.path.basename(path)
-                try:
-                    with open(path) as fh:
-                        prior[name] = json.load(fh)
-                except Exception as exc:  # noqa: BLE001 — record, still clear
-                    prior[name] = {"unreadable": str(exc)}
-                os.unlink(path)
-                removed.append(path)
+    for pat in ("pallas_elect_*.json", "device_rates_*.json"):
+        for path in sorted(glob.glob(os.path.join(cache_dir(), pat))):
+            name = os.path.basename(path)
+            try:
+                with open(path) as fh:
+                    prior[name] = json.load(fh)
+            except Exception as exc:  # noqa: BLE001 — record, still clear
+                prior[name] = {"unreadable": str(exc)}
+            os.unlink(path)
+            removed.append(path)
     return {"prior_verdicts": prior, "removed": removed}
 
 
@@ -82,8 +73,8 @@ def refresh_elections() -> dict:
     cache: the pallas settle (micro / block_scatter / relay_fused — a
     no-op off-TPU), the device-journal placement (measures on every
     backend), and the device step-rate probe the chunk scheduler elects
-    plans from."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    plans from.  Runs in a child (``--refresh``): this parent stays off
+    JAX, so each child in turn can hold the chip."""
     import jax
 
     from ratelimiter_tpu.engine import device_rates
@@ -92,7 +83,7 @@ def refresh_elections() -> dict:
     from ratelimiter_tpu.replication import log as rlog
     from ratelimiter_tpu.utils.compile_cache import enable_compile_cache
 
-    enable_compile_cache(os.path.join(_REPO, ".jax_cache"))
+    enable_compile_cache()
     election.reset_for_tests()       # drop in-process memos too
     device_rates._mem_cache.clear()
     pallas_pkg.settle_all()          # TPU: micro/block_scatter/relay_fused
@@ -104,11 +95,12 @@ def refresh_elections() -> dict:
                              if not k.startswith("_")}}
 
 
-def _run(cmd_path: str, timeout: int) -> dict:
-    """Run one bench script as a subprocess; parse its last JSON line."""
-    env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
-    proc = subprocess.run([sys.executable, cmd_path], capture_output=True,
+def _run(cmd: list, timeout: int, cpu: bool = False) -> dict:
+    """Run one child to its end and parse its last JSON line.  Children
+    run one at a time and this process never imports JAX, so a child
+    may hold the chip; ``cpu`` pins a virtual-mesh child at spawn."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu") if cpu else None
+    proc = subprocess.run([sys.executable, *cmd], capture_output=True,
                           timeout=timeout, text=True, cwd=_REPO, env=env)
     out = {"rc": proc.returncode,
            "tail": (proc.stdout + proc.stderr)[-2000:]}
@@ -127,14 +119,21 @@ def main() -> None:
                         help="clear verdicts + rerun sharded_scaling only "
                              "(no bench.py round, no BENCH_r06.json)")
     parser.add_argument("--bench-timeout", type=int, default=3600)
+    parser.add_argument("--refresh", action="store_true",
+                        help=argparse.SUPPRESS)  # the child mode
     args = parser.parse_args()
+    if args.refresh:
+        print(json.dumps(refresh_elections()))
+        return
 
     t0 = time.time()
     cleared = clear_verdicts()
     print(f"cleared {len(cleared['removed'])} persisted verdict/rate "
-          f"file(s) across {len(_cache_dirs())} cache dir(s)",
-          file=sys.stderr)
-    refreshed = refresh_elections()
+          "file(s)", file=sys.stderr)
+    refresh = _run([os.path.abspath(__file__), "--refresh"], timeout=900)
+    if "parsed" not in refresh:
+        raise SystemExit(f"election refresh failed: {refresh['tail']}")
+    refreshed = refresh["parsed"]
     print(f"re-measured elections on {refreshed['platform']}: "
           f"{sorted(refreshed['verdicts'])}", file=sys.stderr)
 
@@ -142,8 +141,8 @@ def main() -> None:
     # clear for this one, the rerun IS the refresh.
     print("re-running sharded_scaling (route re-election)...",
           file=sys.stderr)
-    sharded = _run(os.path.join(_REPO, "bench", "sharded_scaling.py"),
-                   timeout=900)
+    sharded = _run([os.path.join(_REPO, "bench", "sharded_scaling.py")],
+                   timeout=900, cpu=True)
     if args.skip_bench:
         print(json.dumps({"cleared": len(cleared["removed"]),
                           "elections": sorted(refreshed["verdicts"]),
@@ -154,7 +153,7 @@ def main() -> None:
     # (the files we just deleted force a fresh measurement) and writes
     # the refreshed verdicts into BENCH_DETAIL.json.
     print("running bench.py (fresh election round)...", file=sys.stderr)
-    bench = _run(os.path.join(_REPO, "bench.py"),
+    bench = _run([os.path.join(_REPO, "bench.py")],
                  timeout=args.bench_timeout)
 
     # Verdicts of record: the force-resolved set, overlaid with

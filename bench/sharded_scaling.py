@@ -15,7 +15,7 @@ warmup pass (one-super-batch warmup left XLA compiles inside the timed
 region — they were most of the recorded r2 "overhead") and O(n) C
 routing (rl_shard_route: hash + stable counting sort in one pass,
 replacing a numpy hash + argsort that was 60% of the warm chunk cost).
-r8 removed the remaining inversion (BENCH_r05: 19.5M -> 4.3M/s from
+r8 removed the remaining inversion (r05, before PR 1: 19.5M -> 4.3M/s from
 1 -> 8 shards): the per-chunk mesh-wide shard_map dispatch — every
 shard barriered on the slowest sibling's layout, the multi-device
 launch rendezvoused all devices, lanes padded to the busiest shard —

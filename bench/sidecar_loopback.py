@@ -219,7 +219,7 @@ def main() -> None:
     from ratelimiter_tpu.storage import TpuBatchedStorage
     from ratelimiter_tpu.utils.compile_cache import enable_compile_cache
 
-    enable_compile_cache(os.path.join(_REPO, ".jax_cache"))
+    enable_compile_cache()
     small = os.environ.get("BENCH_SCALE", "small") == "small"
     reps = 40 if small else 200
 

@@ -16,7 +16,9 @@ per (platform, device kind) and cached
   so later processes skip the probe entirely.
 
 ``RATELIMITER_RATE_PROBE=0`` disables probing (the v5e fallback
-constants below are used); probing also falls back on any error.
+constants below are used; tests/conftest.py sets it).  Only that switch
+serves them: a probe that fails raises, since an assumed rate for a
+device nobody measured would steer every election.
 Rates are returned as a dict
 ``{"s_per_lane", "s_per_unique_sorted", "s_per_unique_unsorted"}``.
 The probed artifact additionally carries ``probed_at_ms`` and the
@@ -44,16 +46,9 @@ _mem_cache: Dict[str, Dict] = {}
 
 
 def _cache_path(platform: str, kind: str) -> Optional[str]:
-    try:
-        import jax
+    from ratelimiter_tpu.utils.compile_cache import cache_dir
 
-        base = jax.config.jax_compilation_cache_dir
-    except Exception:  # noqa: BLE001
-        base = None
-    if not base:
-        from ratelimiter_tpu.utils.compile_cache import default_cache_dir
-
-        base = default_cache_dir()
+    base = cache_dir()
     safe_kind = "".join(ch if ch.isalnum() else "_" for ch in kind)[:40]
     return os.path.join(base, f"device_rates_{platform}_{safe_kind}.json")
 
@@ -163,15 +158,12 @@ def _probe() -> Dict[str, float]:
 
 def get_device_rates() -> Dict:
     """Rates for the default jax backend, probing + caching as
-    documented in the module docstring.  Never raises."""
-    try:
-        import jax
+    documented in the module docstring."""
+    import jax
 
-        dev = jax.devices()[0]
-        platform = dev.platform
-        kind = getattr(dev, "device_kind", platform)
-    except Exception:  # noqa: BLE001 — no backend at all
-        return dict(FALLBACK_RATES, source="fallback")
+    dev = jax.devices()[0]
+    platform = dev.platform
+    kind = getattr(dev, "device_kind", platform)
     key = f"{platform}/{kind}"
     hit = _mem_cache.get(key)
     if hit is not None:
@@ -194,13 +186,8 @@ def get_device_rates() -> Dict:
                 return rates
         except Exception:  # noqa: BLE001 — corrupt cache: re-probe
             pass
-    try:
-        rates = dict(_probe(), source="probe", device=key,
-                     probed_at_ms=int(time.time() * 1000))
-    except Exception:  # noqa: BLE001 — probe failed: fall back
-        rates = dict(FALLBACK_RATES, source="fallback", device=key)
-        _mem_cache[key] = rates
-        return rates
+    rates = dict(_probe(), source="probe", device=key,
+                 probed_at_ms=int(time.time() * 1000))
     _mem_cache[key] = rates
     if path:
         try:

@@ -54,7 +54,7 @@ _MIN_BATCH = 256
 # Micro-batch floor (r6): interactive traffic through the micro-batcher
 # produces 1-100-request batches, and padding them to 256 lanes made the
 # device step ~0.7 ms on the CPU backend — most of the local-SLO p50 miss
-# (BENCH_r05 latency_slo_local: p50 1558 us vs the 1000 us target).
+# (r05, before PR 1, latency_slo_local: p50 1558 us vs the 1000 us target).
 # Small batches now bucket at {32, 64, 128} before joining the pow2
 # ladder; three extra compile shapes, device step cost proportional to
 # lanes.  Streams never see these shapes (their chunks are >= 2^19).
@@ -172,10 +172,9 @@ class DeviceEngine:
         self._relay_resident = {}  # (algo, out_dtype name, sorted) -> jitted step
         self._sw_peek = jax.jit(sw_peek_p)
         self._tb_peek = jax.jit(tb_peek_p)
-        # Settle the Pallas probes NOW, before any step kernel compiles:
-        # a probe firing lazily inside another program's lowering nests a
-        # second remote compile on toolchains that cannot serve one, and
-        # the resulting failure would stick as a permanent fallback.
+        # Settle the Pallas probes NOW, before any step kernel compiles,
+        # so a probe never compiles nested inside another program's
+        # lowering, and a probe failure on a TPU raises here, at init.
         from ratelimiter_tpu.ops import pallas as pallas_kernels
 
         pallas_kernels.settle_all()

@@ -5,7 +5,10 @@ vectorized batch assignment, which is what makes the host keep up with the
 device: one C call maps a whole micro-batch of keys to slots.
 
 The shared library is built on demand with the repo Makefile (g++ is in the
-image; pybind11 is not, hence the C ABI + ctypes).  If compilation is
+image; pybind11 is not, hence the C ABI + ctypes).  A library on disk is
+used only when its build key — a content hash of the source, the Makefile,
+the build variables and this host's CPU (the default build is
+``-march=native``) — matches; otherwise it is rebuilt.  If compilation is
 impossible the caller falls back to the Python index — behavior is
 identical, only slower (tested equivalent in tests/test_native_index.py).
 """
@@ -14,6 +17,8 @@ from __future__ import annotations
 
 import contextlib
 import ctypes
+import fcntl
+import hashlib
 import os
 import subprocess
 import threading
@@ -30,6 +35,61 @@ _lib = None
 _lib_failed = False
 
 
+def _cpu_signature() -> bytes:
+    try:
+        with open("/proc/cpuinfo", "rb") as fh:
+            return b"".join(line for line in fh
+                            if line.startswith((b"model name", b"flags")))
+    except OSError:
+        import platform
+
+        return platform.processor().encode()
+
+
+def build_key(source: str) -> str:
+    """Content hash a library must have been built from: its source, the
+    Makefile, the build variables and the CPU the default
+    ``-march=native`` build targets."""
+    native = os.path.abspath(_NATIVE_DIR)
+    h = hashlib.sha256()
+    for name in (source, "Makefile"):
+        with open(os.path.join(native, name), "rb") as fh:
+            h.update(fh.read())
+    for var in ("CXX", "ARCH", "CXXFLAGS"):
+        h.update(f"{var}={os.environ.get(var, '')}".encode())
+    h.update(_cpu_signature())
+    return h.hexdigest()
+
+
+def _ensure_built(lib_path: str, source: str) -> None:
+    """Rebuild ``lib_path`` unless its stamp records the current build
+    key.  A file lock serializes concurrent builders (test workers); the
+    Makefile renames the finished library into place."""
+    native = os.path.abspath(_NATIVE_DIR)
+    stamp = lib_path + ".buildkey"
+    key = build_key(source)
+
+    def current() -> bool:
+        try:
+            with open(stamp, encoding="ascii") as fh:
+                return os.path.exists(lib_path) and fh.read() == key
+        except OSError:
+            return False
+
+    if current():
+        return
+    with open(os.path.join(native, ".build.lock"), "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        if current():
+            return
+        subprocess.run(["make", "-B", "-C", native,
+                        os.path.basename(lib_path)],
+                       check=True, capture_output=True, timeout=120)
+        with open(stamp + ".tmp", "w", encoding="ascii") as fh:
+            fh.write(key)
+        os.replace(stamp + ".tmp", stamp)
+
+
 def _load_library():
     global _lib, _lib_failed
     if _lib is not None or _lib_failed:
@@ -38,32 +98,21 @@ def _load_library():
         if _lib is not None or _lib_failed:
             return _lib
         try:
-            # Rebuild when the source is newer than the .so (a stale
-            # library would silently miss newer entry points).  A failed
-            # build — e.g. a deployment with a prebuilt .so but no
-            # toolchain — falls through to loading the existing library.
-            src = os.path.join(os.path.abspath(_NATIVE_DIR), "slot_index.cpp")
-            stale = (not os.path.exists(_LIB_PATH)
-                     or (os.path.exists(src) and os.path.getmtime(src)
-                         > os.path.getmtime(_LIB_PATH)))
-            if stale:
-                try:
-                    subprocess.run(
-                        ["make", "-C", os.path.abspath(_NATIVE_DIR)],
-                        check=True, capture_output=True, timeout=120)
-                except Exception as exc:  # noqa: BLE001
-                    if not os.path.exists(_LIB_PATH):
-                        raise
-                    # A symbol-complete but semantically outdated library
-                    # would load silently otherwise; give operators a signal
-                    # that the binary predates the source.
-                    import warnings
+            try:
+                _ensure_built(_LIB_PATH, "slot_index.cpp")
+            except Exception as exc:  # noqa: BLE001
+                # A deployment with a prebuilt .so but no toolchain (the
+                # Dockerfile's runtime stage) loads what it was shipped,
+                # with a signal that it was not checked against the source.
+                if not os.path.exists(_LIB_PATH):
+                    raise
+                import warnings
 
-                    warnings.warn(
-                        f"native slot index rebuild failed ({exc!r}); "
-                        f"loading possibly STALE {_LIB_PATH} — rebuild "
-                        "with `make -C native` to match the source",
-                        RuntimeWarning, stacklevel=2)
+                warnings.warn(
+                    f"native slot index build failed ({exc!r}); loading "
+                    f"{_LIB_PATH}, which was not checked against the "
+                    "source — rebuild with `make -C native`",
+                    RuntimeWarning, stacklevel=2)
             lib = ctypes.CDLL(_LIB_PATH)
             _bind(lib)  # missing symbol (stale prebuilt .so) => fallback
         except Exception:  # noqa: BLE001 — any failure => Python fallback
@@ -218,15 +267,7 @@ def _load_strpack():
         if _strpack is not None or _strpack_failed:
             return _strpack
         try:
-            src = os.path.join(os.path.abspath(_NATIVE_DIR), "str_pack.cpp")
-            stale = (not os.path.exists(_STRPACK_PATH)
-                     or (os.path.exists(src) and os.path.getmtime(src)
-                         > os.path.getmtime(_STRPACK_PATH)))
-            if stale:
-                subprocess.run(
-                    ["make", "-C", os.path.abspath(_NATIVE_DIR),
-                     "libstrpack.so"],
-                    check=True, capture_output=True, timeout=120)
+            _ensure_built(_STRPACK_PATH, "str_pack.cpp")
             # PyDLL, not CDLL: these functions touch Python objects, so
             # the GIL must stay held across the call.
             lib = ctypes.PyDLL(_STRPACK_PATH)

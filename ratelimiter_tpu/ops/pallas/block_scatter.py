@@ -49,11 +49,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:  # jax >= 0.6 exports the context manager at top level
-    enable_x64 = jax.enable_x64
-except AttributeError:  # older jax: experimental API, same semantics
-    from jax.experimental import enable_x64
-
 T = 256          # state rows per block; S must divide by this
 
 _FLAG = os.environ.get("RATELIMITER_BLOCK_SCATTER", "1") == "1"
@@ -157,7 +152,7 @@ def scatter_rows(state, sorted_slots, write_mask, rows,
     # under jax_enable_x64 the grid/BlockSpec index plumbing emits i64
     # index arithmetic that crashes the TPU compiler outright (any
     # grid-ful pallas_call does, even a block copy — found on v5e).
-    with enable_x64(False):
+    with jax.enable_x64(False):
         key = jnp.where(write_mask, sorted_slots, jnp.int32(s_rows))
         ops = jax.lax.sort(
             (key,) + tuple(rows[:, j] for j in range(lanes)), num_keys=1)
@@ -188,7 +183,7 @@ def scatter_rows_presorted(state, sorted_slots, write_mask, rows,
     if interpret is None:
         interpret = _INTERPRET
     s_rows, lanes = state.shape
-    with enable_x64(False):
+    with jax.enable_x64(False):
         # Masked lanes are at the tail, so mapping them to the sentinel
         # (s_rows) preserves ascending order.
         key = jnp.where(write_mask, sorted_slots, jnp.int32(s_rows))
@@ -219,6 +214,13 @@ def _probe() -> bool:
     """One-time self-check on this platform: tiny scatter vs XLA truth."""
     global _probe_ok
     if _probe_ok is None:
+        from ratelimiter_tpu.ops.pallas import (
+            probe_failed,
+            refuse_interpret_on_tpu,
+        )
+
+        refuse_interpret_on_tpu("block_scatter", _INTERPRET,
+                                "RATELIMITER_BLOCK_SCATTER_INTERPRET")
         try:
             rng = np.random.default_rng(7)
             s = jnp.asarray(rng.integers(0, 1 << 30, (2 * T, 3), np.int32))
@@ -230,9 +232,15 @@ def _probe() -> bool:
                 jnp.asarray(rows), interpret=_INTERPRET))
             want = np.asarray(s).copy()
             want[slots[mask]] = rows[mask]
-            _probe_ok = bool((got == want).all())
-        except Exception:  # noqa: BLE001 — any lowering failure => fallback
-            _probe_ok = False
+            ok = bool((got == want).all())
+        except Exception as exc:  # noqa: BLE001 — verdict below
+            ok = probe_failed("block_scatter",
+                              f"{type(exc).__name__}: {exc}")
+        else:
+            if not ok:
+                ok = probe_failed("block_scatter",
+                                  "mismatch against the XLA scatter")
+        _probe_ok = ok
     return _probe_ok
 
 

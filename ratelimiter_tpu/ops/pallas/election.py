@@ -1,6 +1,6 @@
 """Measured per-path Pallas elections (VERDICT r5 #7, generalized r6->r7).
 
-A supported kernel is not necessarily a WINNING kernel: BENCH_r05's A/B
+A supported kernel is not necessarily a WINNING kernel: r05's A/B (a remote-link run before PR 1)
 put the Pallas micro-batch solver at x0.91 of the XLA path on the very
 traffic it exists to serve, and which backend wins varies by device
 generation and toolchain.  PR 3 gave the solver a one-time timed A/B
@@ -47,16 +47,9 @@ _verdicts: Dict[str, Dict] = {}
 
 
 def _cache_path(path_name: str) -> Optional[str]:
-    try:
-        import jax
+    from ratelimiter_tpu.utils.compile_cache import cache_dir
 
-        base = jax.config.jax_compilation_cache_dir
-    except Exception:  # noqa: BLE001
-        base = None
-    if not base:
-        from ratelimiter_tpu.utils.compile_cache import default_cache_dir
-
-        base = default_cache_dir()
+    base = cache_dir()
     try:
         import jax
 
@@ -86,9 +79,11 @@ def measured_election(
 ) -> bool:
     """True when the Pallas implementation of ``path_name`` should serve
     on this device.  ``measure`` runs at most once per (device, path)
-    across processes; a measurement failure keeps Pallas (the support
-    probe already proved it computes correctly — refusing to elect on a
-    timing error would silently discard a working kernel)."""
+    across processes; off the TPU a measurement failure keeps Pallas
+    (the support probe already proved it computes correctly — refusing
+    to elect on a timing error would silently discard a working kernel).
+    On a TPU backend it raises: a measurement that cannot run there is a
+    device fault."""
     hit = _verdicts.get(path_name)
     if hit is not None:
         return bool(hit["elected"])
@@ -146,6 +141,10 @@ def _resolve_verdict(
         ab = dict(measure())
         elected = ab["pallas_s"] <= margin * ab["xla_s"]
     except Exception as exc:  # noqa: BLE001 — measurement failed: keep Pallas
+        import jax
+
+        if jax.default_backend() == "tpu":
+            raise  # a device fault on the chip, not a timing error
         _verdicts[path_name] = {"elected": True, "source": "measure_error",
                                 "error": str(exc)[:200]}
         return True

@@ -68,11 +68,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:  # jax >= 0.6 exports the context manager at top level
-    enable_x64 = jax.enable_x64
-except AttributeError:  # older jax: experimental API, same semantics
-    from jax.experimental import enable_x64
-
 T = 256          # state rows per block; num_slots must divide by this
 
 _FLAG = os.environ.get("RATELIMITER_RELAY_FUSED", "1") == "1"
@@ -527,7 +522,7 @@ def _fused_counts(algo, packed, table, uwords, lid, now, *, rank_bits: int,
         table, lid, jnp.asarray(now))
     s_rows, _ = packed.shape
     u = uwords.shape[0]
-    with enable_x64(False):
+    with jax.enable_x64(False):
         # Every scalar below is explicitly 32-bit: a weak python-int
         # literal traced in this scope can still materialize as i64 at
         # lowering time (the same trap block_scatter.py documents).
@@ -597,6 +592,13 @@ def _probe() -> bool:
     global _probe_ok
     if _probe_ok is not None:
         return _probe_ok
+    from ratelimiter_tpu.ops.pallas import (
+        probe_failed,
+        refuse_interpret_on_tpu,
+    )
+
+    refuse_interpret_on_tpu("relay_fused", _INTERPRET,
+                            "RATELIMITER_RELAY_FUSED_INTERPRET")
     try:
         from ratelimiter_tpu.core.config import RateLimitConfig
         from ratelimiter_tpu.engine.state import LimiterTable
@@ -639,15 +641,18 @@ def _probe() -> bool:
             if not (np.array_equal(np.asarray(want_st), np.asarray(got_st))
                     and np.array_equal(np.asarray(want_c),
                                        np.asarray(got_c))):
-                _probe_ok = False
-                _note_fallback(f"probe mismatch ({algo}): fused output "
-                               "diverged from the composed XLA step")
-                return False
+                reason = (f"probe mismatch ({algo}): fused output "
+                          "diverged from the composed XLA step")
+                break
+        else:
+            reason = None
+    except Exception as exc:  # noqa: BLE001 — verdict below
+        reason = f"probe error: {type(exc).__name__}: {str(exc)[:160]}"
+    if reason is None:
         _probe_ok = True
-    except Exception as exc:  # noqa: BLE001 — any lowering failure => fallback
-        _probe_ok = False
-        _note_fallback(f"probe error: {type(exc).__name__}: "
-                       f"{str(exc)[:160]}")
+    else:
+        _note_fallback(reason)
+        _probe_ok = probe_failed("relay_fused", reason)
     return _probe_ok
 
 

@@ -198,14 +198,25 @@ def _pallas_supported() -> bool:
         if not (_PALLAS_INTERPRET or jax.default_backend() == "tpu"):
             _pallas_ok = False
             return False
+        from ratelimiter_tpu.ops.pallas import (
+            probe_failed,
+            refuse_interpret_on_tpu,
+        )
+
+        refuse_interpret_on_tpu("solver", _PALLAS_INTERPRET,
+                                "RATELIMITER_PALLAS_INTERPRET")
         try:
             test = jnp.asarray([5, 5, -1], dtype=jnp.int32)
             w = jnp.ones(3, dtype=jnp.int32)
             sf = jnp.zeros(3, dtype=jnp.int32)
             out = pallas_solve(test, w, sf, interpret=_PALLAS_INTERPRET)
-            _pallas_ok = list(jax.device_get(out)) == [1, 1, 0]
-        except Exception:  # noqa: BLE001 — any lowering failure => fallback
-            _pallas_ok = False
+            got = list(jax.device_get(out))
+        except Exception as exc:  # noqa: BLE001 — verdict below
+            _pallas_ok = probe_failed("solver",
+                                      f"{type(exc).__name__}: {exc}")
+        else:
+            _pallas_ok = got == [1, 1, 0] or probe_failed(
+                "solver", f"mismatch: got {got}, want [1, 1, 0]")
     return _pallas_ok
 
 
@@ -214,7 +225,7 @@ def _pallas_supported() -> bool:
 # ops/pallas/election.py in r7 — this module keeps only its measure
 # function and delegates the verdict/caching/override machinery).
 #
-# BENCH_r05's A/B put the Pallas solver at x0.91 of the XLA path on the
+# r05's A/B (a remote-link run before PR 1) put the Pallas solver at x0.91 of the XLA path on the
 # micro-batch traffic it exists to serve — a supported kernel is not
 # necessarily a WINNING kernel, and which one wins varies by device
 # generation and toolchain.  The auto dispatcher runs a one-time timed

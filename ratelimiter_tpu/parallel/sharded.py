@@ -28,24 +28,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.6 exports shard_map at top level
-    _shard_map_impl = jax.shard_map
-except AttributeError:  # older jax: the same API lives in experimental
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-
-def shard_map(f, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` with the replication checker off: the flat
-    step's duplicate solver lowers a ``while_loop``, for which older
-    checkers have no replication rule (every spec here is explicit, so
-    the checker adds nothing)."""
-    try:
-        return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_rep=False)
-    except TypeError:  # newer jax dropped/renamed check_rep
-        return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs)
-
 from ratelimiter_tpu.engine.slots import SlotIndex
 from ratelimiter_tpu.engine.state import LimiterTable
 from ratelimiter_tpu.ops.sliding_window import (
@@ -65,6 +47,15 @@ from ratelimiter_tpu.ops.token_bucket import (
     tb_unpack_state,
 )
 from ratelimiter_tpu.parallel.mesh import SHARD_AXIS, make_mesh
+
+
+def shard_map(f, *, mesh, in_specs, out_specs):
+    """``jax.shard_map`` with the replication checker off: every spec
+    here is explicit, so the checker adds nothing, and the flat step's
+    duplicate solver lowers a ``while_loop``."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
+
 
 _MIN_BATCH = 256
 
@@ -493,9 +484,8 @@ class ShardedDeviceEngine:
         self._packed_cache = {"sw": None, "tb": None}
 
         # Settle the Pallas probes before any shard_map step compiles
-        # (same reason as DeviceEngine: a probe firing lazily inside
-        # another program's lowering nests a remote compile some
-        # toolchains cannot serve, sticking as a permanent fallback).
+        # (same reason as DeviceEngine: never nested in another
+        # program's lowering, and a probe failure raises at init).
         from ratelimiter_tpu.ops import pallas as pallas_kernels
 
         pallas_kernels.settle_all()

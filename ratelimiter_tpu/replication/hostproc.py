@@ -515,12 +515,9 @@ def main(argv=None) -> int:
     # Persistent XLA compile cache: the node's dispatch shapes are the
     # standard micro-batch buckets, so a warm cache turns per-process
     # jit compiles into disk loads (utils/compile_cache.py).
-    try:
-        from ratelimiter_tpu.utils.compile_cache import enable_compile_cache
+    from ratelimiter_tpu.utils.compile_cache import enable_compile_cache
 
-        enable_compile_cache(None)
-    except Exception:  # noqa: BLE001 — cold compiles still work
-        pass
+    enable_compile_cache()
     _install_sigterm()
     if args.role == "primary":
         return run_primary(args)

@@ -146,8 +146,6 @@ DEFAULTS = {
     # Boot-time host<->device link probe feeding the streaming loops'
     # chunk plans (storage/tpu.py).
     "link.probe.enabled": "true",
-    # Persistent XLA compile-cache dir; empty -> ~/.cache/ratelimiter_tpu/jax.
-    "jax.cache.dir": "",
     # Chaos drill: inject StorageException on this fraction of storage ops
     # (0 = off) and/or add latency to every op (fault-tolerance rehearsal).
     "chaos.failure_rate": "0",

@@ -210,10 +210,15 @@ def warmup_shapes(storage: RateLimitStorage, max_batch: int = 8192) -> None:
 
     Warms the smallest bucket (single requests) and the full-flush bucket;
     intermediate power-of-two buckets compile on demand (or come from the
-    persistent cache).  Each call is independently best-effort (padding-only
-    batches route as shard-0 padding on the sharded engine, so both engine
-    kinds warm their acquire and peek shapes).
+    persistent cache).  Padding-only batches route as shard-0 padding on
+    the sharded engine, so both engine kinds warm their acquire and peek
+    shapes.  Off the TPU each call is best-effort; on a TPU backend a
+    warmup failure propagates — it is the device failing, and the
+    breaker and degraded tiers would otherwise hide it behind 200s.
     """
+    import jax
+
+    strict = jax.default_backend() == "tpu"
     engine = getattr(storage, "engine", None)
     if engine is None:
         return
@@ -228,15 +233,12 @@ def warmup_shapes(storage: RateLimitStorage, max_batch: int = 8192) -> None:
         lambda: engine.sw_available([0], [0], now),
         lambda: engine.tb_available([0], [0], now),
     ]
-    for call in calls:
+    for call in calls + [engine.block_until_ready]:
         try:
             call()
-        except Exception:  # noqa: BLE001 — warmup is best-effort
-            pass
-    try:
-        engine.block_until_ready()
-    except Exception:  # noqa: BLE001
-        pass
+        except Exception:  # noqa: BLE001 — best-effort off the TPU
+            if strict:
+                raise
 
 
 def build_storage(props: AppProperties, meter_registry=None) -> RateLimitStorage:
@@ -840,7 +842,7 @@ def build_app(props: AppProperties | None = None,
     from ratelimiter_tpu.utils.logging import setup_logging
 
     setup_logging(props)
-    enable_compile_cache(props.get("jax.cache.dir"))
+    enable_compile_cache()
     registry = MeterRegistry()
     # Flight recorder (observability/flightrecorder.py): the process-
     # global ring every subsystem appends state transitions to; sized +

@@ -1,40 +1,35 @@
-"""Persistent XLA compilation cache setup (shared by service wiring and
-bench.py).
+"""Persistent XLA compilation cache: one placement rule for every entry
+point (service wiring, hostproc, bench scripts, chip_smoke.py).
 
-jit compiles cost 40-90 s per batch shape on TPU; the persistent cache
-brings repeats down to ~2 s across process restarts.  Best-effort: any
-failure (read-only filesystem, unsupported backend) leaves compilation
-working, just uncached.
+The cache lives where ``JAX_COMPILATION_CACHE_DIR`` says when that is
+set, and otherwise at the fixed ``<checkout>/.jax_cache`` (listed in
+.gitignore).  The directory is never derived from a temp name, a pid or
+the time: a directory that moves never hits.  Election verdicts and
+probed device rates are kept next to the compiled programs.
 """
 
 from __future__ import annotations
 
 import os
 
-
-def default_cache_dir() -> str:
-    return os.path.join(
-        os.path.expanduser("~"), ".cache", "ratelimiter_tpu", "jax")
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
 
-def enable_compile_cache(cache_dir: str | None = None) -> None:
-    try:
-        import jax
+def cache_dir() -> str:
+    return (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or os.path.join(_CHECKOUT, ".jax_cache"))
 
-        jax.config.update("jax_compilation_cache_dir",
-                          cache_dir or default_cache_dir())
-        # 0.1 s (was 1.0): the staged micro steps compile in ~0.3-0.8 s
-        # on CPU — under the old threshold they were re-compiled every
-        # process boot, which is exactly the latency spike the warmup
-        # and the local-SLO p99 gate exist to prevent.
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
-        # The cache module latches "disabled" the first time a compile
-        # consults it with no directory configured (_cache_initialized).
-        # A caller that builds a storage BEFORE wiring (tests, embedded
-        # use) would silently lose the cache for the whole process —
-        # reset so this configuration takes effect from now on.
-        from jax._src import compilation_cache as _cc
 
-        _cc.reset_cache()
-    except Exception:  # noqa: BLE001 — cache is an optimization only
-        pass
+def enable_compile_cache() -> None:
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    jax.config.update("jax_compilation_cache_dir", cache_dir())
+    # 0.1 s: the staged micro steps compile in ~0.3-0.8 s on CPU — under
+    # JAX's 1 s default they would recompile at every process boot.
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
+    # The cache latches "disabled" the first time a compile consults it
+    # with no directory configured; a caller that built a storage before
+    # wiring would otherwise lose the cache for the whole process.
+    compilation_cache.reset_cache()

@@ -408,7 +408,7 @@ def test_digest_mode_election_flips_with_device_rates():
 def test_device_rates_fallback_and_cache(monkeypatch, tmp_path):
     """RATELIMITER_RATE_PROBE=0 yields the v5e fallback constants; a
     pre-seeded disk cache is honored without probing; both are
-    memoized per (platform, kind)."""
+    memoized per (platform, kind); a failed probe raises."""
     import json as _json
 
     import jax
@@ -417,8 +417,7 @@ def test_device_rates_fallback_and_cache(monkeypatch, tmp_path):
 
     monkeypatch.setattr(dr, "_mem_cache", {})
     monkeypatch.setenv("RATELIMITER_RATE_PROBE", "0")
-    old = jax.config.jax_compilation_cache_dir
-    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     got = dr.get_device_rates()
     assert got["source"] == "fallback"
     assert got["s_per_lane"] == dr.FALLBACK_RATES["s_per_lane"]
@@ -436,19 +435,23 @@ def test_device_rates_fallback_and_cache(monkeypatch, tmp_path):
     with open(path, "w", encoding="utf-8") as fh:
         _json.dump(rates, fh)
     monkeypatch.setattr(dr, "_mem_cache", {})
-    try:
-        # The opt-out beats the disk artifact (determinism pin) ...
-        assert dr.get_device_rates()["source"] == "fallback"
-        # ... and with probing allowed, the artifact is honored without
-        # re-probing.
-        monkeypatch.setenv("RATELIMITER_RATE_PROBE", "1")
-        monkeypatch.setattr(dr, "_probe", lambda: (_ for _ in ()).throw(
-            AssertionError("disk cache must prevent probing")))
-        monkeypatch.setattr(dr, "_mem_cache", {})
-        got2 = dr.get_device_rates()
-    finally:
-        jax.config.update("jax_compilation_cache_dir", old)
+    # The opt-out beats the disk artifact (determinism pin) ...
+    assert dr.get_device_rates()["source"] == "fallback"
+    # ... and with probing allowed, the artifact is honored without
+    # re-probing.
+    monkeypatch.setenv("RATELIMITER_RATE_PROBE", "1")
+    monkeypatch.setattr(dr, "_probe", lambda: (_ for _ in ()).throw(
+        AssertionError("disk cache must prevent probing")))
+    monkeypatch.setattr(dr, "_mem_cache", {})
+    got2 = dr.get_device_rates()
     assert got2["s_per_lane"] == 1e-9 and got2["source"] == "probe"
+    # A failed probe is an error, never a silent fallback constant.
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "empty"))
+    monkeypatch.setattr(dr, "_mem_cache", {})
+    monkeypatch.setattr(dr, "_probe", lambda: (_ for _ in ()).throw(
+        RuntimeError("probe failed")))
+    with pytest.raises(RuntimeError, match="probe failed"):
+        dr.get_device_rates()
 
 
 def test_schedule_candidates_invariants():
