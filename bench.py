@@ -201,14 +201,7 @@ def main() -> None:
                                      default=0.0), 4),
             "wire_bytes": int(sum(r.get("wire_bytes", 0) for r in stats)),
         }
-        # r5: drains run CONCURRENTLY, so the honest fetch wall-clock
-        # figure is the SPAN of fetch activity, not the sum of per-chunk
-        # blocking times (which can exceed the wall under overlap).
-        ats = [r["fetch_at"] for r in stats if r.get("fetch_at")]
-        if ats:
-            agg["fetch_span_s"] = round(
-                max(a[1] for a in ats) - min(a[0] for a in ats), 4)
-        for extra in ("rebuild_s", "dispatch_s", "pack_s"):
+        for extra in ("dispatch_s", "pack_s"):
             tot = sum(r.get(extra, 0) for r in stats)
             if tot:
                 agg[extra] = round(tot, 4)

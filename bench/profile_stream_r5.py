@@ -2,8 +2,8 @@
 
 Mirrors bench.py's scenario 2 (TB 1M Zipf) and scenario 3 (SW 10M
 uniform) shapes, runs the warmup/plan-settling discipline, then prints
-per-chunk stream_stats records with the r5 sub-phase timers
-(rebuild_s / dispatch_s) so host_s stops being a mystery number.
+per-chunk stream_stats records with the dispatch sub-phase timer
+(dispatch_s) so host_s stops being a mystery number.
 
 Usage:  python bench/profile_stream_r5.py [headline|sc3|both] [reps]
 """

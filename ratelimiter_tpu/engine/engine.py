@@ -94,6 +94,16 @@ _MICRO_STEPS = {
 }
 
 
+def _step(base, **static):
+    """``base`` with its static arguments bound, under ``base``'s name:
+    ``jax.jit`` names a program after its callable's ``__name__``, which
+    a bare ``functools.partial`` lacks, so a device trace would show
+    every such step as ``jit__unknown``."""
+    step = functools.partial(base, **static)
+    step.__name__ = base.__name__
+    return step
+
+
 def _bucket_size(n: int) -> int:
     size = _MICRO_FLOOR
     while size < n:
@@ -154,9 +164,9 @@ class DeviceEngine:
         # the uint32 carry the duplicate rank + last flag.
         self.slot_bits = max(int(self.num_slots).bit_length(), 1)
         self.rank_bits = 31 - self.slot_bits
-        self._sw_relay = jax.jit(functools.partial(
+        self._sw_relay = jax.jit(_step(
             sw_relay_bits, rank_bits=self.rank_bits), donate_argnums=0)
-        self._tb_relay = jax.jit(functools.partial(
+        self._tb_relay = jax.jit(_step(
             tb_relay_bits, rank_bits=self.rank_bits), donate_argnums=0)
         self._relay_counts = {}  # (algo, out_dtype name, sorted) -> jitted step
         self._relay_weighted = {}  # (algo, r_steps) -> jitted weighted step
@@ -475,7 +485,7 @@ class DeviceEngine:
         fn = self._relay_weighted.get(key)
         if fn is None:
             base = sw_relay_weighted if algo == "sw" else tb_relay_weighted
-            fn = jax.jit(functools.partial(
+            fn = jax.jit(_step(
                 base, rank_bits=self.rank_bits, r_steps=int(r_steps)),
                 donate_argnums=0)
             self._relay_weighted[key] = fn
@@ -529,7 +539,7 @@ class DeviceEngine:
         if fn is None:
             base = (sw_relay_weighted_counts if algo == "sw"
                     else tb_relay_weighted_counts)
-            fn = jax.jit(functools.partial(
+            fn = jax.jit(_step(
                 base, rank_bits=self.rank_bits, out_dtype=jdt),
                 donate_argnums=0)
             self._relay_counts[key] = fn
@@ -595,7 +605,7 @@ class DeviceEngine:
         if fn is None:
             base = (sw_relay_counts_split if algo == "sw"
                     else tb_relay_counts_split)
-            fn = jax.jit(functools.partial(
+            fn = jax.jit(_step(
                 base, rank_bits=self.rank_bits, out_dtype=jdt),
                 donate_argnums=0)
             self._relay_counts[key] = fn
@@ -651,7 +661,7 @@ class DeviceEngine:
         if fn is None:
             base = (sw_relay_counts_resident if algo == "sw"
                     else tb_relay_counts_resident)
-            fn = jax.jit(functools.partial(
+            fn = jax.jit(_step(
                 base, rank_bits=self.rank_bits, out_dtype=jdt,
                 slots_sorted=bool(slots_sorted)),
                 donate_argnums=(0, 1))
@@ -698,13 +708,13 @@ class DeviceEngine:
 
                 base = (relay_step.sw_relay_counts_fused if algo == "sw"
                         else relay_step.tb_relay_counts_fused)
-                fn = jax.jit(functools.partial(
+                fn = jax.jit(_step(
                     base, rank_bits=self.rank_bits, out_dtype=jdt,
                     interpret=relay_step.interpret_mode()),
                     donate_argnums=0)
             else:
                 base = sw_relay_counts if algo == "sw" else tb_relay_counts
-                fn = jax.jit(functools.partial(
+                fn = jax.jit(_step(
                     base, rank_bits=self.rank_bits, out_dtype=jdt,
                     slots_sorted=bool(slots_sorted)),
                     donate_argnums=0)

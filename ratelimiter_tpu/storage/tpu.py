@@ -35,6 +35,7 @@ from concurrent.futures import Future
 from typing import Callable, Dict, List, Sequence, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ratelimiter_tpu.core.config import RateLimitConfig
 from ratelimiter_tpu.engine.batcher import MicroBatcher
@@ -314,10 +315,10 @@ class _DrainSet:
     ``finish(swallow=True)`` is for paths already propagating a primary
     exception (drain errors are then secondary)."""
 
-    __slots__ = ("_pool", "_futs", "_inflight", "_on_block")
+    __slots__ = ("_pool", "_futs", "_inflight", "_on_block", "_wait_span")
 
     def __init__(self, pool, inflight: int = _DRAIN_INFLIGHT,
-                 on_block=None):
+                 on_block=None, wait_span=contextlib.nullcontext):
         self._pool = pool
         self._futs: list = []
         self._inflight = inflight
@@ -325,6 +326,9 @@ class _DrainSet:
         # out an old drain — the per-shard lanes feed it to the flight
         # recorder so a drain-bound shard is diagnosable.
         self._on_block = on_block
+        # Factory of the span around every wait of the caller on drains
+        # (backpressure and finish).
+        self._wait_span = wait_span
 
     def submit(self, fn, *args) -> None:
         self._futs.append(self._pool.submit(fn, *args))
@@ -334,19 +338,62 @@ class _DrainSet:
         if len(live) > self._inflight:
             if self._on_block is not None:
                 self._on_block()
-            live[0].result()
+            with self._wait_span():
+                live[0].result()
 
     def finish(self, swallow: bool = False) -> None:
+        if not self._futs:
+            return
         err = None
-        for f in self._futs:
-            try:
-                f.result()
-            except Exception as exc:  # noqa: BLE001 — re-raised below
-                if err is None:
-                    err = exc
+        with self._wait_span():
+            for f in self._futs:
+                try:
+                    f.result()
+                except Exception as exc:  # noqa: BLE001 — re-raised below
+                    if err is None:
+                        err = exc
         self._futs.clear()
         if err is not None and not swallow:
             raise err
+
+
+class _Span:
+    """One stage of a stream pass, on the profiler's clock and in the
+    stage's timer: a ``jax.profiler.TraceAnnotation`` named
+    ``ratelimiter.stream.<stage>`` (written only while a profiler
+    session is active; ``chunk`` rides along as its metadata) around
+    the interval the stage's timer records (``timer`` None: a span with
+    no timer, or observability off).  ``end()`` closes it early, where
+    the next stage starts inside the same block; ``t0``/``t1`` keep the
+    interval for callers that need it."""
+
+    __slots__ = ("_ann", "_timer", "t0", "t1")
+
+    def __init__(self, name: str, timer, chunk: int | None):
+        self._ann = (TraceAnnotation(name) if chunk is None
+                     else TraceAnnotation(name, chunk=chunk))
+        self._timer = timer
+        self.t1 = None
+
+    def __enter__(self) -> "_Span":
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def end(self) -> None:
+        if self.t1 is None:
+            self.t1 = time.perf_counter()
+            self._ann.__exit__(None, None, None)
+            if self._timer is not None:
+                self._timer.record_us((self.t1 - self.t0) * 1e6)
+
+    def __exit__(self, *exc) -> bool:
+        self.end()
+        return False
+
+    @property
+    def secs(self) -> float:
+        return self.t1 - self.t0
 
 
 class _StagingPool:
@@ -684,18 +731,21 @@ class TpuBatchedStorage(RateLimitStorage):
             if self._obs else None
         )
         # Per-stage pipeline timers (r6, unconditional since the
-        # observability PR): where a stream chunk's seconds go — pack
-        # (string hashing), index (slot walk), layout (host dispatch
-        # prep), enqueue (device dispatch call), fetch (the blocking
-        # result read).
+        # observability PR): where a stream chunk's seconds go — route
+        # (shard binning), pack (string hashing), index (slot walk),
+        # assign (the caller's wait for the walk), layout (host
+        # dispatch prep), enqueue (device dispatch call), fetch (the
+        # blocking result read), decide (host reconstruction after the
+        # fetch), drain_wait (the caller blocked on drains).  Each is
+        # fed by the span of the same name (_span).
         self._stage_timers = None
         if self._obs:
             self._stage_timers = {
                 s: meter_registry.timer(
                     f"ratelimiter.stream.{s}",
                     f"Stream pipeline {s} stage (us per chunk)")
-                for s in ("route", "pack", "index", "layout", "enqueue",
-                          "fetch")}
+                for s in ("route", "pack", "index", "assign", "layout",
+                          "enqueue", "fetch", "decide", "drain_wait")}
         # Reusable dispatch staging buffers shared by every stream loop.
         self._staging = _StagingPool()
         if engine is not None and table is None:
@@ -1457,7 +1507,16 @@ class TpuBatchedStorage(RateLimitStorage):
         and ``acquire``, so paths can be mixed freely.  ``permits=None``
         means one permit per request (the permits upload is skipped; the
         device materializes ones).  Returns bool[n] allowed.
+
+        The call is the ``ratelimiter.stream.call`` span; its stages are
+        its children (ARCHITECTURE §13a).
         """
+        with self._span("call"):
+            return self._stream_ids(algo, lid, key_ids, permits, batch,
+                                    subbatches)
+
+    def _stream_ids(self, algo, lid, key_ids, permits, batch,
+                    subbatches) -> np.ndarray:
         self._check_not_promoting()
         if self._fenced_shards:
             self._check_fence_int_keys(key_ids)
@@ -1632,7 +1691,8 @@ class TpuBatchedStorage(RateLimitStorage):
         def clear(slots):
             self._clear_slots(algo, slots)
         out = np.empty(n, dtype=bool)
-        drains = _DrainSet(self._drain_pool())
+        drains = _DrainSet(self._drain_pool(),
+                           wait_span=lambda: self._span("drain_wait"))
 
         # Chunk plan (VERDICT r3 #1): the first pass over this stream
         # shape runs the wire-budget growth schedule and measures; later
@@ -1646,53 +1706,50 @@ class TpuBatchedStorage(RateLimitStorage):
         # band instead of re-measuring every distinct n.
         plan_key = ("relay", key_kind, algo, lid_arr is not None,
                     _bucket_fine(n, floor=_RELAY_CHUNK))
-        plan, pipelined, tot, timed_assign, t_pass0 = self._plan_setup(
-            plan_key, assign_uniques)
-        rates = self._device_rates()
+        with self._span("plan"):
+            plan, pipelined, tot, timed_assign, t_pass0 = self._plan_setup(
+                plan_key, assign_uniques)
+            rates = self._device_rates()
 
-        def drain(mode, handle, start, count, extra, t0, rec, bufs=()):
+        def drain(mode, handle, start, count, extra, t0, rec, bufs, chunk):
             try:
-                tf0 = time.perf_counter()
-                arr = np.asarray(handle)  # the one blocking fetch
-                tf1 = time.perf_counter()
-                dt_us = (tf1 - t0) * 1e6
-                self._stage("fetch", tf1 - tf0)
-                if mode == "bits":
-                    got = np.unpackbits(arr)[:count].astype(bool)
-                elif mode == "split":
-                    # [packed singleton bits | multi count bytes] -> one
-                    # per-unique counts lane, then the standard rank
-                    # compare (singleton counts are exactly their allow
-                    # bit).
-                    from ratelimiter_tpu.engine.native_index import (
-                        relay_decide,
-                    )
+                with self._span("fetch", chunk) as fetch:
+                    arr = np.asarray(handle)  # the one blocking fetch
+                with self._span("decide", chunk):
+                    if mode == "bits":
+                        got = np.unpackbits(arr)[:count].astype(bool)
+                    elif mode == "split":
+                        # [packed singleton bits | multi count bytes] ->
+                        # one per-unique counts lane, then the standard
+                        # rank compare (singleton counts are exactly
+                        # their allow bit).
+                        from ratelimiter_tpu.engine.native_index import (
+                            relay_decide,
+                        )
 
-                    uidx2, rank, u, n_s, s_pad, m_pad, cdt_l = extra
-                    csize = np.dtype(cdt_l).itemsize
-                    counts_all = np.empty(u, dtype=cdt_l)
-                    counts_all[:n_s] = np.unpackbits(
-                        arr[:s_pad // 8])[:n_s]
-                    counts_all[n_s:] = arr[
-                        s_pad // 8:s_pad // 8 + m_pad * csize].view(
-                            cdt_l)[:u - n_s]
-                    got = relay_decide(counts_all, uidx2, rank)
-                else:  # digest: reconstruct from per-unique counts
-                    from ratelimiter_tpu.engine.native_index import (
-                        relay_decide,
-                    )
+                        uidx2, rank, u, n_s, s_pad, m_pad, cdt_l = extra
+                        csize = np.dtype(cdt_l).itemsize
+                        counts_all = np.empty(u, dtype=cdt_l)
+                        counts_all[:n_s] = np.unpackbits(
+                            arr[:s_pad // 8])[:n_s]
+                        counts_all[n_s:] = arr[
+                            s_pad // 8:s_pad // 8 + m_pad * csize].view(
+                                cdt_l)[:u - n_s]
+                        got = relay_decide(counts_all, uidx2, rank)
+                    else:  # digest: reconstruct from per-unique counts
+                        from ratelimiter_tpu.engine.native_index import (
+                            relay_decide,
+                        )
 
-                    uidx, rank, u = extra
-                    got = relay_decide(arr[:u], uidx, rank)
-                out[start:start + count] = got
+                        uidx, rank, u = extra
+                        got = relay_decide(arr[:u], uidx, rank)
+                    out[start:start + count] = got
                 n_allowed = int(got.sum())
                 with tot["_lock"]:
-                    tot["fetch_s"] += tf1 - tf0
                     if rec is not None:
-                        rec["fetch_s"] = round(tf1 - tf0, 6)
-                        rec["fetch_at"] = [round(tf0 - t_pass0, 6),
-                                           round(tf1 - t_pass0, 6)]
-                    self._record_dispatch(algo, count, n_allowed, dt_us,
+                        rec["fetch_s"] = round(fetch.secs, 6)
+                    self._record_dispatch(algo, count, n_allowed,
+                                          (fetch.t1 - t0) * 1e6,
                                           path=f"relay|{mode}",
                                           lid=None if multi_lid else lid)
             finally:
@@ -1703,22 +1760,26 @@ class TpuBatchedStorage(RateLimitStorage):
 
         cursor = _ChunkCursor(plan, pipelined)
         start = 0
+        ci = 0  # the chunk's index in this call: every span's metadata
         fut = None  # prefetched next-chunk assignment (holds pins)
         try:
             while start < n:
                 cn = cursor.next_size(n - start)
-                t_a0 = time.perf_counter()
-                if fut is not None:
-                    uwords, uidx, rank, clears = fut.result()
-                    fut = None
-                else:
-                    uwords, uidx, rank, clears = timed_assign(start, cn)
-                t_assign = time.perf_counter() - t_a0
+                with self._span("assign", ci) as waited:
+                    if fut is not None:
+                        uwords, uidx, rank, clears = fut.result()
+                        fut = None
+                    else:
+                        uwords, uidx, rank, clears = timed_assign(start, cn,
+                                                                  ci)
+                t_assign = waited.secs
                 u = len(uwords)
                 pack_s = (getattr(self._index[algo], "str_pack_s", None)
                           if key_kind == "strs" else None)
-                if pack_s is not None:
-                    self._stage("pack", pack_s)
+                if pack_s is not None and self._stage_timers is not None:
+                    # Timed inside the index's assign (on whichever
+                    # thread walked), so it has no span here.
+                    self._stage_timers["pack"].record_us(pack_s * 1e6)
                 rec = self._stream_rec("relay", n=int(cn), u=int(u),
                                        assign_s=t_assign)
                 if rec is not None:
@@ -1730,213 +1791,207 @@ class TpuBatchedStorage(RateLimitStorage):
                         rec["host_parallel"] = self._host_parallel
                     if pack_s is not None:
                         rec["pack_s"] = round(pack_s, 6)
-                uslots_all = (uwords >> np.uint32(rb + 1)).astype(np.int32)
+                with self._span("clear", ci):  # the slots pinned below
+                    uslots_all = (uwords >> np.uint32(rb + 1)).astype(
+                        np.int32)
                 with self._pins_released(self._index[algo], uslots_all):
                     if len(clears):
-                        clear(list(clears))
-                    l_chunk = (lid_arr[start:start + cn] if multi_lid
-                               else None)
-                    # Mode election: steady-state digest cost per unique plus
-                    # this chunk's (slot, lid) delta uploads charged at
-                    # 1/_DELTA_AMORT (they are an investment — once resident,
-                    # every later chunk reads the lid from the device map).
-                    fresh = None
-                    n_delta = 0
-                    if cdt is not None and multi_lid:
-                        with self._lid_locks[algo]:
-                            known = self._lid_known.setdefault(
-                                algo, np.zeros(eng.num_slots, dtype=bool))
-                            uslots = uslots_all.astype(np.int64)
-                            fresh = ~known[uslots]
-                        from ratelimiter_tpu.parallel.sharded import _bucket as _bkt
-                        n_delta = _bkt(max(int(fresh.sum()), 1), floor=8)
-                    # One sorted-eligibility verdict drives BOTH the
-                    # mode election's device rate and the dispatch path
-                    # below — they must never disagree.  Sorting pays
-                    # off when EITHER sorted device path engages: the
-                    # dense presorted sweep, or (scalar-lid dispatches
-                    # only) the fused Pallas relay step the engine
-                    # elects per device (ops/pallas/relay_step.py).
-                    fused_ok = (not multi_lid
-                                and hasattr(eng, "_relay_fused_ok")
-                                and eng._relay_fused_ok(
-                                    algo, _bucket_pow2(u)))
-                    srt_ok = (u >= _SORT_UNIQUES_MIN
-                              and _sort_affordable(self._link_profile, u)
-                              and (fused_ok or _presorted_scatter_usable(
-                                  eng, algo, _bucket_pow2(u))))
-                    digest = cdt is not None and _elect_digest_mode(
-                        self._link_profile, u, cn, n_delta, digest_bpu,
-                        words_bpr, srt_ok,
-                        cdt_size=np.dtype(cdt).itemsize if cdt else 1,
-                        rates=rates)
-                    # Split-digest election (r5): singletons as a 3-byte
-                    # slot plane with BIT decisions back, multis as
-                    # classic uwords+counts — beats classic digest when
-                    # most uniques are singletons and beats words mode
-                    # at high u/n, per-direction costs compared against
-                    # whichever of the two won above.
-                    split = False
-                    n_singles = 0
-                    if (self._link_profile is not None and cdt is not None
-                            and not multi_lid and rb >= 2
-                            and eng.num_slots <= 0xFFFFFF
-                            and u >= _SORT_UNIQUES_MIN):
-                        prof = self._link_profile
-                        up_r = max(prof[0], 1.0)
-                        down_r = max(prof[2], 1.0) if len(prof) > 2 else up_r
-                        cdt_b = np.dtype(cdt).itemsize
-                        singles_mask = (((uwords >> np.uint32(1))
-                                         & np.uint32((1 << rb) - 1)) == 1)
-                        n_singles = int(singles_mask.sum())
-                        n_multi = u - n_singles
-                        cost_split = (
-                            n_singles * (3.0 / up_r + 0.125 / down_r)
-                            + n_multi * (4.0 / up_r + cdt_b / down_r)
-                            + u * (rates["s_per_unique_unsorted"]
-                                   + _SPLIT_HOST_S_PER_UNIQUE))
-                        if digest:
-                            # Classic digest uploads exactly the 4 B
-                            # uword and downloads the cdt count (the
-                            # blended digest_bpu would overcharge the
-                            # upload by 1 B at cdt_b=1).
-                            dev_u = rates["s_per_unique_sorted" if srt_ok
-                                          else "s_per_unique_unsorted"]
-                            rival = u * (4.0 / up_r + cdt_b / down_r
-                                         + dev_u)
-                        else:
-                            rival = cn * ((words_bpr - 0.125) / up_r
-                                          + 0.125 / down_r
-                                          + rates["s_per_lane"])
-                        split = cost_split < rival
+                        with self._span("clear", ci):
+                            clear(list(clears))
+                    with self._span("elect", ci):
+                        l_chunk = (lid_arr[start:start + cn] if multi_lid
+                                   else None)
+                        # Mode election: steady-state digest cost per unique plus
+                        # this chunk's (slot, lid) delta uploads charged at
+                        # 1/_DELTA_AMORT (they are an investment — once resident,
+                        # every later chunk reads the lid from the device map).
+                        fresh = None
+                        n_delta = 0
+                        if cdt is not None and multi_lid:
+                            with self._lid_locks[algo]:
+                                known = self._lid_known.setdefault(
+                                    algo, np.zeros(eng.num_slots, dtype=bool))
+                                uslots = uslots_all.astype(np.int64)
+                                fresh = ~known[uslots]
+                            from ratelimiter_tpu.parallel.sharded import _bucket as _bkt
+                            n_delta = _bkt(max(int(fresh.sum()), 1), floor=8)
+                        # One sorted-eligibility verdict drives BOTH the
+                        # mode election's device rate and the dispatch path
+                        # below — they must never disagree.  Sorting pays
+                        # off when EITHER sorted device path engages: the
+                        # dense presorted sweep, or (scalar-lid dispatches
+                        # only) the fused Pallas relay step the engine
+                        # elects per device (ops/pallas/relay_step.py).
+                        fused_ok = (not multi_lid
+                                    and hasattr(eng, "_relay_fused_ok")
+                                    and eng._relay_fused_ok(
+                                        algo, _bucket_pow2(u)))
+                        srt_ok = (u >= _SORT_UNIQUES_MIN
+                                  and _sort_affordable(self._link_profile, u)
+                                  and (fused_ok or _presorted_scatter_usable(
+                                      eng, algo, _bucket_pow2(u))))
+                        digest = cdt is not None and _elect_digest_mode(
+                            self._link_profile, u, cn, n_delta, digest_bpu,
+                            words_bpr, srt_ok,
+                            cdt_size=np.dtype(cdt).itemsize if cdt else 1,
+                            rates=rates)
+                        # Split-digest election (r5): singletons as a 3-byte
+                        # slot plane with BIT decisions back, multis as
+                        # classic uwords+counts — beats classic digest when
+                        # most uniques are singletons and beats words mode
+                        # at high u/n, per-direction costs compared against
+                        # whichever of the two won above.
+                        split = False
+                        n_singles = 0
+                        if (self._link_profile is not None and cdt is not None
+                                and not multi_lid and rb >= 2
+                                and eng.num_slots <= 0xFFFFFF
+                                and u >= _SORT_UNIQUES_MIN):
+                            prof = self._link_profile
+                            up_r = max(prof[0], 1.0)
+                            down_r = max(prof[2], 1.0) if len(prof) > 2 else up_r
+                            cdt_b = np.dtype(cdt).itemsize
+                            singles_mask = (((uwords >> np.uint32(1))
+                                             & np.uint32((1 << rb) - 1)) == 1)
+                            n_singles = int(singles_mask.sum())
+                            n_multi = u - n_singles
+                            cost_split = (
+                                n_singles * (3.0 / up_r + 0.125 / down_r)
+                                + n_multi * (4.0 / up_r + cdt_b / down_r)
+                                + u * (rates["s_per_unique_unsorted"]
+                                       + _SPLIT_HOST_S_PER_UNIQUE))
+                            if digest:
+                                # Classic digest uploads exactly the 4 B
+                                # uword and downloads the cdt count (the
+                                # blended digest_bpu would overcharge the
+                                # upload by 1 B at cdt_b=1).
+                                dev_u = rates["s_per_unique_sorted" if srt_ok
+                                              else "s_per_unique_unsorted"]
+                                rival = u * (4.0 / up_r + cdt_b / down_r
+                                             + dev_u)
+                            else:
+                                rival = cn * ((words_bpr - 0.125) / up_r
+                                              + 0.125 / down_r
+                                              + rates["s_per_lane"])
+                            split = cost_split < rival
                     now = self._monotonic_now()
-                    t_prep = time.perf_counter()
-                    t0 = time.perf_counter()
-                    if split:
-                        from ratelimiter_tpu.engine.native_index import (
-                            split_layout,
-                        )
-
-                        srt = False  # split lanes dispatch unsorted
-                        s3, mwords, uidx2, n_s = split_layout(
-                            uwords, rb, uidx, singles=singles_mask)
-                        # Quarter-octave buckets: pow2 padding at these
-                        # lane counts wastes up to ~55% of the wire the
-                        # split exists to save (2.7M singles -> 4.19M
-                        # pow2 lanes, measured); fine buckets cap the
-                        # waste at ~12% for a couple extra compile
-                        # shapes.  Both stay multiples of 8 (packbits).
-                        s_pad = _bucket_fine(n_s)
-                        m_pad = _bucket_fine(u - n_s)
-                        s3p = self._staging.take((s_pad, 3), np.uint8)
-                        s3p[:n_s] = s3
-                        s3p[n_s:] = 0xFF
-                        mw = self._staging.take((m_pad,), np.uint32)
-                        mw[:u - n_s] = mwords
-                        mw[u - n_s:] = 0xFFFFFFFF
-                        split_dispatch = (
-                            eng.sw_relay_counts_split_dispatch
-                            if algo == "sw"
-                            else eng.tb_relay_counts_split_dispatch)
-                        t_e0 = time.perf_counter()
-                        outh = split_dispatch(s3p, mw, lid, now, cdt)
-                        self._stage("layout", t_e0 - t0)
-                        self._stage("enqueue", time.perf_counter() - t_e0)
-                        item = ("split", outh, start, cn,
-                                (uidx2, rank, u, n_s, s_pad, m_pad, cdt),
-                                t0, rec, [s3p, mw])
-                        digest = True  # per-unique accounting below
-                    elif digest:
-                        # Slot-sorted digest: the C index sorts the uniques
-                        # in place (uidx remapped — reconstruction is order-
-                        # agnostic) so the device write is a dense sweep.
-                        # srt_ok (shared with the election above) already
-                        # gates on the sweep actually engaging — on the
-                        # XLA fallback the scatter is order-blind and the
-                        # sort would be pure overhead.
-                        srt = False
-                        if srt_ok:
+                    with self._span("layout", ci) as lay:
+                        if split:
                             from ratelimiter_tpu.engine.native_index import (
-                                sort_uniques,
+                                split_layout,
                             )
 
-                            srt = sort_uniques(uwords, rb, uidx)
-                        size = _bucket_pow2(u)
-                        uw = self._staging.take((size,), np.uint32)
-                        uw[:u] = uwords
-                        uw[u:] = 0xFFFFFFFF
-                        if multi_lid:
-                            # Tenant ids live RESIDENT on device (a slot's lid is
-                            # immutable while assigned): upload only the (slot,
-                            # lid) pairs the device doesn't know yet — fresh
-                            # assignments and post-eviction reuse, tracked in
-                            # _lid_known and invalidated by _clear_slots.  Per-
-                            # unique lids map through uidx (NOT positional: a
-                            # partitioned index merges uniques partition-major).
-                            from ratelimiter_tpu.parallel.sharded import _bucket
+                            srt = False  # split lanes dispatch unsorted
+                            s3, mwords, uidx2, n_s = split_layout(
+                                uwords, rb, uidx, singles=singles_mask)
+                            # Quarter-octave buckets: pow2 padding at these
+                            # lane counts wastes up to ~55% of the wire the
+                            # split exists to save (2.7M singles -> 4.19M
+                            # pow2 lanes, measured); fine buckets cap the
+                            # waste at ~12% for a couple extra compile
+                            # shapes.  Both stay multiples of 8 (packbits).
+                            s_pad = _bucket_fine(n_s)
+                            m_pad = _bucket_fine(u - n_s)
+                            s3p = self._staging.take((s_pad, 3), np.uint8)
+                            s3p[:n_s] = s3
+                            s3p[n_s:] = 0xFF
+                            mw = self._staging.take((m_pad,), np.uint32)
+                            mw[:u - n_s] = mwords
+                            mw[u - n_s:] = 0xFFFFFFFF
+                            split_dispatch = (
+                                eng.sw_relay_counts_split_dispatch
+                                if algo == "sw"
+                                else eng.tb_relay_counts_split_dispatch)
+                            lay.end()
+                            with self._span("enqueue", ci):
+                                outh = split_dispatch(s3p, mw, lid, now, cdt)
+                            item = ("split", outh, start, cn,
+                                    (uidx2, rank, u, n_s, s_pad, m_pad, cdt),
+                                    lay.t0, rec, [s3p, mw], ci)
+                            digest = True  # per-unique accounting below
+                        elif digest:
+                            # Slot-sorted digest: the C index sorts the uniques
+                            # in place (uidx remapped — reconstruction is order-
+                            # agnostic) so the device write is a dense sweep.
+                            # srt_ok (shared with the election above) already
+                            # gates on the sweep actually engaging — on the
+                            # XLA fallback the scatter is order-blind and the
+                            # sort would be pure overhead.
+                            srt = False
+                            if srt_ok:
+                                from ratelimiter_tpu.engine.native_index import (
+                                    sort_uniques,
+                                )
 
-                            first = rank == 0
-                            ulids = np.zeros(u, dtype=np.int32)
-                            ulids[uidx[first]] = l_chunk[first]
-                            # Re-read fresh, mark, and dispatch under the lock
-                            # shared with _clear_slots: an eviction racing the
-                            # mark must win (forcing a later re-upload), never
-                            # lose to a stale known=True.
-                            with self._lid_locks[algo]:
-                                if srt:  # uwords were re-ordered in place
-                                    uslots = (uwords >> np.uint32(rb + 1)
-                                              ).astype(np.int64)
-                                fresh = ~known[uslots]
-                                n_delta = int(fresh.sum())
-                                dsize = _bucket(max(n_delta, 1), floor=8)
-                                d_slots = _pad_tail(uslots[fresh], dsize, -1,
-                                                    np.int32)
-                                d_lids = _pad_tail(ulids[fresh], dsize, 0,
-                                                   np.int32)
-                                resident = (eng.sw_relay_counts_resident_dispatch
-                                            if algo == "sw"
-                                            else eng.tb_relay_counts_resident_dispatch)
-                                t_e0 = time.perf_counter()
-                                counts = resident(uw, d_slots, d_lids, now,
-                                                  cdt, slots_sorted=srt)
-                                self._stage("layout", t_e0 - t0)
-                                self._stage("enqueue",
-                                            time.perf_counter() - t_e0)
-                                # Mark AFTER the dispatch: a raise must not
-                                # leave slots "known" with no lid uploaded.
-                                known[uslots[fresh]] = True
-                                n_delta = dsize  # charge the padded lane
+                                srt = sort_uniques(uwords, rb, uidx)
+                            size = _bucket_pow2(u)
+                            uw = self._staging.take((size,), np.uint32)
+                            uw[:u] = uwords
+                            uw[u:] = 0xFFFFFFFF
+                            if multi_lid:
+                                # Tenant ids live RESIDENT on device (a slot's lid is
+                                # immutable while assigned): upload only the (slot,
+                                # lid) pairs the device doesn't know yet — fresh
+                                # assignments and post-eviction reuse, tracked in
+                                # _lid_known and invalidated by _clear_slots.  Per-
+                                # unique lids map through uidx (NOT positional: a
+                                # partitioned index merges uniques partition-major).
+                                from ratelimiter_tpu.parallel.sharded import _bucket
+
+                                first = rank == 0
+                                ulids = np.zeros(u, dtype=np.int32)
+                                ulids[uidx[first]] = l_chunk[first]
+                                # Re-read fresh, mark, and dispatch under the lock
+                                # shared with _clear_slots: an eviction racing the
+                                # mark must win (forcing a later re-upload), never
+                                # lose to a stale known=True.
+                                with self._lid_locks[algo]:
+                                    if srt:  # uwords were re-ordered in place
+                                        uslots = (uwords >> np.uint32(rb + 1)
+                                                  ).astype(np.int64)
+                                    fresh = ~known[uslots]
+                                    n_delta = int(fresh.sum())
+                                    dsize = _bucket(max(n_delta, 1), floor=8)
+                                    d_slots = _pad_tail(uslots[fresh], dsize, -1,
+                                                        np.int32)
+                                    d_lids = _pad_tail(ulids[fresh], dsize, 0,
+                                                       np.int32)
+                                    resident = (eng.sw_relay_counts_resident_dispatch
+                                                if algo == "sw"
+                                                else eng.tb_relay_counts_resident_dispatch)
+                                    lay.end()
+                                    with self._span("enqueue", ci):
+                                        counts = resident(uw, d_slots, d_lids,
+                                                          now, cdt,
+                                                          slots_sorted=srt)
+                                    # Mark AFTER the dispatch: a raise must not
+                                    # leave slots "known" with no lid uploaded.
+                                    known[uslots[fresh]] = True
+                                    n_delta = dsize  # charge the padded lane
+                            else:
+                                lay.end()
+                                with self._span("enqueue", ci):
+                                    counts = counts_dispatch(
+                                        uw, lid, now, cdt, slots_sorted=srt)
+                            item = ("digest", counts, start, cn,
+                                    (uidx, rank, u), lay.t0, rec, [uw], ci)
                         else:
-                            t_e0 = time.perf_counter()
-                            counts = counts_dispatch(uw, lid, now, cdt,
-                                                     slots_sorted=srt)
-                            self._stage("layout", t_e0 - t0)
-                            self._stage("enqueue",
-                                        time.perf_counter() - t_e0)
-                        item = ("digest", counts, start, cn,
-                                (uidx, rank, u), t0, rec, [uw])
-                    else:
-                        size = _bucket_pow2(cn)
-                        words = self._staging.take((size,), np.uint32)
-                        words[cn:] = 0xFFFFFFFF
-                        if not rebuild_words_into(uwords, uidx, rank, rb,
-                                                  words[:cn]):
-                            words[:cn] = rebuild_words(uwords, uidx, rank, rb)
-                        lid_lane = lid if not multi_lid else _pad_tail(
-                            l_chunk, size, 0, np.int32)
-                        if rec is not None:
-                            rec["rebuild_s"] = round(
-                                time.perf_counter() - t_prep, 6)
-                            t_prep = time.perf_counter()
-                        t_e0 = time.perf_counter()
-                        bits = bits_dispatch(words, lid_lane, now)
-                        self._stage("layout", t_e0 - t0)
-                        self._stage("enqueue", time.perf_counter() - t_e0)
-                        item = ("bits", bits, start, cn, None, t0, rec,
-                                [words])
+                            size = _bucket_pow2(cn)
+                            words = self._staging.take((size,), np.uint32)
+                            words[cn:] = 0xFFFFFFFF
+                            if not rebuild_words_into(uwords, uidx, rank, rb,
+                                                      words[:cn]):
+                                words[:cn] = rebuild_words(uwords, uidx, rank, rb)
+                            lid_lane = lid if not multi_lid else _pad_tail(
+                                l_chunk, size, 0, np.int32)
+                            lay.end()
+                            with self._span("enqueue", ci):
+                                bits = bits_dispatch(words, lid_lane, now)
+                            item = ("bits", bits, start, cn, None, lay.t0,
+                                    rec, [words], ci)
                     if rec is not None:
                         rec["dispatch_s"] = round(
-                            time.perf_counter() - t_prep, 6)
+                            time.perf_counter() - lay.t0, 6)
                 # Grow the next chunk toward the wire budget at this chunk's
                 # measured bytes/request (skewed streams compact hard in
                 # digest mode, so their chunks grow to _RELAY_CHUNK_MAX and
@@ -1951,7 +2006,7 @@ class TpuBatchedStorage(RateLimitStorage):
                 else:
                     wire_b = (digest_bpu * u + 8 * n_delta if digest
                               else words_bpr * cn)
-                host_span = time.perf_counter() - t_a0 - t_assign
+                host_span = time.perf_counter() - waited.t1
                 with tot["_lock"]:
                     tot["wire"] += wire_b
                     tot["chunks"] += 1
@@ -1981,12 +2036,13 @@ class TpuBatchedStorage(RateLimitStorage):
                     cursor.grow(int(min(max(budget / bpr, _RELAY_CHUNK),
                                         _RELAY_CHUNK_MAX)))
                 start += cn
+                ci += 1
                 if start < n:
                     # Prefetch the next chunk's assignment on the worker: it
                     # runs (GIL-free C walk) while this chunk's drain blocks
                     # in its (GIL-free) fetch on the drain pool.
                     fut = self._assign_pool().submit(
-                        timed_assign, start, cursor.peek(n - start))
+                        timed_assign, start, cursor.peek(n - start), ci)
                 # Concurrent drain: the fetch cycle of this chunk overlaps
                 # the next chunks' walks AND the other in-flight fetches'
                 # round trips (ROUND_NOTES r5: serial cycles 688 ms vs
@@ -2000,7 +2056,8 @@ class TpuBatchedStorage(RateLimitStorage):
                     lambda res: (res[0] >> np.uint32(rb + 1)).astype(
                         np.int32))
             drains.finish(swallow=True)  # no-op on the normal path
-        self._plan_finish(plan_key, plan, pipelined, n, tot, t_pass0)
+        with self._span("plan"):
+            self._plan_finish(plan_key, plan, pipelined, n, tot, t_pass0)
         return out
 
     def _stream_weighted(self, algo, lid, assign_uniques, n, permits,
@@ -2039,21 +2096,22 @@ class TpuBatchedStorage(RateLimitStorage):
         drains = _DrainSet(self._drain_pool())
 
         def drain(kind, handle, start, count, extra, t0, rec):
-            tf0 = time.perf_counter()
+            with self._span("fetch") as fetch:
+                arr = np.asarray(handle)
+                if kind == "weighted":
+                    arr = np.unpackbits(arr)
+                elif kind != "flat":
+                    arr = np.ascontiguousarray(arr)
             if kind == "weighted_coal":
                 # Coalesced digest: per-unique allowed counts; the
                 # prefix-allow closed form makes ``rank < counts[uidx]``
                 # the exact arrival-order reconstruction (same C helper
                 # as the unit-permit digest drain).
-                arr = np.ascontiguousarray(np.asarray(handle))
-                tf1 = time.perf_counter()
                 from ratelimiter_tpu.engine.native_index import relay_decide
 
                 uidx, rank, u = extra
                 got = relay_decide(arr[:u], uidx, rank)
             elif kind == "weighted_native":
-                arr = np.ascontiguousarray(np.asarray(handle))
-                tf1 = time.perf_counter()
                 from ratelimiter_tpu.engine.native_index import (
                     weighted_decide,
                 )
@@ -2061,25 +2119,17 @@ class TpuBatchedStorage(RateLimitStorage):
                 roff, spos32, uidx, rank = extra
                 got = weighted_decide(arr, roff, spos32, uidx, rank)
             elif kind == "weighted":
-                flat_bits = np.unpackbits(np.asarray(handle))
-                tf1 = time.perf_counter()
                 pos = extra  # roff[rank] + spos per request
-                got = flat_bits[pos].astype(bool)
+                got = arr[pos].astype(bool)
             else:  # flat-fallback slice
-                arr = np.asarray(handle)
-                tf1 = time.perf_counter()
                 got = np.unpackbits(arr)[:count].astype(bool)
-            self._stage("fetch", tf1 - tf0)
             out[start:start + count] = got
             dt_us = (time.perf_counter() - t0) * 1e6
             n_allowed = int(got.sum())
             with tot["_lock"]:
-                tot["fetch_s"] += tf1 - tf0
                 if rec is not None:
                     rec["fetch_s"] = round(
-                        rec.get("fetch_s", 0) + (tf1 - tf0), 6)
-                    rec["fetch_at"] = [round(tf0 - t_pass0, 6),
-                                       round(tf1 - t_pass0, 6)]
+                        rec.get("fetch_s", 0) + fetch.secs, 6)
                 self._record_dispatch(algo, count, n_allowed, dt_us,
                                       path=f"relay_w|{kind}", lid=lid)
 
@@ -2310,11 +2360,9 @@ class TpuBatchedStorage(RateLimitStorage):
         rec_lock = threading.Lock()
 
         def drain(handle, start, count, t0, rec):
-            tf0 = time.perf_counter()
-            arr = np.asarray(handle)  # the one blocking fetch
-            tf1 = time.perf_counter()
-            dt_us = (tf1 - t0) * 1e6
-            self._stage("fetch", tf1 - tf0)
+            with self._span("fetch") as fetch:
+                arr = np.asarray(handle)  # the one blocking fetch
+            dt_us = (fetch.t1 - t0) * 1e6
             if k_scan:  # uint8[k, cap//8]
                 got = np.unpackbits(arr, axis=1).reshape(-1)[:count]
                 got = got.astype(bool)
@@ -2324,7 +2372,7 @@ class TpuBatchedStorage(RateLimitStorage):
             n_allowed = int(got.sum())
             with rec_lock:
                 if rec is not None:
-                    rec["fetch_s"] = round(tf1 - tf0, 6)
+                    rec["fetch_s"] = round(fetch.secs, 6)
                 self._record_dispatch(algo, count, n_allowed, dt_us,
                                       path="flat|scan" if k_scan
                                       else "flat|sorted",
@@ -2338,13 +2386,13 @@ class TpuBatchedStorage(RateLimitStorage):
                 # partial chunk doesn't ship k_scan's worth of padding lanes.
                 k_i = (min(k_scan, -(-cn // _FLAT_MAX_LANES)) if k_scan else 0)
                 pad_n = k_i * _FLAT_MAX_LANES if k_i else super_n
-                t_a0 = time.perf_counter()
-                if fut is not None:
-                    slots, clears = fut.result()
-                    fut = None
-                else:
-                    slots, clears = assign(start, cn)
-                t_assign = time.perf_counter() - t_a0
+                with self._span("index") as waited:
+                    if fut is not None:
+                        slots, clears = fut.result()
+                        fut = None
+                    else:
+                        slots, clears = assign(start, cn)
+                t_assign = waited.secs
                 lanes = 4 + (np.dtype(p_dtype).itemsize
                              if permits is not None else 0) + (
                     4 if multi_lid else 0)
@@ -2363,22 +2411,20 @@ class TpuBatchedStorage(RateLimitStorage):
                     p_flat = None if permits is None else _pad_tail(
                         permits[start:start + cn], pad_n, 1, p_dtype)
                     now = self._monotonic_now()
-                    t0 = time.perf_counter()
-                    if k_i:
-                        bits = dispatch(
-                            slots.reshape(k_i, _FLAT_MAX_LANES),
-                            lid_flat if not multi_lid
-                            else lid_flat.reshape(k_i, _FLAT_MAX_LANES),
-                            None if p_flat is None
-                            else p_flat.reshape(k_i, _FLAT_MAX_LANES),
-                            np.full(k_i, now, dtype=np.int64))
-                    else:
-                        bits = dispatch(slots, lid_flat, p_flat, now)
-                    self._stage("index", t_assign)
-                    self._stage("enqueue", time.perf_counter() - t0)
+                    with self._span("enqueue") as enq:
+                        if k_i:
+                            bits = dispatch(
+                                slots.reshape(k_i, _FLAT_MAX_LANES),
+                                lid_flat if not multi_lid
+                                else lid_flat.reshape(k_i, _FLAT_MAX_LANES),
+                                None if p_flat is None
+                                else p_flat.reshape(k_i, _FLAT_MAX_LANES),
+                                np.full(k_i, now, dtype=np.int64))
+                        else:
+                            bits = dispatch(slots, lid_flat, p_flat, now)
+                    t0 = enq.t0
                 if rec is not None:
-                    rec["host_s"] = round(time.perf_counter() - t_a0 - t_assign,
-                                          6)
+                    rec["host_s"] = round(time.perf_counter() - waited.t1, 6)
                 nxt = start + super_n
                 if nxt < n:
                     # Prefetch the next super-batch's assignment (see
@@ -2750,30 +2796,28 @@ class TpuBatchedStorage(RateLimitStorage):
             pinned_local = None
             dispatched = False
             try:
-                tw0 = time.perf_counter()
-                try:
-                    if key_kind != "ints":
-                        uw, uidx, rank, ev = sub.assign_batch_fps_uniques(
-                            h1_s, h2_s, rb, pinned=pins_s, hold_pins=True)
-                    elif multi_lid:
-                        uw, uidx, rank, ev = (
-                            sub.assign_batch_ints_multi_uniques(
-                                keys_s, l_s, rb, pinned=pins_s,
-                                hold_pins=True))
-                    else:
-                        uw, uidx, rank, ev = sub.assign_batch_ints_uniques(
-                            keys_s, lid, rb, pinned=pins_s, hold_pins=True)
-                except Exception as exc:  # noqa: BLE001
-                    # Lanes that assigned before the failure are already
-                    # remapped in the index: their evicted slots must be
-                    # zeroed even though nothing dispatches (ADVICE r3).
-                    pc = consume_pending_clears(exc, 0)
-                    if len(pc):
-                        self._clear_shard(algo, s, pc)
-                    raise
-                walk_s = time.perf_counter() - tw0
-                ctx["walk"][s] = walk_s
-                self._stage("index", walk_s)
+                with self._span("index") as walk:
+                    try:
+                        if key_kind != "ints":
+                            uw, uidx, rank, ev = sub.assign_batch_fps_uniques(
+                                h1_s, h2_s, rb, pinned=pins_s, hold_pins=True)
+                        elif multi_lid:
+                            uw, uidx, rank, ev = (
+                                sub.assign_batch_ints_multi_uniques(
+                                    keys_s, l_s, rb, pinned=pins_s,
+                                    hold_pins=True))
+                        else:
+                            uw, uidx, rank, ev = sub.assign_batch_ints_uniques(
+                                keys_s, lid, rb, pinned=pins_s, hold_pins=True)
+                    except Exception as exc:  # noqa: BLE001
+                        # Lanes that assigned before the failure are already
+                        # remapped in the index: their evicted slots must be
+                        # zeroed even though nothing dispatches (ADVICE r3).
+                        pc = consume_pending_clears(exc, 0)
+                        if len(pc):
+                            self._clear_shard(algo, s, pc)
+                        raise
+                ctx["walk"][s] = walk.secs
                 if len(ev):
                     # Stream-order clear path: this lane is a FIFO, so
                     # the clear precedes this chunk's dispatch in this
@@ -2782,52 +2826,49 @@ class TpuBatchedStorage(RateLimitStorage):
                 u = len(uw)
                 ctx["u"][s] = u
                 pinned_local = (uw >> np.uint32(rb + 1)).astype(np.int32)
-                t_l0 = time.perf_counter()
-                digest = (cdt is not None
-                          and digest_bpu * _bucket(max(u, 1))
-                          <= words_bpr * ns)
-                if digest:
-                    u_pad = _bucket(max(u, 1))
-                    buf = lane.staging.take((u_pad,), np.uint32)
-                    buf[:u] = uw
-                    buf[u:] = 0xFFFFFFFF
-                    lid_lane = lid
-                    if multi_lid:
-                        first = rank == 0
-                        ulids = np.zeros(u_pad, dtype=np.int32)
-                        ulids[uidx[first]] = l_s[first]
-                        lid_lane = ulids
-                    ctx["wire"][s] = digest_bpu * u
-                else:
-                    b_pad = _bucket(max(ns, 1))
-                    buf = lane.staging.take((b_pad,), np.uint32)
-                    if not rebuild_words_into(uw, uidx, rank, rb,
-                                              buf[:ns]):
-                        buf[:ns] = rebuild_words(uw, uidx, rank, rb)
-                    buf[ns:] = 0xFFFFFFFF
-                    lid_lane = lid
-                    if multi_lid:
-                        lid_lane = np.zeros(b_pad, dtype=np.int32)
-                        lid_lane[:ns] = l_s
-                    ctx["wire"][s] = words_bpr * ns
-                mode = "digest" if digest else "words"
-                ctx["modes"][s] = mode
-                layout_s = time.perf_counter() - t_l0
-                ctx["layout"][s] = layout_s
-                self._stage("layout", layout_s)
+                with self._span("layout") as lay:
+                    digest = (cdt is not None
+                              and digest_bpu * _bucket(max(u, 1))
+                              <= words_bpr * ns)
+                    if digest:
+                        u_pad = _bucket(max(u, 1))
+                        buf = lane.staging.take((u_pad,), np.uint32)
+                        buf[:u] = uw
+                        buf[u:] = 0xFFFFFFFF
+                        lid_lane = lid
+                        if multi_lid:
+                            first = rank == 0
+                            ulids = np.zeros(u_pad, dtype=np.int32)
+                            ulids[uidx[first]] = l_s[first]
+                            lid_lane = ulids
+                        ctx["wire"][s] = digest_bpu * u
+                    else:
+                        b_pad = _bucket(max(ns, 1))
+                        buf = lane.staging.take((b_pad,), np.uint32)
+                        if not rebuild_words_into(uw, uidx, rank, rb,
+                                                  buf[:ns]):
+                            buf[:ns] = rebuild_words(uw, uidx, rank, rb)
+                        buf[ns:] = 0xFFFFFFFF
+                        lid_lane = lid
+                        if multi_lid:
+                            lid_lane = np.zeros(b_pad, dtype=np.int32)
+                            lid_lane[:ns] = l_s
+                        ctx["wire"][s] = words_bpr * ns
+                    mode = "digest" if digest else "words"
+                    ctx["modes"][s] = mode
+                ctx["layout"][s] = lay.secs
                 if stop.is_set():  # a sibling failed after our assign
                     return
-                t0 = time.perf_counter()
-                if digest:
-                    handle = eng.relay_shard_dispatch(
-                        algo, s, "counts", buf, lid_lane, now, cdt)
-                else:
-                    handle = eng.relay_shard_dispatch(
-                        algo, s, "bits", buf, lid_lane, now)
-                dispatched = True
-                enq_s = time.perf_counter() - t0
-                ctx["enq"][s] = enq_s
-                self._stage("enqueue", enq_s)
+                with self._span("enqueue") as enq:
+                    if digest:
+                        handle = eng.relay_shard_dispatch(
+                            algo, s, "counts", buf, lid_lane, now, cdt)
+                    else:
+                        handle = eng.relay_shard_dispatch(
+                            algo, s, "bits", buf, lid_lane, now)
+                    dispatched = True
+                t0 = enq.t0
+                ctx["enq"][s] = enq.secs
             except Exception as exc:  # noqa: BLE001
                 fail(ci, s, exc)
                 return
@@ -2843,10 +2884,8 @@ class TpuBatchedStorage(RateLimitStorage):
                       rank=rank, pos_s=pos_s, ns=ns, s=s, start=start,
                       t0=t0, ctx=ctx):
                 try:
-                    tf0 = time.perf_counter()
-                    arr = np.asarray(handle)
-                    tf1 = time.perf_counter()
-                    self._stage("fetch", tf1 - tf0)
+                    with self._span("fetch") as fetch:
+                        arr = np.asarray(handle)
                     if mode == "digest":
                         # Fused reconstruct + unscatter straight into the
                         # output suffix (one C pass).
@@ -2860,9 +2899,9 @@ class TpuBatchedStorage(RateLimitStorage):
                     if rec is not None:
                         with ctx["lock"]:
                             rec["fetch_s"] = round(
-                                max(rec.get("fetch_s", 0.0), tf1 - tf0), 6)
+                                max(rec.get("fetch_s", 0.0), fetch.secs), 6)
                     self._record_dispatch(algo, ns, int(alw),
-                                          (tf1 - t0) * 1e6,
+                                          (fetch.t1 - t0) * 1e6,
                                           path=f"sharded|{mode}", shard=s,
                                           lid=None if multi_lid else lid)
                 finally:
@@ -2926,26 +2965,25 @@ class TpuBatchedStorage(RateLimitStorage):
         try:
             while start < n and not stop.is_set():
                 cn = min(chunk, n - start)
-                t_r0 = time.perf_counter()
                 pack_s = 0.0
                 h1st = h2st = kst = None
                 if key_kind == "ints":
-                    kchunk = key_ids[start:start + cn]
-                    shard, order, counts, kst = self._route_sharded(
-                        eng, kchunk=kchunk)
+                    with self._span("route") as route:
+                        kchunk = key_ids[start:start + cn]
+                        shard, order, counts, kst = self._route_sharded(
+                            eng, kchunk=kchunk)
                 else:
-                    t_p0 = time.perf_counter()
-                    fp = hash_str_keys(key_ids, lid, start, cn)
-                    if fp is None:
-                        raise RuntimeError(
-                            "native string hashing unavailable mid-stream "
-                            "(mutated key list?)")
-                    pack_s = time.perf_counter() - t_p0
-                    self._stage("pack", pack_s)
-                    shard, order, counts, h1st, h2st = self._route_sharded(
-                        eng, h1=fp[0], h2=fp[1])
-                route_s = time.perf_counter() - t_r0 - pack_s
-                self._stage("route", route_s)
+                    with self._span("pack") as pack:
+                        fp = hash_str_keys(key_ids, lid, start, cn)
+                        if fp is None:
+                            raise RuntimeError(
+                                "native string hashing unavailable "
+                                "mid-stream (mutated key list?)")
+                    pack_s = pack.secs
+                    with self._span("route") as route:
+                        shard, order, counts, h1st, h2st = (
+                            self._route_sharded(eng, h1=fp[0], h2=fp[1]))
+                route_s = route.secs
                 offs = np.zeros(n_sh + 1, dtype=np.int64)
                 np.cumsum(counts, out=offs[1:])
                 l_chunk = lid_arr[start:start + cn] if multi_lid else None
@@ -3398,16 +3436,14 @@ class TpuBatchedStorage(RateLimitStorage):
         (plan, pipelined, tot, timed_assign, t_pass0)."""
         plan = self._chunk_plans.get(plan_key)
         pipelined = plan is not None and plan["kind"] == "pipelined"
-        tot = {"walk_s": 0.0, "wire": 0.0, "fetch_s": 0.0, "chunks": 0,
+        tot = {"walk_s": 0.0, "wire": 0.0, "chunks": 0,
                "device_s": 0.0, "digest_chunks": 0, "host_s": 0.0,
                "cu": [], "_lock": threading.Lock()}
 
-        def timed_assign(s0, cnt):
-            ta = time.perf_counter()
-            r = assign_uniques(s0, cnt)
-            dt = time.perf_counter() - ta
-            tot["walk_s"] += dt
-            self._stage("index", dt)
+        def timed_assign(s0, cnt, chunk=None):
+            with self._span("index", chunk) as walk:
+                r = assign_uniques(s0, cnt)
+            tot["walk_s"] += walk.secs
             return r
 
         return plan, pipelined, tot, timed_assign, time.perf_counter()
@@ -3492,7 +3528,8 @@ class TpuBatchedStorage(RateLimitStorage):
             yield
         finally:
             if hasattr(index, "unpin_batch") and len(slots):
-                index.unpin_batch(slots)
+                with self._span("clear"):
+                    index.unpin_batch(slots)
 
     def _clear_slots(self, algo: str, slots) -> None:
         """Single choke point for zeroing evicted/reset slots.
@@ -3559,12 +3596,13 @@ class TpuBatchedStorage(RateLimitStorage):
             rec.anomaly("slow_dispatch", dt_us,
                         algo=algo, batch=n, path=path, **extra)
 
-    def _stage(self, stage: str, secs: float) -> None:
-        """Record one chunk's seconds in a pipeline-stage timer
-        (pack/index/layout/enqueue/fetch; no-op with observability off)."""
+    def _span(self, stage: str, chunk: int | None = None) -> _Span:
+        """The span of one stream stage, ``ratelimiter.stream.<stage>``
+        (ARCHITECTURE §13a), feeding the stage's timer where it has one
+        (no timer with observability off)."""
         t = self._stage_timers
-        if t is not None:
-            t[stage].record_us(secs * 1e6)
+        return _Span(f"ratelimiter.stream.{stage}",
+                     None if t is None else t.get(stage), chunk)
 
     def _stream_rec(self, path: str, **fields):
         """One optional per-chunk instrumentation record: appends to
