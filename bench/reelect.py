@@ -70,7 +70,7 @@ def refresh_elections() -> dict:
     happened to probe — on CPU the pallas kernels are unsupported (no
     probe fires, by design), so the report would be empty there.  This
     resolves each electable path directly against the now-cleared disk
-    cache: the pallas settle (micro / block_scatter / relay_fused — a
+    cache: the pallas settle (micro / relay_fused, and the tile sweep's probe — a
     no-op off-TPU), the device-journal placement (measures on every
     backend), and the device step-rate probe the chunk scheduler elects
     plans from.  Runs in a child (``--refresh``): this parent stays off
@@ -86,7 +86,7 @@ def refresh_elections() -> dict:
     enable_compile_cache()
     election.reset_for_tests()       # drop in-process memos too
     device_rates._mem_cache.clear()
-    pallas_pkg.settle_all()          # TPU: micro/block_scatter/relay_fused
+    pallas_pkg.settle_all()          # TPU: micro/relay_fused/sweep probe
     rlog.device_journal_elected()    # measures host-vs-device everywhere
     rates = device_rates.get_device_rates()
     return {"platform": jax.default_backend(),

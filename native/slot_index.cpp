@@ -1056,53 +1056,56 @@ int32_t rl_weighted_layout(const uint32_t* uwords, int64_t u,
   return 0;
 }
 
-// Sort a uniques batch by SLOT (radix on the word's slot field) and
-// remap uidx accordingly — in place.  Slot-sorted digests let the
-// device scatter run as a dense block sweep (ops/pallas/block_scatter
-// presorted path) instead of XLA's ~45 ns/index generic scatter, and
-// the gather ride ascending addresses.  Slots are unique within a
-// batch, so stability is irrelevant; 2x11-bit LSD radix passes cover
-// the <= 2^22 slot ids every engine geometry produces (wider slot
-// fields fall back to more passes).  O(u) per pass + O(n) remap.
+// Sort a uniques batch by SLOT and remap uidx accordingly — in place.
+// Slot-sorted digests let the device scatter run as a tile sweep
+// (ops/pallas/block_scatter presorted path) instead of XLA's
+// ~45-90 ns/index generic scatter, and the gather ride ascending
+// addresses.  Slots are unique within a batch, so a unique's position
+// is its slot's rank among the batch's slots: one bit per slot in a
+// bitmap, a popcount prefix per 64-slot word, then every word goes
+// straight to its place.  The scratch is kept per thread (the walk
+// pool's threads and the caller sort), so a call faults in no fresh
+// pages.  O(u + max slot / 64) + O(n) remap.
 int32_t rl_sort_uniques(uint32_t* uwords, int64_t u, int32_t rank_bits,
                         int32_t* uidx, int64_t n) {
   if (u <= 1) return 0;
+  static thread_local std::vector<uint64_t> bits;
+  static thread_local std::vector<int32_t> prefix, inv;
+  static thread_local std::vector<uint32_t> placed;
   const int shift = rank_bits + 1;
-  std::vector<uint32_t> tmp_w(u);
-  std::vector<int32_t> ord(u), ord_tmp(u);
-  for (int64_t i = 0; i < u; i++) ord[i] = static_cast<int32_t>(i);
   uint32_t max_slot = 0;
   for (int64_t i = 0; i < u; i++) {
     uint32_t s = uwords[i] >> shift;
     if (s > max_slot) max_slot = s;
   }
-  const int kBits = 11;
-  const uint32_t kMask = (1u << kBits) - 1u;
-  int passes = 1;
-  while (passes * kBits < 32 && (max_slot >> (passes * kBits)) != 0)
-    passes++;
-  std::vector<int64_t> cnt(1u << kBits);
-  for (int p = 0; p < passes; p++) {
-    const int sh = shift + p * kBits;
-    std::fill(cnt.begin(), cnt.end(), 0);
-    for (int64_t i = 0; i < u; i++) cnt[(uwords[ord[i]] >> sh) & kMask]++;
-    int64_t acc = 0;
-    for (uint32_t b = 0; b <= kMask; b++) {
-      int64_t c = cnt[b];
-      cnt[b] = acc;
-      acc += c;
-    }
-    for (int64_t i = 0; i < u; i++)
-      ord_tmp[cnt[(uwords[ord[i]] >> sh) & kMask]++] = ord[i];
-    ord.swap(ord_tmp);
+  const size_t words = (max_slot >> 6) + 1;
+  if (bits.size() < words) {
+    bits.resize(words);
+    prefix.resize(words);
   }
-  // inv[old] = new position; gather words into sorted order.
-  std::vector<int32_t> inv(u);
-  for (int64_t j = 0; j < u; j++) {
-    inv[ord[j]] = static_cast<int32_t>(j);
-    tmp_w[j] = uwords[ord[j]];
+  std::memset(bits.data(), 0, words * sizeof(uint64_t));
+  if (static_cast<int64_t>(inv.size()) < u) {
+    inv.resize(u);
+    placed.resize(u);
   }
-  std::memcpy(uwords, tmp_w.data(), u * sizeof(uint32_t));
+  for (int64_t i = 0; i < u; i++) {
+    uint32_t s = uwords[i] >> shift;
+    bits[s >> 6] |= uint64_t{1} << (s & 63);
+  }
+  int32_t acc = 0;
+  for (size_t w = 0; w < words; w++) {
+    prefix[w] = acc;
+    acc += __builtin_popcountll(bits[w]);
+  }
+  for (int64_t i = 0; i < u; i++) {
+    uint32_t s = uwords[i] >> shift;
+    int32_t p = prefix[s >> 6] +
+                __builtin_popcountll(bits[s >> 6] &
+                                     ((uint64_t{1} << (s & 63)) - 1));
+    inv[i] = p;
+    placed[p] = uwords[i];
+  }
+  std::memcpy(uwords, placed.data(), u * sizeof(uint32_t));
   for (int64_t i = 0; i < n; i++) {
     int32_t ui = uidx[i];
     if (ui >= 0) uidx[i] = inv[ui];

@@ -1,43 +1,39 @@
-"""Pallas TPU dense block-scatter for sorted-unique row updates.
+"""Pallas TPU tile sweep for sorted-unique row updates.
 
-XLA's generic scatter on TPU costs ~45 ns/index, far above the
+XLA's generic scatter on TPU costs ~45-90 ns per index, far above the
 HBM-bandwidth floor for the same bytes.  But the sorted step's scatter
 has structure XLA cannot exploit: the batch is sorted by slot and
 carries at most one surviving write per slot (the segment-last row of
-each sorted duplicate run).  That makes the scatter expressible as a
-DENSE sweep:
+each sorted duplicate run).  That makes the scatter a SWEEP over the
+table in its own layout:
 
-    for each aligned block of T consecutive state rows:
-        the updates touching it sit in a contiguous window of the
-        (compacted, slot-sorted) update array, at most T long
-        -> load block + window into VMEM, select per row, write back
+- A narrow table ``(S, L)`` lives on the TPU as ``{0,1:T(8,128)}``:
+  slots are the minor dimension, so ``state.T`` is ``(L, S)`` row-major,
+  the same bytes, and one (8, 128) tile holds the rows of 128
+  consecutive slots.
+- Each grid step takes a lane-dense block of ``wb`` slots.  Its updates
+  are the window ``[start[i], start[i+1])`` of the sorted update lane
+  (a ``searchsorted`` of the block boundaries, scalar-prefetched).  A
+  window holds at most ``wb`` updates, so one ``wb + 128``-lane window
+  of the update arrays, at a tile-aligned element offset, covers it:
+  the slots in SMEM, the rows lane-major in VMEM.
+- A loop over the window applies each update with one masked store to
+  the 128-slot tile it falls in: the update's row is rotated from its
+  lane in the update window to the slot's lane, and only that lane is
+  stored.  Updates are unique, so order does not matter, and the loop
+  runs ``_UNROLL`` independent updates per iteration.
 
-Pipeline:
-1. Compact: one payload-carrying ``lax.sort`` moves masked-out lanes to
-   the tail (key = slot for live updates, S sentinel otherwise), leaving
-   live updates sorted by slot and unique; the update array is then
-   TRANSPOSED (XLA-side) so the kernel reads (row-vector slots,
-   lane-major rows) — rank-2 friendly shapes for Mosaic.
-2. Window map: ``searchsorted`` of the T-aligned block boundaries over
-   the compacted keys, divided down to block granularity — per state
-   block i a scalar sigma[i] such that update-blocks [sigma[i],
-   sigma[i]+1] cover every update for block i (<= T updates; any exact
-   window start spans at most two aligned T-blocks).
-3. One ``pallas_call`` over the S/T state blocks: per window the kernel
-   builds the (T, T) match matrix t_slot == w_slot and SELECTS each
-   row's matching update by two exact f32 matmuls over the update's
-   16-bit halves (at most one match per row, so every dot-product has
-   at most one nonzero term — exact in f32 regardless of magnitude).
-   Slots are unique and the two windows are disjoint, so summing the
-   per-window selections composes them.
-
-HBM traffic: read S + 2B rows, write S rows — bandwidth-bound instead
-of per-index-bound.  The state output aliases the state input (in-place
-in HBM, composing with the caller's donated buffers).
+HBM traffic: read and write the table once, plus the updates — bound by
+the table's bytes and a few vector ops per update.  Below a batch of
+about one update per 512 table rows, or of 1024 updates, XLA's
+per-index scatter is cheaper than moving the whole table, and
+:func:`supported` says no (the crossovers are in PERF.md §6).  The
+state output aliases the state input (in place in HBM, composing with
+the caller's donated buffers).
 
 Mosaic survival rules baked in (learned on v5e, see also
-ops/pallas/solver.py): rank-2 everything, no 1-D slices/gathers,
-explicit 32-bit literals under jax_enable_x64.
+ops/pallas/solver.py): explicit 32-bit literals, traced with 64-bit
+types off.
 """
 
 from __future__ import annotations
@@ -49,92 +45,103 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-T = 256          # state rows per block; S must divide by this
+T = 256          # slot alignment of tables sized by align_slots
+_TILE = 128      # slots per (8, 128) tile of the transposed table
+_TB = 16384      # slots per sweep block (PERF.md §6: the A/B)
+_UNROLL = 16     # updates per loop iteration (same A/B)
+_ROWS_PER_UPDATE = 512  # fewer updates than rows / this: XLA wins (same)
+_MIN_BATCH = 1024       # and below this many, on any table (same)
 
 _FLAG = os.environ.get("RATELIMITER_BLOCK_SCATTER", "1") == "1"
 _INTERPRET = os.environ.get("RATELIMITER_BLOCK_SCATTER_INTERPRET", "0") == "1"
 _probe_ok: bool | None = None
 
 
-def _select_window(eq_f, rows_ref):
-    """Per-target-row selected update values for one window.
-
-    eq_f: f32[T, T] 0/1 match matrix (at most one 1 per row).
-    rows_ref: i32[lanes, T] window rows, lane-major.
-    Returns (vals u32[T, lanes] — zeros where unmatched, hits f32-exact
-    via 16-bit halves; match f32[T, 1] row match counts).
-    """
-    rows = rows_ref[...]
-    # 16-bit halves in SIGNED i32 arithmetic (Mosaic crashes on
-    # uint32 casts/bitcasts): both halves land in [0, 65535], exact in
-    # f32; the left-shift recombine wraps into the sign bit, which is
-    # exactly the original bit pattern.
-    lo = (rows & jnp.int32(0xFFFF)).astype(jnp.float32)
-    hi = ((rows >> jnp.int32(16)) & jnp.int32(0xFFFF)).astype(jnp.float32)
-    dn = (((1,), (1,)), ((), ()))  # contract window axis of both
-    # HIGHEST precision: the TPU's default bf16 matmul passes would
-    # round the 16-bit halves; the 3-pass f32 mode keeps them exact.
-    lo_s = jax.lax.dot_general(eq_f, lo, dn,
-                               precision=jax.lax.Precision.HIGHEST,
-                               preferred_element_type=jnp.float32)
-    hi_s = jax.lax.dot_general(eq_f, hi, dn,
-                               precision=jax.lax.Precision.HIGHEST,
-                               preferred_element_type=jnp.float32)
-    match = jnp.sum(eq_f, axis=1, keepdims=True)
-    vals = ((hi_s.astype(jnp.int32) << jnp.int32(16))
-            | lo_s.astype(jnp.int32))
-    return vals, match
+def _width(batch: int) -> int:
+    """Sweep block width (slots) for a batch: at most half of it, so that
+    a window (at most one block's width of updates) fits one update
+    window of ``width + _TILE`` lanes."""
+    return min(_TB, batch // 2 // _TILE * _TILE)
 
 
-def _kernel(sigma_ref, state_ref, sl_a_ref, sl_b_ref, rw_a_ref, rw_b_ref,
-            out_ref, *, lanes):
-    del lanes  # shapes carry it
-    from jax.experimental import pallas as pl
-
-    block = state_ref[...]                       # (T, lanes)
-    t_slot = (jnp.int32(T) * pl.program_id(0)
-              + jax.lax.broadcasted_iota(jnp.int32, (T, 1), 0))
-    eq_a = (sl_a_ref[...] == t_slot).astype(jnp.float32)   # (T, T)
-    eq_b = (sl_b_ref[...] == t_slot).astype(jnp.float32)
-    va, ma = _select_window(eq_a, rw_a_ref)
-    vb, mb = _select_window(eq_b, rw_b_ref)
-    # Windows are disjoint and slots unique: at most one nonzero term.
-    vals = va | vb
-    anym = (ma + mb) > 0.0
-    out_ref[...] = jnp.where(anym, vals, block)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _block_scatter(state, upd_slots, upd_rows_t, sigma,
-                   interpret: bool = False):
-    """state (S, L) i32; upd_slots (1, B) i32 compacted sorted keys;
-    upd_rows_t (L, B) i32 lane-major rows; sigma (S/T,) i32 aligned
-    window starts (units of T)."""
+def _kernel(start_ref, state_ref, slot_ref, rows_ref, out_ref, *, n):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    s_rows, lanes = state.shape
-    grid = s_rows // T
-    kernel = functools.partial(_kernel, lanes=lanes)
+    i = pl.program_id(0)
+    lo, hi = start_ref[i], start_ref[i + 1]
+    base = i * out_ref.shape[1]
+    off = _window_offset(lo, n, rows_ref.shape[1])
+    out_ref[...] = state_ref[...]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (out_ref.shape[0], _TILE), 1)
+
+    def update(j):
+        k = j - off
+        slot = slot_ref[0, k]
+        row = rows_ref[:, pl.ds(pl.multiple_of(k & -_TILE, _TILE), _TILE)]
+        # k and j share their lane (off is tile-aligned): rotate it to
+        # the slot's lane and store that lane alone.
+        row = pltpu.roll(row, (slot - j) & (_TILE - 1), 1)
+        # Both starts int32, also where interpret mode discharges this
+        # store with 64-bit types on.
+        dst = (pl.ds(jnp.int32(0), row.shape[0]),
+               pl.ds(pl.multiple_of((slot - base) & -_TILE, _TILE), _TILE))
+        pltpu.store(out_ref.at[dst], row, mask=lane == (slot & (_TILE - 1)))
+
+    def group(g, carry):
+        # _UNROLL independent updates per iteration let their loads,
+        # rotations and stores overlap.  Past the window's end an update
+        # repeats its last one, which stores the same lane again.
+        j0 = lo + g * _UNROLL
+        for u in range(_UNROLL):
+            update(jnp.minimum(j0 + u, hi - 1))
+        return carry
+
+    jax.lax.fori_loop(0, pl.cdiv(hi - lo, _UNROLL), group, jnp.int32(0))
+
+
+def _window_offset(lo, n, w):
+    """First lane of the ``w``-lane update window of a block whose
+    updates start at ``lo``: tile-aligned, and inside the ``n`` lanes."""
+    from jax.experimental import pallas as pl
+
+    return pl.multiple_of(jnp.minimum(lo & -_TILE, n - w), _TILE)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _sweep(state_t, starts, keys, rows_t, interpret: bool = False):
+    """state_t (L, S) i32, the table in its own layout; starts
+    (cdiv(S, wb) + 1,) i32 window starts; keys (1, B) i32 sorted live
+    slots (sentinel S after them); rows_t (L, B) i32 lane-major rows."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    lanes, s_rows = state_t.shape
+    n = keys.shape[1]
+    wb = _width(n)
+    w = wb + _TILE
+
+    def window(i, st):
+        return (0, _window_offset(st[i], n, w))
+
+    el = pl.Element
     spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(grid,),
+        grid=(pl.cdiv(s_rows, wb),),
         in_specs=[
-            pl.BlockSpec((T, lanes), lambda i, sig: (i, 0)),
-            pl.BlockSpec((1, T), lambda i, sig: (0, sig[i])),
-            pl.BlockSpec((1, T), lambda i, sig: (0, sig[i] + 1)),
-            pl.BlockSpec((lanes, T), lambda i, sig: (0, sig[i])),
-            pl.BlockSpec((lanes, T), lambda i, sig: (0, sig[i] + 1)),
+            pl.BlockSpec((lanes, wb), lambda i, st: (0, i)),
+            pl.BlockSpec((el(1), el(w)), window, memory_space=pltpu.SMEM),
+            pl.BlockSpec((el(lanes), el(w)), window),
         ],
-        out_specs=pl.BlockSpec((T, lanes), lambda i, sig: (i, 0)),
+        out_specs=pl.BlockSpec((lanes, wb), lambda i, st: (0, i)),
     )
     return pl.pallas_call(
-        kernel,
+        functools.partial(_kernel, n=n),
         grid_spec=spec,
-        out_shape=jax.ShapeDtypeStruct(state.shape, state.dtype),
+        out_shape=jax.ShapeDtypeStruct(state_t.shape, state_t.dtype),
         input_output_aliases={1: 0},  # state buffer updated in place
         interpret=interpret,
-    )(sigma, state, upd_slots, upd_slots, upd_rows_t, upd_rows_t)
+    )(starts, state_t, keys, rows_t)
 
 
 def scatter_rows(state, sorted_slots, write_mask, rows,
@@ -147,7 +154,6 @@ def scatter_rows(state, sorted_slots, write_mask, rows,
     if interpret is None:
         interpret = _INTERPRET
     s_rows, lanes = state.shape
-    n = sorted_slots.shape[0]
     # Trace with 64-bit disabled: every value here is explicit int32, but
     # under jax_enable_x64 the grid/BlockSpec index plumbing emits i64
     # index arithmetic that crashes the TPU compiler outright (any
@@ -161,15 +167,16 @@ def scatter_rows(state, sorted_slots, write_mask, rows,
 
 
 def _windowed_call(state, key_sorted, upd_rows_t, interpret):
-    """Shared tail of both entry points: block-aligned window map over
-    the sorted key lane, then the pallas_call."""
+    """Shared tail of both entry points: the window starts of every
+    sweep block over the sorted key lane, then the sweep over the
+    transposed table (a bitcast of its TPU layout)."""
     s_rows, _ = state.shape
-    n = key_sorted.shape[0]
-    bounds = jnp.arange(s_rows // T, dtype=jnp.int32) * T
+    wb = _width(key_sorted.shape[0])
+    bounds = jnp.minimum(
+        jnp.arange(-(-s_rows // wb) + 1, dtype=jnp.int32) * wb, s_rows)
     starts = jnp.searchsorted(key_sorted, bounds).astype(jnp.int32)
-    sigma = jnp.clip(starts // T, 0, n // T - 2)
-    return _block_scatter(state, key_sorted.reshape(1, n), upd_rows_t,
-                          sigma, interpret=interpret)
+    return _sweep(state.T, starts, key_sorted.reshape(1, -1), upd_rows_t,
+                  interpret=interpret).T
 
 
 def scatter_rows_presorted(state, sorted_slots, write_mask, rows,
@@ -191,23 +198,25 @@ def scatter_rows_presorted(state, sorted_slots, write_mask, rows,
 
 
 def align_slots(n: int) -> int:
-    """Smallest multiple of the block size T at or above ``n`` — the
-    num_slots alignment that lets the dense sweeps engage (supported()
-    requires state rows %% T == 0).  Benchmarks and deployments that
-    want the presorted digest path should size their tables with
-    this."""
+    """Smallest multiple of T at or above ``n`` — the num_slots
+    alignment that lets the sweep engage (supported() requires whole
+    128-slot tiles).  Benchmarks and deployments that want the presorted
+    digest path should size their tables with this."""
     return -(-int(n) // T) * T
 
 
 def supported(state_shape, batch: int) -> bool:
-    """Static geometry gate: aligned table, window-coverable batch."""
+    """Static gate on the shapes: whole tiles of table and batch, and
+    enough updates that sweeping the table beats XLA's per-index
+    scatter (at least ``_MIN_BATCH``, and one per ``_ROWS_PER_UPDATE``
+    rows: the crossovers measured on v5e)."""
     try:
         from jax.experimental import pallas as pl  # noqa: F401
     except Exception:  # noqa: BLE001
         return False
-    s_rows = state_shape[0]
-    return (s_rows % T == 0 and s_rows // T >= 1
-            and batch >= 2 * T and batch % T == 0)
+    rows = state_shape[0]
+    return (rows % _TILE == 0 and rows >= _TILE and batch % _TILE == 0
+            and batch >= _MIN_BATCH and batch * _ROWS_PER_UPDATE >= rows)
 
 
 def _probe() -> bool:
@@ -244,74 +253,17 @@ def _probe() -> bool:
     return _probe_ok
 
 
-def _measure_ab() -> dict:
-    """Timed A/B of the dense sweep vs XLA's drop-mode scatter at a
-    representative sorted-unique digest shape (chained inside one jit,
-    one fetched checksum — the device_rates.py method)."""
-    import time
-
-    s_rows, b, k_steps = 1 << 17, 1 << 15, 8
-    rng = np.random.default_rng(3)
-    slots = np.sort(rng.choice(s_rows, size=b, replace=False)
-                    ).astype(np.int32)
-    mask = np.ones(b, dtype=bool)
-    slots_j, mask_j = jnp.asarray(slots), jnp.asarray(mask)
-    rows = jnp.asarray(rng.integers(-(1 << 30), 1 << 30, (b, 4), np.int32))
-
-    def xla_scatter(state, rows):
-        widx = jnp.where(mask_j, slots_j, jnp.int32(s_rows))
-        return state.at[widx].set(rows, mode="drop")
-
-    def pallas_scatter(state, rows):
-        return scatter_rows_presorted(state, slots_j, mask_j, rows,
-                                      interpret=_INTERPRET)
-
-    def best_of(fn):
-        import functools as ft
-
-        @ft.partial(jax.jit, donate_argnums=0)
-        def chain(state, rows):
-            def body(i, st):
-                return fn(st, rows + i.astype(jnp.int32))
-
-            st = jax.lax.fori_loop(0, k_steps, body, state)
-            return st, jnp.sum(st[:8].astype(jnp.int64))
-
-        st, acc = chain(jnp.zeros((s_rows, 4), jnp.int32), rows)
-        int(np.asarray(acc))  # compile + settle
-        best = float("inf")
-        for _ in range(3):
-            t0 = time.perf_counter()
-            st, acc = chain(st, rows)
-            int(np.asarray(acc))
-            best = min(best, time.perf_counter() - t0)
-        return best / k_steps
-
-    return {"pallas_s": best_of(pallas_scatter),
-            "xla_s": best_of(xla_scatter),
-            "updates": b, "state_rows": s_rows}
-
-
-def _elected() -> bool:
-    """Measured per-path election (ops/pallas/election.py): the sweep
-    only serves where it beats XLA's per-index scatter on THIS device."""
-    from ratelimiter_tpu.ops.pallas import election
-
-    return election.measured_election("block_scatter", _measure_ab,
-                                      interpret=_INTERPRET)
-
-
 def settle() -> bool:
-    """Resolve the support probe (and the measured election) eagerly
-    (engine init calls this before any step kernel compiles — a probe
-    firing lazily inside another program's lowering would nest remote
-    compiles).  Respects the RATELIMITER_BLOCK_SCATTER kill switch:
-    disabled means no Pallas compile at all."""
+    """Resolve the support probe eagerly (engine init calls this before
+    any step kernel compiles — a probe firing lazily inside another
+    program's lowering would nest compiles).  Respects the
+    RATELIMITER_BLOCK_SCATTER kill switch: disabled means no Pallas
+    compile at all."""
     if not _FLAG:
         return False
     if not (_INTERPRET or jax.default_backend() == "tpu"):
         return False
-    return _probe() and _elected()
+    return _probe()
 
 
 def enabled(state_shape, batch: int) -> bool:
@@ -319,4 +271,4 @@ def enabled(state_shape, batch: int) -> bool:
         return False
     if not (_INTERPRET or jax.default_backend() == "tpu"):
         return False
-    return _probe() and _elected()
+    return _probe()

@@ -5,11 +5,13 @@ put the Pallas micro-batch solver at x0.91 of the XLA path on the very
 traffic it exists to serve, and which backend wins varies by device
 generation and toolchain.  PR 3 gave the solver a one-time timed A/B
 (`solver.py:_micro_election`); this module is that machinery extracted
-so EVERY Pallas-capable path elects the same way:
+so Pallas-capable paths elect the same way:
 
 - ``micro``        — the micro-batch sandwich solver (ops/pallas/solver.py)
-- ``block_scatter``— the dense presorted digest sweep (block_scatter.py)
 - ``relay_fused``  — the fused relay-step kernel (relay_step.py)
+
+The tile sweep (block_scatter.py) has no election: it serves wherever
+its rule on the shapes (``supported``) holds.
 
 Each path registers a measure function returning ``{"pallas_s",
 "xla_s", ...shape keys...}``; the verdict (Pallas serves iff

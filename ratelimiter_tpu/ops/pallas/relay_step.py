@@ -265,8 +265,9 @@ def _div64_by_u32(ph, pl, d):
 # ---------------------------------------------------------------------------
 
 def _f32_dot(a, b, contract_a: int, contract_b: int):
-    """Exact f32 matmul (values < 2^24, at most one nonzero term per
-    output element — same argument as block_scatter._select_window)."""
+    """Exact f32 matmul: with 0/1 selectors that match at most once,
+    every output element has at most one nonzero term, so values below
+    2^24 come out exact."""
     dn = (((contract_a,), (contract_b,)), ((), ()))
     return jax.lax.dot_general(a, b, dn,
                                precision=jax.lax.Precision.HIGHEST,

@@ -259,9 +259,9 @@ def _relay_counts_split(algo_core, packed, table, s3, mwords, lids, now, *,
 
 
 def _scatter_rows(packed, slot, valid, new_rows, slots_sorted):
-    """Unique-row state write: the dense presorted block sweep when the
-    host sorted the uniques by slot (padding decodes to slot >=
-    num_slots, at the tail), else XLA's per-index scatter."""
+    """Unique-row state write: the tile sweep when the host sorted the
+    uniques by slot (padding decodes to slot >= num_slots, at the
+    tail) and the shapes allow it, else XLA's per-index scatter."""
     if slots_sorted:
         from ratelimiter_tpu.ops.scatter import scatter_rows_presorted
 

@@ -241,14 +241,18 @@ def _elect_digest_mode(link_profile, u: int, cn: int, n_delta: int,
     unique, words uploads 4 B/request but downloads 1 BIT per request;
     on a download-degraded link that asymmetry decides high-u/n
     chunks — r5) plus device seconds (the digest rate depending on
-    whether the slot-sorted sweep engages).  Without a profile it falls
+    whether the slot-sorted sweep engages).  Without a profile the device
+    is attached, so a chunk the sorted sweep serves is elected by device
+    seconds alone (per unique against per lane); any other chunk falls
     back to the blended wire-byte constants.  cdt presence is the
     caller's gate."""
+    if rates is None:
+        rates = _FB_RATES
+    if link_profile is None and srt_ok:
+        return u * rates["s_per_unique_sorted"] <= cn * rates["s_per_lane"]
     if link_profile is not None:
         up = max(link_profile[0], 1.0)
         down = max(link_profile[2], 1.0) if len(link_profile) > 2 else up
-        if rates is None:
-            rates = _FB_RATES
         dev_u = rates["s_per_unique_sorted" if srt_ok
                       else "s_per_unique_unsorted"]
         # digest_bpu/words_bpr carry the blended per-lane bytes (incl.
@@ -733,7 +737,8 @@ class TpuBatchedStorage(RateLimitStorage):
         # Per-stage pipeline timers (r6, unconditional since the
         # observability PR): where a stream chunk's seconds go — route
         # (shard binning), pack (string hashing), index (slot walk),
-        # assign (the caller's wait for the walk), layout (host
+        # sort (a digest chunk's slot sort), assign (the caller's wait
+        # for the walk and, on the relay path, the sort), layout (host
         # dispatch prep), enqueue (device dispatch call), fetch (the
         # blocking result read), decide (host reconstruction after the
         # fetch), drain_wait (the caller blocked on drains).  Each is
@@ -744,8 +749,9 @@ class TpuBatchedStorage(RateLimitStorage):
                 s: meter_registry.timer(
                     f"ratelimiter.stream.{s}",
                     f"Stream pipeline {s} stage (us per chunk)")
-                for s in ("route", "pack", "index", "assign", "layout",
-                          "enqueue", "fetch", "decide", "drain_wait")}
+                for s in ("route", "pack", "index", "sort", "assign",
+                          "layout", "enqueue", "fetch", "decide",
+                          "drain_wait")}
         # Reusable dispatch staging buffers shared by every stream loop.
         self._staging = _StagingPool()
         if engine is not None and table is None:
@@ -1711,6 +1717,48 @@ class TpuBatchedStorage(RateLimitStorage):
                 plan_key, assign_uniques)
             rates = self._device_rates()
 
+        def sortable(u):
+            """Whether a digest chunk of ``u`` uniques is slot-sorted:
+            sorting pays off when EITHER sorted device path engages —
+            the tile sweep, or (scalar-lid dispatches only) the fused
+            Pallas relay step the engine elects per device
+            (ops/pallas/relay_step.py)."""
+            fused_ok = (not multi_lid
+                        and hasattr(eng, "_relay_fused_ok")
+                        and eng._relay_fused_ok(algo, _bucket_pow2(u)))
+            return (u >= _SORT_UNIQUES_MIN
+                    and _sort_affordable(self._link_profile, u)
+                    and (fused_ok or _presorted_scatter_usable(
+                        eng, algo, _bucket_pow2(u))))
+
+        def sort(uwords, uidx):
+            """Slot-sort a chunk's uniques in place (uidx remapped).  It
+            feeds the ``sort`` timer and has no span of its own: the
+            caller's time in it counts to the span around it."""
+            from ratelimiter_tpu.engine.native_index import sort_uniques
+
+            t0 = time.perf_counter()
+            done = sort_uniques(uwords, rb, uidx)
+            if self._stage_timers is not None:
+                self._stage_timers["sort"].record_us(
+                    (time.perf_counter() - t0) * 1e6)
+            return done
+
+        def walk(s0, cnt, chunk):
+            """The chunk's walk and, where the chunk is sure to go to
+            the sorted digest step (no link profile), its slot sort:
+            both run on whichever thread walks, so a prefetched chunk
+            sorts behind the device.  The last field says whether the
+            uniques are sorted."""
+            uwords, uidx, rank, clears = timed_assign(s0, cnt, chunk)
+            u = len(uwords)
+            srt = (self._link_profile is None and cdt is not None
+                   and sortable(u) and _elect_digest_mode(
+                       None, u, cnt, 0, digest_bpu, words_bpr, True,
+                       rates=rates)
+                   and sort(uwords, uidx))
+            return uwords, uidx, rank, clears, srt
+
         def drain(mode, handle, start, count, extra, t0, rec, bufs, chunk):
             try:
                 with self._span("fetch", chunk) as fetch:
@@ -1767,11 +1815,11 @@ class TpuBatchedStorage(RateLimitStorage):
                 cn = cursor.next_size(n - start)
                 with self._span("assign", ci) as waited:
                     if fut is not None:
-                        uwords, uidx, rank, clears = fut.result()
+                        uwords, uidx, rank, clears, presorted = fut.result()
                         fut = None
                     else:
-                        uwords, uidx, rank, clears = timed_assign(start, cn,
-                                                                  ci)
+                        uwords, uidx, rank, clears, presorted = walk(
+                            start, cn, ci)
                 t_assign = waited.secs
                 u = len(uwords)
                 pack_s = (getattr(self._index[algo], "str_pack_s", None)
@@ -1817,19 +1865,8 @@ class TpuBatchedStorage(RateLimitStorage):
                             n_delta = _bkt(max(int(fresh.sum()), 1), floor=8)
                         # One sorted-eligibility verdict drives BOTH the
                         # mode election's device rate and the dispatch path
-                        # below — they must never disagree.  Sorting pays
-                        # off when EITHER sorted device path engages: the
-                        # dense presorted sweep, or (scalar-lid dispatches
-                        # only) the fused Pallas relay step the engine
-                        # elects per device (ops/pallas/relay_step.py).
-                        fused_ok = (not multi_lid
-                                    and hasattr(eng, "_relay_fused_ok")
-                                    and eng._relay_fused_ok(
-                                        algo, _bucket_pow2(u)))
-                        srt_ok = (u >= _SORT_UNIQUES_MIN
-                                  and _sort_affordable(self._link_profile, u)
-                                  and (fused_ok or _presorted_scatter_usable(
-                                      eng, algo, _bucket_pow2(u))))
+                        # below — they must never disagree.
+                        srt_ok = sortable(u)
                         digest = cdt is not None and _elect_digest_mode(
                             self._link_profile, u, cn, n_delta, digest_bpu,
                             words_bpr, srt_ok,
@@ -1912,18 +1949,14 @@ class TpuBatchedStorage(RateLimitStorage):
                         elif digest:
                             # Slot-sorted digest: the C index sorts the uniques
                             # in place (uidx remapped — reconstruction is order-
-                            # agnostic) so the device write is a dense sweep.
+                            # agnostic) so the device write is a tile sweep.
                             # srt_ok (shared with the election above) already
                             # gates on the sweep actually engaging — on the
                             # XLA fallback the scatter is order-blind and the
-                            # sort would be pure overhead.
-                            srt = False
-                            if srt_ok:
-                                from ratelimiter_tpu.engine.native_index import (
-                                    sort_uniques,
-                                )
-
-                                srt = sort_uniques(uwords, rb, uidx)
+                            # sort would be pure overhead.  With no link
+                            # profile the walk job has sorted already.
+                            srt = presorted or (srt_ok
+                                                and sort(uwords, uidx))
                             size = _bucket_pow2(u)
                             uw = self._staging.take((size,), np.uint32)
                             uw[:u] = uwords
@@ -1973,8 +2006,12 @@ class TpuBatchedStorage(RateLimitStorage):
                                 with self._span("enqueue", ci):
                                     counts = counts_dispatch(
                                         uw, lid, now, cdt, slots_sorted=srt)
-                            item = ("digest", counts, start, cn,
-                                    (uidx, rank, u), lay.t0, rec, [uw], ci)
+                            # The label says which row write the step used:
+                            # a sorted one (the tile sweep, or the fused
+                            # relay step where elected) or XLA's scatter.
+                            item = ("digest-sorted" if srt else "digest",
+                                    counts, start, cn, (uidx, rank, u),
+                                    lay.t0, rec, [uw], ci)
                         else:
                             size = _bucket_pow2(cn)
                             words = self._staging.take((size,), np.uint32)
@@ -2022,8 +2059,7 @@ class TpuBatchedStorage(RateLimitStorage):
                     else:
                         tot["bpr"] = words_bpr
                 if rec is not None:
-                    rec["mode"] = ("split" if split
-                                   else "digest" if digest else "bits")
+                    rec["mode"] = item[0]
                     rec["wire_bytes"] = int(wire_b)
                     rec["walk_s"] = round(tot["walk_s"], 6)  # cumulative
                     rec["host_s"] = round(host_span, 6)
@@ -2042,7 +2078,7 @@ class TpuBatchedStorage(RateLimitStorage):
                     # runs (GIL-free C walk) while this chunk's drain blocks
                     # in its (GIL-free) fetch on the drain pool.
                     fut = self._assign_pool().submit(
-                        timed_assign, start, cursor.peek(n - start), ci)
+                        walk, start, cursor.peek(n - start), ci)
                 # Concurrent drain: the fetch cycle of this chunk overlaps
                 # the next chunks' walks AND the other in-flight fetches'
                 # round trips (ROUND_NOTES r5: serial cycles 688 ms vs

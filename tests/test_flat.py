@@ -168,7 +168,7 @@ def test_flat_step_through_block_scatter_interpret(table, monkeypatch):
     from ratelimiter_tpu.ops.pallas import block_scatter as bs
 
     rng = np.random.default_rng(13)
-    n = 2 * bs.T
+    n = 4 * bs.T
     S = 4 * bs.T
     big = LimiterTable()
     big.register(RateLimitConfig(max_permits=5, window_ms=1000))

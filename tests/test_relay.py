@@ -670,6 +670,52 @@ def test_sorted_digest_stream_matches_unsorted(monkeypatch):
         np.testing.assert_array_equal(a, b)
 
 
+@pytest.mark.parametrize("algo", ["sw", "tb"])
+def test_uniform_chunk_sorted_digest_matches_words_mode(monkeypatch, algo):
+    """A chunk of distinct keys (u/n = 1, the uniform cell's shape) with
+    no link profile goes to the sorted digest step — the walk job sorts
+    it and the tile sweep (interpret mode) writes its rows — and decides
+    bit-identically to words mode, leaving the same state."""
+    import ratelimiter_tpu.storage.tpu as tpu_mod
+    from ratelimiter_tpu.engine.native_index import native_available
+    from ratelimiter_tpu.ops.pallas import block_scatter
+    from ratelimiter_tpu.storage import TpuBatchedStorage
+
+    if not native_available():
+        pytest.skip("needs the native library")
+    monkeypatch.setattr(block_scatter, "_INTERPRET", True)
+    monkeypatch.setattr(block_scatter, "_probe_ok", None)
+    monkeypatch.setattr(tpu_mod, "_SORT_UNIQUES_MIN", 256)
+    now = [1_000_000]
+    rng = np.random.default_rng(19)
+    cfg = (RateLimitConfig(max_permits=2, window_ms=1000,
+                           enable_local_cache=False) if algo == "sw"
+           else RateLimitConfig(max_permits=2, window_ms=1000,
+                                refill_rate=1.0))
+    stores = []
+    for _ in range(2):
+        st = TpuBatchedStorage(num_slots=1 << 12, clock_ms=lambda: now[0])
+        stores.append((st, st.register_limiter(algo, cfg)))
+    (srt, lid_s), (words, lid_w) = stores
+    srt.stream_stats = stats = []
+    bits = _forced_bits_stream(TpuBatchedStorage._stream_relay)
+    for rep in range(3):
+        ids = rng.permutation(3000)[:1024]  # every id distinct
+        a = srt.acquire_stream_ids(algo, lid_s, ids, None)
+        monkeypatch.setattr(TpuBatchedStorage, "_stream_relay", bits)
+        b = words.acquire_stream_ids(algo, lid_w, ids, None)
+        monkeypatch.undo()
+        monkeypatch.setattr(block_scatter, "_INTERPRET", True)
+        monkeypatch.setattr(tpu_mod, "_SORT_UNIQUES_MIN", 256)
+        np.testing.assert_array_equal(a, b, err_msg=f"rep {rep}")
+        now[0] += 400
+    assert [r["mode"] for r in stats] == ["digest-sorted"] * 3
+    np.testing.assert_array_equal(_state(srt.engine, algo),
+                                  _state(words.engine, algo))
+    for st, _ in stores:
+        st.close()
+
+
 def test_sort_uniques_parity():
     """rl_sort_uniques: words end up slot-ascending, the multiset of
     words is preserved, and the remapped uidx points every request at

@@ -42,10 +42,11 @@ def one_chip():
 @pytest.fixture()
 def compile_for(one_chip):
     """``compile_for(fn, *shapes)`` -> the compiled program's HLO text,
-    with the persistent compilation cache off around the compile."""
+    with the persistent compilation cache off around the compile
+    (``donate`` as jit's ``donate_argnums``)."""
     from jax.experimental.compilation_cache import compilation_cache
 
-    def run(fn, *shapes, x64=True):
+    def run(fn, *shapes, x64=True, donate=()):
         specs = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
                  for shape, dtype in shapes]
         was = jax.config.jax_enable_compilation_cache
@@ -53,7 +54,8 @@ def compile_for(one_chip):
         compilation_cache.reset_cache()
         try:
             with jax.enable_x64(x64):
-                return jax.jit(fn).lower(*specs).compile().as_text()
+                return jax.jit(fn, donate_argnums=donate).lower(
+                    *specs).compile().as_text()
         finally:
             jax.config.update("jax_enable_compilation_cache", was)
             compilation_cache.reset_cache()
@@ -93,13 +95,24 @@ def test_fused_relay_compiles(compile_for, algo, lanes):
     assert "tpu_custom_call" in text
 
 
-def test_block_scatter_compiles(compile_for):
-    from ratelimiter_tpu.ops.pallas.block_scatter import T, _block_scatter
+@pytest.mark.parametrize("rows,lanes,updates", [
+    (12_500_224, 6, 1 << 19),   # sw-api-10m-uniform: 0.5 GB table
+    (1_250_048, 4, 1 << 18),    # tb-burst-1m-zipf
+])
+def test_block_scatter_compiles(compile_for, rows, lanes, updates):
+    """The tile sweep at the stream cells' widths.  The table enters and
+    leaves the kernel as a bitcast of its own layout: no copy of it."""
+    from ratelimiter_tpu.ops.pallas.block_scatter import (
+        scatter_rows_presorted,
+    )
 
-    lanes, updates = 4, 1 << 16
     text = compile_for(
-        functools.partial(_block_scatter, interpret=False),
-        ((S_ROWS, lanes), jnp.int32), ((1, updates), jnp.int32),
-        ((lanes, updates), jnp.int32), ((S_ROWS // T,), jnp.int32),
-        x64=False)
+        functools.partial(scatter_rows_presorted, interpret=False),
+        ((rows, lanes), jnp.int32), ((updates,), jnp.int32),
+        ((updates,), jnp.bool_), ((updates, lanes), jnp.int32), x64=False,
+        donate=0)
     assert "tpu_custom_call" in text
+    table = f"s32[{rows},{lanes}]"
+    assert not [ln for ln in text.splitlines()
+                if table in ln and " copy(" in ln]
+    assert f"s32[{lanes},{rows}]" in text and "bitcast(" in text

@@ -362,13 +362,20 @@ def test_link_probe_and_profile_reset():
 def test_rate_aware_mode_election():
     """_elect_digest_mode: on fast links the sorted digest's cheaper
     device step wins even where its wire cost loses; on slow links wire
-    dominates and the verdict matches the bytes-only fallback."""
+    dominates and the verdict matches the bytes-only fallback.  With no
+    link profile (an attached device) a chunk the sorted sweep serves is
+    elected by device seconds alone; any other falls back to bytes."""
     from ratelimiter_tpu.storage.tpu import _elect_digest_mode
 
     dig_bpu, words_bpr = 6.0, 4.125
     u, cn = 900_000, 1_000_000  # u/n = 0.9: wire alone says words
     assert not _elect_digest_mode(None, u, cn, 0, dig_bpu, words_bpr,
-                                  True)  # bytes-only fallback: words
+                                  False)  # bytes-only fallback: words
+    # Attached, sweep engaged: 25 ns per unique beats 60 ns per lane.
+    assert _elect_digest_mode(None, u, cn, 0, dig_bpu, words_bpr, True)
+    assert not _elect_digest_mode(None, u, cn, 0, dig_bpu, words_bpr, True,
+                                  rates={"s_per_unique_sorted": 70e-9,
+                                         "s_per_lane": 60e-9})
     # 85 MB/s, sorted sweep engaged: device savings flip it to digest.
     assert _elect_digest_mode((85e6, 0.1), u, cn, 0, dig_bpu, words_bpr,
                               True)
