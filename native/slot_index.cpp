@@ -33,6 +33,8 @@
 #include <cstdint>
 #include <cstdlib>
 #include <cstring>
+#include <exception>
+#include <thread>
 #include <vector>
 
 #if defined(__linux__)
@@ -518,6 +520,99 @@ inline int64_t assign_batch_uniques(Index* ix, int64_t n, int32_t rank_bits,
   return u;
 }
 
+// -- Ranged partition routing (engine/partitioned.py) ------------------------
+// The host-partitioned index routes a batch to its partitions and merges
+// the partitions' walks back to request order in contiguous request
+// ranges [bounds[r], bounds[r+1]) that run side by side, each writing
+// only its own slice of the outputs.
+
+// body(r) for every range: range 0 on the calling thread, the others on
+// threads of their own, started together and joined before returning.
+// A range whose thread cannot be started runs on the caller.
+template <typename Body>
+void on_ranges(int32_t n_ranges, const Body& body) {
+  std::vector<std::thread> threads;
+  int32_t started = 1;
+  try {
+    threads.reserve(n_ranges > 1 ? n_ranges - 1 : 0);
+    for (; started < n_ranges; started++) threads.emplace_back(body, started);
+  } catch (const std::exception&) {
+  }
+  for (int32_t r = started; r < n_ranges; r++) body(r);
+  if (n_ranges > 0) body(0);
+  for (auto& t : threads) t.join();
+}
+
+// Partition of each request of [a, b) into part[] and the range's count
+// per partition: the splitmix64 finalizer of an int key (hashed, as
+// rl_shard_route) or a fingerprint's h1 as it is (as rl_route_hashes),
+// modulo n_parts; a power-of-two count masks instead, with the same
+// result.
+void route_range(const uint64_t* keys, int64_t a, int64_t b,
+                 int32_t n_parts, bool hashed, uint8_t* part,
+                 int64_t* counts) {
+  int64_t cnt[256] = {0};
+  const uint64_t np = static_cast<uint64_t>(n_parts);
+  const bool pow2 = (np & (np - 1)) == 0;
+  for (int64_t i = a; i < b; i++) {
+    uint64_t x = keys[i];
+    if (hashed) {
+      x += 0x9E3779B97F4A7C15ULL;
+      x = (x ^ (x >> 30)) * 0xBF58476D1CE4E5B9ULL;
+      x = (x ^ (x >> 27)) * 0x94D049BB133111EBULL;
+      x = x ^ (x >> 31);
+    }
+    const uint32_t p = static_cast<uint32_t>(pow2 ? x & (np - 1) : x % np);
+    part[i] = static_cast<uint8_t>(p);
+    cnt[p]++;
+  }
+  for (int32_t p = 0; p < n_parts; p++) counts[p] = cnt[p];
+}
+
+// dst[cursors[p]++] = src[i] for each request i of [a, b) in partition
+// p = part[i]; src1/dst1 may be null.
+void scatter_range(const uint8_t* part, int64_t a, int64_t b,
+                   int32_t n_parts, const int64_t* cursors,
+                   const uint64_t* src0, uint64_t* dst0,
+                   const uint64_t* src1, uint64_t* dst1) {
+  int64_t cur[256];
+  for (int32_t p = 0; p < n_parts; p++) cur[p] = cursors[p];
+  if (src1 != nullptr) {
+    for (int64_t i = a; i < b; i++) {
+      const int64_t j = cur[part[i]]++;
+      dst0[j] = src0[i];
+      dst1[j] = src1[i];
+    }
+  } else {
+    for (int64_t i = a; i < b; i++) dst0[cur[part[i]]++] = src0[i];
+  }
+}
+
+// dst0[i] = src0[p][j] + add0[p] and dst1[i] = src1[p][j] for each
+// request i of [a, b), where p = part[i] and j = cursors[p]++ is its
+// position in that partition's walk; src1/dst1 may be null.
+void merge_range(const uint8_t* part, int64_t a, int64_t b,
+                 int32_t n_parts, const int64_t* cursors,
+                 const int32_t* const* src0, const int32_t* add0,
+                 int32_t* dst0, const int32_t* const* src1,
+                 int32_t* dst1) {
+  int64_t cur[256];
+  for (int32_t p = 0; p < n_parts; p++) cur[p] = cursors[p];
+  if (src1 != nullptr) {
+    for (int64_t i = a; i < b; i++) {
+      const uint8_t p = part[i];
+      const int64_t j = cur[p]++;
+      dst0[i] = src0[p][j] + add0[p];
+      dst1[i] = src1[p][j];
+    }
+  } else {
+    for (int64_t i = a; i < b; i++) {
+      const uint8_t p = part[i];
+      dst0[i] = src0[p][cur[p]++] + add0[p];
+    }
+  }
+}
+
 }  // namespace
 
 extern "C" {
@@ -955,6 +1050,61 @@ void rl_shard_route(const int64_t* keys, int64_t n, int32_t n_shards,
     acc += out_counts[s];
   }
   for (int64_t i = 0; i < n; i++) out_order[off[out_shard[i]]++] = i;
+}
+
+// Route a batch to n_parts partitions over n_ranges request ranges
+// (at most 256 partitions: the partition lane is uint8).  First each
+// range writes its requests' partitions into part[] and counts them;
+// prefix sums over (range, partition) then give local[r][p], range r's
+// first position in partition p's walk, and offs[0..n_parts], the
+// partitions' offsets in the partition-major copies; last each range
+// copies its keys (and src1, if given) to dst0 (dst1) at those
+// cursors.  Every partition's slice keeps arrival order, as
+// rl_shard_route's stable counting sort.
+void rl_route_ranges(const uint64_t* keys, int32_t hashed, int32_t n_parts,
+                     const int64_t* bounds, int32_t n_ranges,
+                     uint8_t* part, int64_t* local, int64_t* offs,
+                     uint64_t* dst0, const uint64_t* src1,
+                     uint64_t* dst1) {
+  on_ranges(n_ranges, [&](int32_t r) {
+    route_range(keys, bounds[r], bounds[r + 1], n_parts, hashed != 0, part,
+                local + static_cast<int64_t>(r) * n_parts);
+  });
+  std::vector<int64_t> total(n_parts, 0);
+  for (int32_t r = 0; r < n_ranges; r++) {
+    for (int32_t p = 0; p < n_parts; p++) {
+      int64_t& c = local[static_cast<int64_t>(r) * n_parts + p];
+      const int64_t count = c;
+      c = total[p];
+      total[p] += count;
+    }
+  }
+  offs[0] = 0;
+  for (int32_t p = 0; p < n_parts; p++) offs[p + 1] = offs[p] + total[p];
+  on_ranges(n_ranges, [&](int32_t r) {
+    int64_t cursors[256];
+    for (int32_t p = 0; p < n_parts; p++)
+      cursors[p] = offs[p] + local[static_cast<int64_t>(r) * n_parts + p];
+    scatter_range(part, bounds[r], bounds[r + 1], n_parts, cursors, keys,
+                  dst0, src1, dst1);
+  });
+}
+
+// The walks' int32 outputs back to request order over the same ranges:
+// dst0[i] = src0[p][j] + add0[p] and dst1[i] = src1[p][j], where p is
+// request i's partition and j its position in that partition's walk
+// (src0/src1: one array per partition, null where it has no requests;
+// src1/dst1 may be null).
+void rl_merge_ranges(const uint8_t* part, int32_t n_parts,
+                     const int64_t* bounds, int32_t n_ranges,
+                     const int64_t* local, const int32_t* const* src0,
+                     const int32_t* add0, int32_t* dst0,
+                     const int32_t* const* src1, int32_t* dst1) {
+  on_ranges(n_ranges, [&](int32_t r) {
+    merge_range(part, bounds[r], bounds[r + 1], n_parts,
+                local + static_cast<int64_t>(r) * n_parts, src0, add0, dst0,
+                src1, dst1);
+  });
 }
 
 void rl_index_pin(void* h, int32_t slot) {

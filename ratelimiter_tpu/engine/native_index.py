@@ -191,6 +191,14 @@ def _bind(lib) -> None:
     lib.rl_shard_route.argtypes = [
         ctypes.c_void_p, ctypes.c_int64, ctypes.c_int32, ctypes.c_void_p,
         ctypes.c_void_p, ctypes.c_void_p]
+    lib.rl_route_ranges.argtypes = [
+        ctypes.c_void_p, ctypes.c_int32, ctypes.c_int32, ctypes.c_void_p,
+        ctypes.c_int32, ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+        ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p]
+    lib.rl_merge_ranges.argtypes = [
+        ctypes.c_void_p, ctypes.c_int32, ctypes.c_void_p, ctypes.c_int32,
+        ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+        ctypes.c_void_p, ctypes.c_void_p]
     lib.rl_sort_uniques.restype = ctypes.c_int32
     lib.rl_sort_uniques.argtypes = [
         ctypes.c_void_p, ctypes.c_int64, ctypes.c_int32, ctypes.c_void_p,
@@ -689,6 +697,101 @@ def shard_route(key_ids: np.ndarray, n_shards: int):
                        shard.ctypes.data, order.ctypes.data,
                        counts.ctypes.data)
     return shard, order, counts
+
+
+# Ranged partition routing (native/slot_index.cpp: rl_route_ranges,
+# rl_merge_ranges): the route and merge passes of the host-partitioned
+# index's batch walk (engine/partitioned.py).  The C side runs the
+# request ranges [bounds[r], bounds[r+1]) on threads of its own, each
+# writing only its own slice of the outputs.
+
+def _require(arr: np.ndarray, dtype, n: int | None = None) -> None:
+    if (arr.dtype != dtype or not arr.flags["C_CONTIGUOUS"]
+            or (n is not None and len(arr) != n)):
+        raise ValueError(f"expected a C-contiguous {np.dtype(dtype)} array"
+                         + ("" if n is None else f" of {n}"))
+
+
+def _check_bounds(bounds: np.ndarray, n: int) -> None:
+    _require(bounds, np.int64)
+    if (len(bounds) < 2 or bounds[0] != 0 or bounds[-1] != n
+            or np.any(np.diff(bounds) < 0)):
+        raise ValueError("bounds must rise from 0 to the batch length")
+
+
+def route_ranges(lanes, hashed: bool, n_parts: int, bounds: np.ndarray):
+    """Route a batch to ``n_parts`` partitions over request ranges.
+
+    ``lanes`` are one or two C-contiguous 64-bit request lanes;
+    ``lanes[0]`` routes: int keys through splitmix64 like
+    :func:`shard_route` (``hashed``), fingerprint h1s as they are like
+    :func:`route_hashes`.  Returns ``(part, local, offs, copies)``: each
+    request's partition (uint8[n]), each range's first position in every
+    partition (int64[ranges, n_parts]), the partitions' offsets
+    (int64[n_parts + 1]) and the lanes copied partition-major, each
+    partition's slice in arrival order."""
+    n = len(lanes[0])
+    if not 0 < n_parts <= 256 or not 1 <= len(lanes) <= 2:
+        raise ValueError("1..256 partitions and one or two lanes")
+    for lane in lanes:
+        if (lane.dtype.itemsize != 8 or not lane.flags["C_CONTIGUOUS"]
+                or len(lane) != n):
+            raise ValueError("lanes must be C-contiguous 64-bit arrays "
+                             "of one length")
+    _check_bounds(bounds, n)
+    part = np.empty(n, dtype=np.uint8)
+    local = np.empty((len(bounds) - 1, n_parts), dtype=np.int64)
+    offs = np.empty(n_parts + 1, dtype=np.int64)
+    copies = [np.empty(n, dtype=lane.dtype) for lane in lanes]
+    _load_library().rl_route_ranges(
+        lanes[0].ctypes.data, int(bool(hashed)), n_parts, bounds.ctypes.data,
+        len(bounds) - 1, part.ctypes.data, local.ctypes.data,
+        offs.ctypes.data, copies[0].ctypes.data,
+        lanes[1].ctypes.data if len(lanes) > 1 else None,
+        copies[1].ctypes.data if len(lanes) > 1 else None)
+    return part, local, offs, copies
+
+
+def _lane_ptrs(srcs, counts) -> np.ndarray:
+    for s, c in zip(srcs, counts):
+        if c and (s is None or len(s) != c):
+            raise ValueError("one source per partition, as long as the "
+                             "partition's share of the batch")
+        if s is not None:
+            _require(s, np.int32)
+    return np.asarray([0 if s is None else s.ctypes.data for s in srcs],
+                      dtype=np.uintp)
+
+
+def merge_ranges(part: np.ndarray, bounds: np.ndarray, local: np.ndarray,
+                 offs: np.ndarray, src0, add0: np.ndarray,
+                 src1=None) -> tuple:
+    """Inverse of :func:`route_ranges` for the walks' int32 outputs:
+    for request i in partition p = part[i] at position j of that
+    partition's walk, ``out0[i] = src0[p][j] + add0[p]`` and ``out1[i]
+    = src1[p][j]``.  ``src*`` hold one array per partition (None where
+    it has no requests); ``local`` and ``offs`` are route_ranges'.
+    Returns ``(out0, out1)`` (``out1`` None without ``src1``)."""
+    n = len(part)
+    n_parts = len(offs) - 1
+    _require(part, np.uint8)
+    _check_bounds(bounds, n)
+    _require(local, np.int64)
+    _require(add0, np.int32, n_parts)
+    if (local.shape != (len(bounds) - 1, n_parts) or len(src0) != n_parts
+            or offs[-1] != n):
+        raise ValueError("routing and sources disagree on their shape")
+    counts = np.diff(offs)
+    p0 = _lane_ptrs(src0, counts)
+    p1 = None if src1 is None else _lane_ptrs(src1, counts)
+    out0 = np.empty(n, dtype=np.int32)
+    out1 = None if src1 is None else np.empty(n, dtype=np.int32)
+    _load_library().rl_merge_ranges(
+        part.ctypes.data, n_parts, bounds.ctypes.data, len(bounds) - 1,
+        local.ctypes.data, p0.ctypes.data, add0.ctypes.data,
+        out0.ctypes.data, None if p1 is None else p1.ctypes.data,
+        None if p1 is None else out1.ctypes.data)
+    return out0, out1
 
 
 def _split_key(key: Hashable) -> Tuple[int, bytes | int]:

@@ -8,6 +8,14 @@ device-sharded index) lets T ctypes calls run truly in parallel — the C
 calls release the GIL — so batch assignment scales with memory
 parallelism instead of serializing on one probe stream.
 
+A batch call runs in three phases (ARCHITECTURE.md, "Host parallelism"):
+route (each request's partition, then each partition's keys copied into
+one contiguous buffer in arrival order) and merge (the walks' outputs
+back to request order) run in C over contiguous request ranges side by
+side; between them each partition's C walk runs on its own buffer, on
+the pool.  A batch shorter than ``_RANGE_GRAIN`` routes and merges on
+the caller's thread.
+
 Semantics: identical to ShardedSlotIndex's host side — eviction is
 per-partition LRU (a key's slot never migrates between partitions), and
 global slot id = partition * slots_per_part + local slot.  This is the
@@ -22,18 +30,23 @@ shard).
 from __future__ import annotations
 
 import concurrent.futures as cf
+import threading
+import time
 from typing import Hashable, Optional, Set, Tuple
 
 import numpy as np
 
 from ratelimiter_tpu.engine.errors import consume_pending_clears
-from ratelimiter_tpu.engine.native_index import NativeSlotIndex
+from ratelimiter_tpu.engine.native_index import (
+    NativeSlotIndex,
+    hash_str_keys,
+    merge_ranges,
+    route_ranges,
+)
 
-
-def _part_of_int_keys(key_ids: np.ndarray, n_parts: int) -> np.ndarray:
-    from ratelimiter_tpu.parallel.sharded import shard_of_int_keys
-
-    return shard_of_int_keys(key_ids, n_parts)
+# Requests per range of the route and merge passes: a batch is cut into
+# ranges of at least this many requests, at most one per partition.
+_RANGE_GRAIN = 1 << 16
 
 
 def _part_of_key(key, n_parts: int) -> int:
@@ -54,6 +67,8 @@ class PartitionedSlotIndex:
     def __init__(self, num_slots: int, n_parts: int = 4):
         if num_slots % n_parts:
             raise ValueError("num_slots must divide evenly by n_parts")
+        if not 0 < n_parts <= 256:
+            raise ValueError("n_parts must be in 1..256")
         self.num_slots = int(num_slots)
         self.n_parts = int(n_parts)
         self.slots_per_part = self.num_slots // self.n_parts
@@ -61,9 +76,16 @@ class PartitionedSlotIndex:
                        for _ in range(self.n_parts)]
         self._pool = cf.ThreadPoolExecutor(
             self.n_parts, thread_name_prefix="slotidx")
+        self._tls = threading.local()
 
     def close(self) -> None:
         self._pool.shutdown(wait=False)
+
+    def last_phase_s(self) -> Optional[Tuple[float, float]]:
+        """(route, merge) seconds of this thread's last vectorized call,
+        for the stream loop's ``index_route``/``index_merge`` timers;
+        None before the first."""
+        return getattr(self._tls, "phase_s", None)
 
     # -- scalar interface ------------------------------------------------------
     def _local_pins(self, pinned, part):
@@ -95,44 +117,86 @@ class PartitionedSlotIndex:
         return sum(len(p) for p in self._parts)
 
     # -- vectorized interface --------------------------------------------------
-    def _scatter_merge(self, n, parts_pos, results, kind, rank_bits=0):
-        """Merge per-partition outputs back to request order.
+    def _walk(self, lanes, hashed, pinned, run, unpin_of):
+        """Route a batch by partition and run every partition's walk on
+        the pool (GIL released inside the C calls).
 
-        kind 'slots': results are (slots, ev) -> (slots i32[n], clears).
-        kind 'uniques': results are (uwords, uidx, rank, ev) -> global
-        (uwords concat with partition slot offsets folded into the slot
-        field, uidx i32[n] offset per partition, rank i32[n], clears).
-        """
-        spp = self.slots_per_part
-        if kind == "slots":
-            out = np.empty(n, dtype=np.int32)
-            clears: list = []
-            for p, (pos, res) in enumerate(zip(parts_pos, results)):
-                if res is None:
-                    continue
-                slots, ev = res
-                out[pos] = slots + p * spp
-                clears.extend(p * spp + int(e) for e in ev)
-            return out, clears
-        rb = rank_bits
-        uw_all, clears = [], []
-        uidx = np.empty(n, dtype=np.int32)
-        rank = np.empty(n, dtype=np.int32)
-        offset = 0
-        for p, (pos, res) in enumerate(zip(parts_pos, results)):
-            if res is None:
+        ``lanes`` are one or two 64-bit request lanes (keys, lids or
+        fingerprints); ``lanes[0]`` routes, through splitmix64 when
+        ``hashed`` (int keys) or as it is (fingerprint h1s).  The route
+        runs over request ranges side by side (native_index.route_ranges)
+        and leaves each partition's requests in one contiguous slice in
+        arrival order.  ``run(p, *slices, pins)`` walks partition p.
+        ``unpin_of(result) -> local slots`` must be given when the run
+        holds pins, so a partial failure releases them.  Returns the
+        routing the merge needs — (partition lane, range bounds, each
+        range's first position in every partition, partition offsets,
+        route seconds) — and the per-partition results (None where
+        empty)."""
+        t0 = time.perf_counter()
+        n = len(lanes[0])
+        n_ranges = max(1, min(self.n_parts, -(-n // _RANGE_GRAIN)))
+        bounds = np.arange(n_ranges + 1, dtype=np.int64) * n // n_ranges
+        part, local, offs, copies = route_ranges(lanes, hashed,
+                                                 self.n_parts, bounds)
+        route_s = time.perf_counter() - t0
+        futs = []
+        for p in range(self.n_parts):
+            lo, hi = int(offs[p]), int(offs[p + 1])
+            if lo == hi:
+                futs.append(None)
                 continue
-            uw, ui, rk, ev = res
-            # Fold the partition's global slot base into the word's slot
-            # field: slot rides in bits rank_bits+1.. so adding
-            # base << (rank_bits+1) re-addresses it globally.
-            uw_all.append(uw + np.uint32(p * spp << (rb + 1)))
-            uidx[pos] = ui + offset
-            rank[pos] = rk
-            offset += len(uw)
-            clears.extend(p * spp + int(e) for e in ev)
-        uwords = (np.concatenate(uw_all) if uw_all
-                  else np.empty(0, dtype=np.uint32))
+            futs.append(self._pool.submit(
+                run, p, *(c[lo:hi] for c in copies),
+                self._local_pins(pinned, p)))
+        return ((part, bounds, local, offs, route_s),
+                self._collect(futs, unpin_of))
+
+    def _clears(self, results) -> np.ndarray:
+        """Every partition's evictions as global slots, partition-major."""
+        spp = self.slots_per_part
+        evs = [res[-1] + np.int32(p * spp)
+               for p, res in enumerate(results) if res is not None]
+        return (np.concatenate(evs) if evs
+                else np.empty(0, dtype=np.int32))
+
+    def _merge_slots(self, routed, results):
+        """(slots i32[n], clears) in request order from per-partition
+        (slots, evictions)."""
+        t0 = time.perf_counter()
+        *routing, route_s = routed
+        base = (np.arange(self.n_parts, dtype=np.int32)
+                * np.int32(self.slots_per_part))
+        out, _ = merge_ranges(
+            *routing, [None if res is None else res[0] for res in results],
+            base)
+        clears = self._clears(results)
+        self._tls.phase_s = (route_s, time.perf_counter() - t0)
+        return out, clears
+
+    def _merge_uniques(self, routed, results, rank_bits):
+        """(uwords, uidx i32[n], rank i32[n], clears) from per-partition
+        (uwords, uidx, rank, evictions): uwords concatenated
+        partition-major with each partition's slot base folded into the
+        slot field, uidx offset by the uniques of the partitions before."""
+        t0 = time.perf_counter()
+        *routing, route_s = routed
+        spp = self.slots_per_part
+        sizes = [0 if res is None else len(res[0]) for res in results]
+        uoff = np.zeros(self.n_parts, dtype=np.int32)
+        np.cumsum(sizes[:-1], out=uoff[1:])
+        uwords = np.empty(sum(sizes), dtype=np.uint32)
+        for p, res in enumerate(results):
+            if res is not None:
+                # Slot rides in bits rank_bits+1.., so adding
+                # base << (rank_bits+1) re-addresses it globally.
+                np.add(res[0], np.uint32(p * spp << (rank_bits + 1)),
+                       out=uwords[uoff[p]:uoff[p] + sizes[p]])
+        uidx, rank = merge_ranges(
+            *routing, [None if res is None else res[1] for res in results],
+            uoff, [None if res is None else res[2] for res in results])
+        clears = self._clears(results)
+        self._tls.phase_s = (route_s, time.perf_counter() - t0)
         return uwords, uidx, rank, clears
 
     def _collect(self, futs, unpin_of):
@@ -173,51 +237,24 @@ class PartitionedSlotIndex:
             raise err
         return results
 
-    def _parallel(self, key_ids, pinned, run, unpin_of=None):
-        """Split a batch by partition, run per-partition C calls on the
-        pool (GIL released inside), return (parts_pos, results).
-        ``unpin_of(result) -> local slots`` must be given when the run
-        holds pins, so a partial failure releases them.  Routing is one
-        native pass (hash + stable counting sort) when available, so
-        each partition's positions are a contiguous slice of one order
-        array instead of T O(n) mask scans."""
-        from ratelimiter_tpu.engine.native_index import shard_route
-
-        r = shard_route(key_ids, self.n_parts)
-        if r is not None:
-            _, order, counts = r
-            offs = np.zeros(self.n_parts + 1, dtype=np.int64)
-            np.cumsum(counts, out=offs[1:])
-            parts_pos = [order[offs[p]:offs[p + 1]]
-                         for p in range(self.n_parts)]
-        else:
-            parts = _part_of_int_keys(key_ids, self.n_parts)
-            parts_pos = [np.where(parts == p)[0]
-                         for p in range(self.n_parts)]
-        futs = []
-        for p, pos in enumerate(parts_pos):
-            if not len(pos):
-                futs.append(None)
-                continue
-            futs.append(self._pool.submit(
-                run, p, pos, self._local_pins(pinned, p)))
-        return parts_pos, self._collect(futs, unpin_of)
+    @staticmethod
+    def _uslots_of(rank_bits):
+        return lambda res: (res[0] >> np.uint32(rank_bits + 1)).astype(
+            np.int32)
 
     def assign_batch_ints(self, keys: np.ndarray, lid: int,
                           pinned: Optional[Set[int]] = None,
                           hold_pins: bool = False):
         keys = np.ascontiguousarray(keys, dtype=np.int64)
 
-        def run(p, pos, pins):
+        def run(p, keys_p, pins):
             return self._parts[p].assign_batch_ints(
-                keys[pos], lid, pinned=pins, hold_pins=hold_pins)
+                keys_p, lid, pinned=pins, hold_pins=hold_pins)
 
-        parts_pos, results = self._parallel(
-            keys, pinned, run,
-            unpin_of=(lambda res: res[0]) if hold_pins else None)
-        slots, clears = self._scatter_merge(len(keys), parts_pos, results,
-                                            "slots")
-        return slots, np.asarray(clears, dtype=np.int32)
+        routed, results = self._walk(
+            (keys,), True, pinned, run,
+            (lambda res: res[0]) if hold_pins else None)
+        return self._merge_slots(routed, results)
 
     def assign_batch_ints_multi(self, keys: np.ndarray, lids: np.ndarray,
                                 pinned: Optional[Set[int]] = None,
@@ -225,16 +262,14 @@ class PartitionedSlotIndex:
         keys = np.ascontiguousarray(keys, dtype=np.int64)
         lids = np.ascontiguousarray(lids, dtype=np.uint64)
 
-        def run(p, pos, pins):
+        def run(p, keys_p, lids_p, pins):
             return self._parts[p].assign_batch_ints_multi(
-                keys[pos], lids[pos], pinned=pins, hold_pins=hold_pins)
+                keys_p, lids_p, pinned=pins, hold_pins=hold_pins)
 
-        parts_pos, results = self._parallel(
-            keys, pinned, run,
-            unpin_of=(lambda res: res[0]) if hold_pins else None)
-        slots, clears = self._scatter_merge(len(keys), parts_pos, results,
-                                            "slots")
-        return slots, np.asarray(clears, dtype=np.int32)
+        routed, results = self._walk(
+            (keys, lids), True, pinned, run,
+            (lambda res: res[0]) if hold_pins else None)
+        return self._merge_slots(routed, results)
 
     def assign_batch_ints_uniques(self, keys: np.ndarray, lid: int,
                                   rank_bits: int,
@@ -242,16 +277,14 @@ class PartitionedSlotIndex:
                                   hold_pins: bool = False):
         keys = np.ascontiguousarray(keys, dtype=np.int64)
 
-        def run(p, pos, pins):
+        def run(p, keys_p, pins):
             return self._parts[p].assign_batch_ints_uniques(
-                keys[pos], lid, rank_bits, pinned=pins,
-                hold_pins=hold_pins)
+                keys_p, lid, rank_bits, pinned=pins, hold_pins=hold_pins)
 
-        parts_pos, results = self._parallel(
-            keys, pinned, run,
-            unpin_of=(lambda res: (res[0] >> np.uint32(rank_bits + 1)).astype(np.int32)) if hold_pins else None)
-        return self._scatter_merge(len(keys), parts_pos, results, "uniques",
-                                   rank_bits)
+        routed, results = self._walk(
+            (keys,), True, pinned, run,
+            self._uslots_of(rank_bits) if hold_pins else None)
+        return self._merge_uniques(routed, results, rank_bits)
 
     def assign_batch_ints_multi_uniques(self, keys: np.ndarray,
                                         lids: np.ndarray, rank_bits: int,
@@ -260,130 +293,54 @@ class PartitionedSlotIndex:
         keys = np.ascontiguousarray(keys, dtype=np.int64)
         lids = np.ascontiguousarray(lids, dtype=np.uint64)
 
-        def run(p, pos, pins):
+        def run(p, keys_p, lids_p, pins):
             return self._parts[p].assign_batch_ints_multi_uniques(
-                keys[pos], lids[pos], rank_bits, pinned=pins,
+                keys_p, lids_p, rank_bits, pinned=pins,
                 hold_pins=hold_pins)
 
-        parts_pos, results = self._parallel(
-            keys, pinned, run,
-            unpin_of=(lambda res: (res[0] >> np.uint32(rank_bits + 1)).astype(np.int32)) if hold_pins else None)
-        return self._scatter_merge(len(keys), parts_pos, results, "uniques",
-                                   rank_bits)
+        routed, results = self._walk(
+            (keys, lids), True, pinned, run,
+            self._uslots_of(rank_bits) if hold_pins else None)
+        return self._merge_uniques(routed, results, rank_bits)
 
     # Strings: hash the whole window ONCE natively (fingerprints straight
     # off the interned UTF-8 buffers), route by h1 — the exact quantity
     # shard_of_key's string branch computes scalar-side, so both paths
     # agree on a key's partition — and feed each partition its
     # fingerprint slice: the per-partition walks then do zero hashing.
-    # Fallback (no native hasher): the r5 per-key Python routing loop.
-    def _parallel_strs_fp(self, keys, lid, pinned, run_fp, start, n,
-                          unpin_of=None):
-        from ratelimiter_tpu.engine.native_index import (
-            hash_str_keys,
-            route_hashes,
-        )
-
+    def _fps(self, keys, lid, start, count):
+        n = (len(keys) - start) if count is None else count
         fp = hash_str_keys(keys, lid, start, n)
         if fp is None:
-            return None
-        h1, h2 = fp
-        part, order, counts = route_hashes(h1, self.n_parts)
-        offs = np.zeros(self.n_parts + 1, dtype=np.int64)
-        np.cumsum(counts, out=offs[1:])
-        h1st, h2st = h1[order], h2[order]
-        parts_pos = [order[offs[p]:offs[p + 1]]
-                     for p in range(self.n_parts)]
-        futs = []
-        for p, pos in enumerate(parts_pos):
-            if not len(pos):
-                futs.append(None)
-                continue
-            lo, hi = int(offs[p]), int(offs[p + 1])
-            futs.append(self._pool.submit(
-                run_fp, p, h1st[lo:hi], h2st[lo:hi],
-                self._local_pins(pinned, p)))
-        return parts_pos, self._collect(futs, unpin_of)
-
-    def _parallel_strs(self, keys, lid, pinned, run, unpin_of=None):
-        parts = np.fromiter(
-            (_part_of_key((lid, k), self.n_parts) for k in keys),
-            dtype=np.int64, count=len(keys))
-        parts_pos = [np.where(parts == p)[0] for p in range(self.n_parts)]
-        futs = []
-        for p, pos in enumerate(parts_pos):
-            if not len(pos):
-                futs.append(None)
-                continue
-            futs.append(self._pool.submit(
-                run, p, [keys[i] for i in pos], self._local_pins(pinned, p)))
-        return parts_pos, self._collect(futs, unpin_of)
+            raise ValueError(f"bad key window: start={start} count={count}")
+        return fp
 
     def assign_batch_strs(self, keys, lid: int,
                           pinned: Optional[Set[int]] = None,
                           hold_pins: bool = False,
                           start: int = 0, count: int | None = None):
-        n = (len(keys) - start) if count is None else count
-
-        def run_fp(p, h1, h2, pins):
+        def run(p, h1, h2, pins):
             return self._parts[p].assign_batch_fps(
                 h1, h2, pinned=pins, hold_pins=hold_pins)
 
-        unpin = (lambda res: res[0]) if hold_pins else None
-        r = self._parallel_strs_fp(keys, lid, pinned, run_fp,
-                                   start, n, unpin_of=unpin)
-        if r is not None:
-            parts_pos, results = r
-            slots, clears = self._scatter_merge(
-                n, parts_pos, results, "slots")
-            return slots, np.asarray(clears, dtype=np.int32)
-
-        sub_keys = keys if (start == 0 and n == len(keys)) else keys[
-            start:start + n]
-
-        def run(p, sub, pins):
-            return self._parts[p].assign_batch_strs(
-                sub, lid, pinned=pins, hold_pins=hold_pins)
-
-        parts_pos, results = self._parallel_strs(
-            sub_keys, lid, pinned, run, unpin_of=unpin)
-        slots, clears = self._scatter_merge(n, parts_pos, results,
-                                            "slots")
-        return slots, np.asarray(clears, dtype=np.int32)
+        routed, results = self._walk(
+            self._fps(keys, lid, start, count), False, pinned, run,
+            (lambda res: res[0]) if hold_pins else None)
+        return self._merge_slots(routed, results)
 
     def assign_batch_strs_uniques(self, keys, lid: int, rank_bits: int,
                                   pinned: Optional[Set[int]] = None,
                                   hold_pins: bool = False,
                                   start: int = 0,
                                   count: int | None = None):
-        n = (len(keys) - start) if count is None else count
-        unpin = (lambda res: (res[0] >> np.uint32(rank_bits + 1))
-                 .astype(np.int32)) if hold_pins else None
-
-        def run_fp(p, h1, h2, pins):
+        def run(p, h1, h2, pins):
             return self._parts[p].assign_batch_fps_uniques(
                 h1, h2, rank_bits, pinned=pins, hold_pins=hold_pins)
 
-        if all(hasattr(s, "assign_batch_fps_uniques")
-               for s in self._parts):
-            r = self._parallel_strs_fp(keys, lid, pinned, run_fp,
-                                       start, n, unpin_of=unpin)
-            if r is not None:
-                parts_pos, results = r
-                return self._scatter_merge(n, parts_pos, results,
-                                           "uniques", rank_bits)
-
-        sub_keys = keys if (start == 0 and n == len(keys)) else keys[
-            start:start + n]
-
-        def run(p, sub, pins):
-            return self._parts[p].assign_batch_strs_uniques(
-                sub, lid, rank_bits, pinned=pins, hold_pins=hold_pins)
-
-        parts_pos, results = self._parallel_strs(
-            sub_keys, lid, pinned, run, unpin_of=unpin)
-        return self._scatter_merge(n, parts_pos, results, "uniques",
-                                   rank_bits)
+        routed, results = self._walk(
+            self._fps(keys, lid, start, count), False, pinned, run,
+            self._uslots_of(rank_bits) if hold_pins else None)
+        return self._merge_uniques(routed, results, rank_bits)
 
     # -- fingerprint enumeration (checkpoint/restore) --------------------------
     def dump_fp(self):

@@ -737,21 +737,24 @@ class TpuBatchedStorage(RateLimitStorage):
         # Per-stage pipeline timers (r6, unconditional since the
         # observability PR): where a stream chunk's seconds go — route
         # (shard binning), pack (string hashing), index (slot walk),
-        # sort (a digest chunk's slot sort), assign (the caller's wait
-        # for the walk and, on the relay path, the sort), layout (host
-        # dispatch prep), enqueue (device dispatch call), fetch (the
-        # blocking result read), decide (host reconstruction after the
-        # fetch), drain_wait (the caller blocked on drains).  Each is
-        # fed by the span of the same name (_span).
+        # sort (a digest chunk's slot sort), index_route and
+        # index_merge (the partitioned index's route and merge passes
+        # inside the walk), assign (the caller's wait for the walk and,
+        # on the relay path, the sort), layout (host dispatch prep),
+        # enqueue (device dispatch call), fetch (the blocking result
+        # read), decide (host reconstruction after the fetch),
+        # drain_wait (the caller blocked on drains).  Each is fed by the
+        # span of the same name (_span), except sort, index_route and
+        # index_merge, which have no span.
         self._stage_timers = None
         if self._obs:
             self._stage_timers = {
                 s: meter_registry.timer(
                     f"ratelimiter.stream.{s}",
                     f"Stream pipeline {s} stage (us per chunk)")
-                for s in ("route", "pack", "index", "sort", "assign",
-                          "layout", "enqueue", "fetch", "decide",
-                          "drain_wait")}
+                for s in ("route", "pack", "index", "sort", "index_route",
+                          "index_merge", "assign", "layout", "enqueue",
+                          "fetch", "decide", "drain_wait")}
         # Reusable dispatch staging buffers shared by every stream loop.
         self._staging = _StagingPool()
         if engine is not None and table is None:
@@ -1714,7 +1717,7 @@ class TpuBatchedStorage(RateLimitStorage):
                     _bucket_fine(n, floor=_RELAY_CHUNK))
         with self._span("plan"):
             plan, pipelined, tot, timed_assign, t_pass0 = self._plan_setup(
-                plan_key, assign_uniques)
+                plan_key, assign_uniques, self._index[algo])
             rates = self._device_rates()
 
         def sortable(u):
@@ -2175,7 +2178,7 @@ class TpuBatchedStorage(RateLimitStorage):
         plan_key = ("weighted", key_kind, algo,
                     _bucket_fine(n, floor=_RELAY_CHUNK))  # banded, see relay
         plan, pipelined, tot, timed_assign, t_pass0 = self._plan_setup(
-            plan_key, assign_uniques)
+            plan_key, assign_uniques, index)
         rates = self._device_rates()
 
         cursor = _ChunkCursor(plan, pipelined)
@@ -2414,6 +2417,13 @@ class TpuBatchedStorage(RateLimitStorage):
                                       else "flat|sorted",
                                       lid=None if multi_lid else lid)
 
+        index = self._index[algo]
+
+        def timed_assign(start, count):
+            r = assign(start, count)
+            self._record_index_phases(index)
+            return r
+
         fut = None  # prefetched next-chunk assignment (holds pins)
         try:
             for start in range(0, n, super_n):
@@ -2427,7 +2437,7 @@ class TpuBatchedStorage(RateLimitStorage):
                         slots, clears = fut.result()
                         fut = None
                     else:
-                        slots, clears = assign(start, cn)
+                        slots, clears = timed_assign(start, cn)
                 t_assign = waited.secs
                 lanes = 4 + (np.dtype(p_dtype).itemsize
                              if permits is not None else 0) + (
@@ -2436,7 +2446,7 @@ class TpuBatchedStorage(RateLimitStorage):
                     "flat", mode="scan" if k_i else "flat", n=int(cn),
                     assign_s=t_assign, wire_bytes=int(pad_n * lanes))
                 raw_slots = slots
-                with self._pins_released(self._index[algo], raw_slots):
+                with self._pins_released(index, raw_slots):
                     if len(clears):
                         clear(list(clears))
                     slots = _pad_tail(slots, pad_n, -1, np.int32)
@@ -2466,7 +2476,7 @@ class TpuBatchedStorage(RateLimitStorage):
                     # Prefetch the next super-batch's assignment (see
                     # _stream_relay).
                     fut = self._assign_pool().submit(
-                        assign, nxt, min(super_n, n - nxt))
+                        timed_assign, nxt, min(super_n, n - nxt))
                 # Concurrent drain (see _stream_relay): the fetch cycle
                 # overlaps later super-batches' walks and fetches.
                 drains.submit(drain, bits, start, cn, t0, rec)
@@ -3464,11 +3474,12 @@ class TpuBatchedStorage(RateLimitStorage):
                 "kind": "giant", "chunk": 0, "ref": round(serial_pred, 4),
                 "passes": (cur.get("passes", 0) + 1) if cur else 1}
 
-    def _plan_setup(self, plan_key: tuple, assign_uniques):
+    def _plan_setup(self, plan_key: tuple, assign_uniques, index):
         """Shared head of the relay/weighted streaming loops: look up the
         chunk plan, build the measurement accumulator, and wrap the
-        assign closure so the TRUE walk seconds are recorded wherever
-        the walk runs (main thread or prefetch worker).  Returns
+        assign closure so the TRUE walk seconds (and ``index``'s route
+        and merge seconds, where it is partitioned) are recorded
+        wherever the walk runs (main thread or prefetch worker).  Returns
         (plan, pipelined, tot, timed_assign, t_pass0)."""
         plan = self._chunk_plans.get(plan_key)
         pipelined = plan is not None and plan["kind"] == "pipelined"
@@ -3479,6 +3490,7 @@ class TpuBatchedStorage(RateLimitStorage):
         def timed_assign(s0, cnt, chunk=None):
             with self._span("index", chunk) as walk:
                 r = assign_uniques(s0, cnt)
+            self._record_index_phases(index)
             tot["walk_s"] += walk.secs
             return r
 
@@ -3631,6 +3643,17 @@ class TpuBatchedStorage(RateLimitStorage):
         if rec is not None and rec.slo_us > 0.0 and dt_us > rec.slo_us:
             rec.anomaly("slow_dispatch", dt_us,
                         algo=algo, batch=n, path=path, **extra)
+
+    def _record_index_phases(self, index) -> None:
+        """Feed the ``index_route`` and ``index_merge`` timers from the
+        partitioned index's last call on this thread (the thread that
+        just walked); a single index has no such passes."""
+        phases = getattr(index, "last_phase_s", None)
+        if phases is None or self._stage_timers is None:
+            return
+        route_s, merge_s = phases()
+        self._stage_timers["index_route"].record_us(route_s * 1e6)
+        self._stage_timers["index_merge"].record_us(merge_s * 1e6)
 
     def _span(self, stage: str, chunk: int | None = None) -> _Span:
         """The span of one stream stage, ``ratelimiter.stream.<stage>``

@@ -511,6 +511,76 @@ def test_route_hashes_gather_matches_numpy():
         np.testing.assert_array_equal(h2s, h2[want_order])
 
 
+def _cuts(n):
+    """Ways to cut a batch of n requests into ranges: one range; ranges
+    of one request and of none; uneven ranges."""
+    return [np.asarray(c, dtype=np.int64) for c in (
+        [0, n], [0, 1, 1, n // 2, n - 1, n], [0, n // 4, 3 * n // 5, n])]
+
+
+@pytest.mark.parametrize("n_parts", [1, 2, 6, 7, 8, 16])
+def test_range_route_matches_shard_route_and_route_hashes(n_parts):
+    """The ranged route, however the batch is cut into ranges, gives
+    each partition the same requests in the same order, with the same
+    counts, as the one-pass stable routes: shard_route for int keys
+    (power-of-two counts masked, others by %) and route_hashes for
+    fingerprints; a second lane travels with the first."""
+    import ratelimiter_tpu.engine.native_index as ni
+
+    rng = np.random.default_rng(40 + n_parts)
+    n = 5000
+    keys = rng.integers(-(1 << 40), 1 << 40, size=n)
+    h1 = rng.integers(0, 1 << 64, size=n, dtype=np.uint64)
+    lids = rng.integers(0, 1 << 63, size=n).astype(np.uint64)
+    for bounds in _cuts(n):
+        for lane, hashed in ((keys, True), (h1, False)):
+            want = (ni.shard_route(lane, n_parts) if hashed
+                    else ni.route_hashes(lane, n_parts))
+            part, local, offs, copies = ni.route_ranges(
+                (lane, lids), hashed, n_parts, bounds)
+            np.testing.assert_array_equal(part, want[0])
+            np.testing.assert_array_equal(np.diff(offs), want[2])
+            np.testing.assert_array_equal(copies[0], lane[want[1]])
+            np.testing.assert_array_equal(copies[1], lids[want[1]])
+            # Range r's first position in partition p: the requests of
+            # partition p in the ranges before it.
+            for r in range(len(bounds) - 1):
+                np.testing.assert_array_equal(local[r], np.bincount(
+                    want[0][:bounds[r]], minlength=n_parts))
+
+
+@pytest.mark.parametrize("n_parts", [3, 8])
+def test_range_merge_inverts_range_route(n_parts):
+    """rl_merge_ranges brings per-partition outputs back to request
+    order: merging each partition's slice of the routed lane (as int32)
+    reproduces the lane, plus the per-partition addend on the first
+    output only; an empty partition passes None."""
+    import ratelimiter_tpu.engine.native_index as ni
+
+    from ratelimiter_tpu.parallel.sharded import shard_of_int_keys
+
+    rng = np.random.default_rng(50 + n_parts)
+    keys = rng.integers(0, 1 << 30, size=6000)  # fits an int32 output
+    keys = keys[shard_of_int_keys(keys, n_parts) != 1]  # partition 1 empty
+    n = len(keys)
+    for bounds in _cuts(n):
+        part, local, offs, (routed,) = ni.route_ranges(
+            (keys,), True, n_parts, bounds)
+        assert offs[2] == offs[1]
+        lo = [routed[offs[p]:offs[p + 1]].astype(np.int32)
+              if offs[p + 1] > offs[p] else None for p in range(n_parts)]
+        neg = [None if x is None else -x for x in lo]
+        add = np.arange(n_parts, dtype=np.int32) * 1000
+        out0, out1 = ni.merge_ranges(part, bounds, local, offs, lo, add,
+                                     neg)
+        np.testing.assert_array_equal(out0, keys + add[part])
+        np.testing.assert_array_equal(out1, -keys)
+        one, none = ni.merge_ranges(part, bounds, local, offs, lo,
+                                    np.zeros(n_parts, dtype=np.int32))
+        np.testing.assert_array_equal(one, keys)
+        assert none is None
+
+
 def test_str_fingerprint_python_mirror_and_shard_agreement():
     """fnv_fingerprint_h1 (the Python mirror shard_of_key routes
     strings with) must equal the native hashers' h1 — and therefore

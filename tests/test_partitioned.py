@@ -79,6 +79,15 @@ def test_partitioned_stream_matches_plain():
         b = st_n.acquire_stream_ids("tb", lid_n, ids, None)
         np.testing.assert_array_equal(a, b, err_msg=f"rep {rep}")
         now[0] += 411
+    # Every partitioned walk feeds the route/merge timers; a single
+    # index has no such passes.
+    for st, walks in ((st_p, True), (st_n, False)):
+        meters = st.registry.meters()
+        n_index = meters["ratelimiter.stream.index"].count()
+        assert n_index > 0
+        for name in ("index_route", "index_merge"):
+            got = meters[f"ratelimiter.stream.{name}"].count()
+            assert got == (n_index if walks else 0), name
     st_p.close()
     st_n.close()
 
@@ -203,18 +212,20 @@ def test_partitioned_checkpoint_round_trip(tmp_path):
 def test_partial_failure_releases_sibling_pins():
     """One partition exhausting capacity mid-batch must release the pins
     the other (successful) partitions took — their results never reach
-    the caller, so nothing else could unpin them."""
-    import numpy as np
-    import pytest
-
+    the caller, so nothing else could unpin them — and carry every
+    eviction the batch applied as ``pending_clears``: on the caller's
+    thread (a short batch) and across ranges on the pool (one longer
+    than the range grain)."""
+    from ratelimiter_tpu.engine.errors import SlotCapacityError
     from ratelimiter_tpu.engine.partitioned import (
+        _RANGE_GRAIN,
         PartitionedSlotIndex,
-        _part_of_int_keys,
     )
+    from ratelimiter_tpu.parallel.sharded import shard_of_int_keys
 
     ix = PartitionedSlotIndex(4, n_parts=2)  # 2 slots per partition
     keys = np.arange(64, dtype=np.int64)
-    part = _part_of_int_keys(keys, 2)
+    part = shard_of_int_keys(keys, 2)
     p0 = keys[part == 0]
     p1 = keys[part == 1]
     # Fill partition 0 and pin both its slots (as in-flight windows).
@@ -229,4 +240,139 @@ def test_partial_failure_releases_sibling_pins():
     s1, ev1 = ix.assign((0, int(p1[2])))
     s2, ev2 = ix.assign((0, int(p1[3])))
     assert {s1, s2} == {2, 3}  # both partition-1 slots reachable
+    ix.close()
+
+    # Ranged: partition 1 is full of unpinned keys that a long batch of
+    # fresh partition-1 keys evicts, while one fresh partition-0 key
+    # fails behind two pinned slots.
+    for family in ("slots", "uniques"):
+        ix = PartitionedSlotIndex(8, n_parts=2)  # 4 slots per partition
+        for k in p0[:4]:
+            ix.assign((0, int(k)), hold_pin=True)
+        for k in p1[:4]:
+            ix.assign((0, int(k)))
+        fresh = np.resize(p1[4:8], 3 * _RANGE_GRAIN + 17)
+        batch = np.concatenate([fresh[:_RANGE_GRAIN + 5], [p0[4]],
+                                fresh[_RANGE_GRAIN + 5:]]).astype(np.int64)
+        with pytest.raises(SlotCapacityError) as err:
+            if family == "slots":
+                ix.assign_batch_ints(batch, lid=0, hold_pins=True)
+            else:
+                ix.assign_batch_ints_uniques(batch, 0, 8, hold_pins=True)
+        # Every partition-1 slot was evicted once (global slots 4..7).
+        assert sorted(err.value.pending_clears.tolist()) == [4, 5, 6, 7]
+        got = {ix.assign((0, int(k)))[0] for k in p1[8:12]}
+        assert got == {4, 5, 6, 7}, family  # no pin left behind
+        ix.close()
+
+
+def _reference_walk(twins, family, keys, lids, rank_bits):
+    """What the partitioned index computes, composed from the plain
+    parts: a numpy stable-argsort route, one NativeSlotIndex walk per
+    partition on twin indexes, and a numpy merge back to request order
+    with each partition's global slot base folded in."""
+    from ratelimiter_tpu.engine.native_index import hash_str_keys
+    from ratelimiter_tpu.parallel.sharded import shard_of_int_keys
+
+    n_parts = len(twins)
+    spp = twins[0].num_slots
+    n = len(keys)
+    if family == "strs_uniques":
+        h1, h2 = (x.copy() for x in hash_str_keys(keys, 3))
+        part = (h1 % np.uint64(n_parts)).astype(np.int64)
+    else:
+        part = shard_of_int_keys(keys, n_parts)
+    order = np.argsort(part, kind="stable")
+    offs = np.concatenate([[0], np.cumsum(np.bincount(
+        part, minlength=n_parts))])
+    out = [np.empty(n, dtype=np.int32) for _ in range(2)]
+    uwords, clears, u = [], [], 0
+    for p, twin in enumerate(twins):
+        pos = order[offs[p]:offs[p + 1]]
+        if not len(pos):
+            continue
+        if family == "ints":
+            res = twin.assign_batch_ints(keys[pos], 3)
+        elif family == "ints_multi":
+            res = twin.assign_batch_ints_multi(keys[pos], lids[pos])
+        elif family == "ints_uniques":
+            res = twin.assign_batch_ints_uniques(keys[pos], 3, rank_bits)
+        elif family == "ints_multi_uniques":
+            res = twin.assign_batch_ints_multi_uniques(
+                keys[pos], lids[pos], rank_bits)
+        else:
+            res = twin.assign_batch_fps_uniques(h1[pos], h2[pos], rank_bits)
+        clears.extend(p * spp + int(e) for e in res[-1])
+        if len(res) == 2:  # slots family
+            out[0][pos] = res[0] + p * spp
+            continue
+        uwords.append(res[0] + np.uint32(p * spp << (rank_bits + 1)))
+        out[0][pos] = res[1] + u
+        out[1][pos] = res[2]
+        u += len(res[0])
+    if family in ("ints", "ints_multi"):
+        return out[0], np.asarray(clears, dtype=np.int64)
+    return (np.concatenate(uwords) if uwords else np.empty(0, np.uint32),
+            out[0], out[1], np.asarray(clears, dtype=np.int64))
+
+
+_GRAIN = 1 << 16  # engine/partitioned.py:_RANGE_GRAIN, checked below
+
+
+@pytest.mark.parametrize("n_parts", [8, 6])
+@pytest.mark.parametrize("skew", ["zipf", "uniform"])
+@pytest.mark.parametrize("n", [1, 100, _GRAIN - 1, _GRAIN + 1,
+                               3 * _GRAIN + 17])
+@pytest.mark.parametrize("family", ["ints_uniques", "ints_multi_uniques",
+                                    "ints", "ints_multi", "strs_uniques"])
+def test_ranged_walk_matches_reference_composition(family, n, skew,
+                                                   n_parts):
+    """Array for array, the ranged route/walk/merge equals the reference
+    composition, chunk after chunk on a table small enough that uniform
+    chunks evict from the third on."""
+    from ratelimiter_tpu.engine.native_index import NativeSlotIndex
+    from ratelimiter_tpu.engine.partitioned import (
+        _RANGE_GRAIN,
+        PartitionedSlotIndex,
+    )
+
+    assert _RANGE_GRAIN == _GRAIN
+    rng = np.random.default_rng(n * 31 + n_parts)
+    space = 8 * n + 64  # distinct keys over the chunks outgrow the table
+    spp = (3 * n) // (2 * n_parts) + 8  # holds one chunk's uniques
+    ix = PartitionedSlotIndex(spp * n_parts, n_parts)
+    twins = [NativeSlotIndex(spp) for _ in range(n_parts)]
+    rank_bits = 7
+    evicted = []
+    for chunk in range(3):
+        if skew == "zipf":
+            ids = (rng.zipf(1.1, n) - 1) % space
+        else:
+            ids = rng.integers(0, space, n)
+        ids = ids.astype(np.int64)
+        lids = rng.integers(0, 3, n).astype(np.uint64)
+        want = _reference_walk(
+            twins, family, [f"u{k}" for k in ids]
+            if family == "strs_uniques" else ids, lids, rank_bits)
+        if family == "ints":
+            got = ix.assign_batch_ints(ids, 3)
+        elif family == "ints_multi":
+            got = ix.assign_batch_ints_multi(ids, lids)
+        elif family == "ints_uniques":
+            got = ix.assign_batch_ints_uniques(ids, 3, rank_bits)
+        elif family == "ints_multi_uniques":
+            got = ix.assign_batch_ints_multi_uniques(ids, lids, rank_bits)
+        else:
+            got = ix.assign_batch_strs_uniques(
+                [f"u{k}" for k in ids], 3, rank_bits)
+        assert len(got) == len(want)
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(
+                np.asarray(g, dtype=np.int64), np.asarray(w, np.int64),
+                err_msg=f"chunk {chunk}")
+        evicted.append(len(want[-1]))
+        route_s, merge_s = ix.last_phase_s()
+        assert route_s >= 0 and merge_s >= 0
+    if skew == "uniform" and n >= 100:
+        assert evicted[2] > 0, evicted  # the table was small enough
     ix.close()
