@@ -28,7 +28,6 @@ _flags = os.environ.get("XLA_FLAGS", "")
 if "--xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (
         _flags + " --xla_force_host_platform_device_count=8").strip()
-os.environ.setdefault("RATELIMITER_RATE_PROBE", "0")
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))

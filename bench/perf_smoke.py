@@ -59,7 +59,6 @@ def run_point(n_shards: int) -> None:
     if "xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (
             flags + " --xla_force_host_platform_device_count=8").strip()
-    os.environ.setdefault("RATELIMITER_RATE_PROBE", "0")
 
     import time
 
@@ -105,7 +104,6 @@ def run_point(n_shards: int) -> None:
 def run_relay_election() -> None:
     """Relay-election smoke: elected path never slower than XLA on this
     (CPU) backend, and cached election artifacts self-consistent."""
-    os.environ.setdefault("RATELIMITER_RATE_PROBE", "0")
 
     import functools
     import glob

@@ -9,14 +9,13 @@ changed kernel can flip a winner, and a stale verdict silently pins the
 loser.  This sweep:
 
 1. snapshots then DELETES every persisted verdict (``pallas_elect_*``)
-   and device-rate probe (``device_rates_*``) in the compile cache
-   directory (utils/compile_cache.py), so the next dispatch of each
-   path re-measures;
+   in the compile cache directory (utils/compile_cache.py), so the next
+   dispatch of each path re-measures;
 2. re-runs ``bench/sharded_scaling.py`` (a fresh storage per shard
    count re-elects ``sharded.route_elect`` at runtime — that election
    is never disk-cached);
 3. runs ``bench.py`` for a full round (its in-process dispatches
-   re-elect every pallas path and re-probe device rates) and writes the
+   re-elect every pallas path) and writes the
    refreshed round to ``BENCH_r06.json`` in the same shape as prior
    rounds (``{n, cmd, rc, tail, parsed}``) plus the refreshed election
    verdicts, the prior (pre-clear) verdicts for diffing, and the
@@ -44,22 +43,22 @@ ROUND = 6
 
 
 def clear_verdicts() -> dict:
-    """Snapshot + delete persisted election/rate files; return the
+    """Snapshot + delete persisted election files; return the
     snapshot keyed by filename (the pre-clear verdicts, for diffing)."""
     from ratelimiter_tpu.utils.compile_cache import cache_dir
 
     prior: dict = {}
     removed = []
-    for pat in ("pallas_elect_*.json", "device_rates_*.json"):
-        for path in sorted(glob.glob(os.path.join(cache_dir(), pat))):
-            name = os.path.basename(path)
-            try:
-                with open(path) as fh:
-                    prior[name] = json.load(fh)
-            except Exception as exc:  # noqa: BLE001 — record, still clear
-                prior[name] = {"unreadable": str(exc)}
-            os.unlink(path)
-            removed.append(path)
+    for path in sorted(glob.glob(os.path.join(cache_dir(),
+                                              "pallas_elect_*.json"))):
+        name = os.path.basename(path)
+        try:
+            with open(path) as fh:
+                prior[name] = json.load(fh)
+        except Exception as exc:  # noqa: BLE001 — record, still clear
+            prior[name] = {"unreadable": str(exc)}
+        os.unlink(path)
+        removed.append(path)
     return {"prior_verdicts": prior, "removed": removed}
 
 
@@ -71,13 +70,11 @@ def refresh_elections() -> dict:
     probe fires, by design), so the report would be empty there.  This
     resolves each electable path directly against the now-cleared disk
     cache: the pallas settle (micro / relay_fused, and the tile sweep's probe — a
-    no-op off-TPU), the device-journal placement (measures on every
-    backend), and the device step-rate probe the chunk scheduler elects
-    plans from.  Runs in a child (``--refresh``): this parent stays off
+    no-op off-TPU) and the device-journal placement (measures on every
+    backend).  Runs in a child (``--refresh``): this parent stays off
     JAX, so each child in turn can hold the chip."""
     import jax
 
-    from ratelimiter_tpu.engine import device_rates
     from ratelimiter_tpu.ops import pallas as pallas_pkg
     from ratelimiter_tpu.ops.pallas import election
     from ratelimiter_tpu.replication import log as rlog
@@ -85,14 +82,10 @@ def refresh_elections() -> dict:
 
     enable_compile_cache()
     election.reset_for_tests()       # drop in-process memos too
-    device_rates._mem_cache.clear()
     pallas_pkg.settle_all()          # TPU: micro/relay_fused/sweep probe
     rlog.device_journal_elected()    # measures host-vs-device everywhere
-    rates = device_rates.get_device_rates()
     return {"platform": jax.default_backend(),
-            "verdicts": election.report(),
-            "device_rates": {k: v for k, v in rates.items()
-                             if not k.startswith("_")}}
+            "verdicts": election.report()}
 
 
 def _run(cmd: list, timeout: int, cpu: bool = False) -> dict:
@@ -178,7 +171,6 @@ def main() -> None:
         "parsed": bench.get("parsed"),
         "elections": elections,
         "election_platform": refreshed["platform"],
-        "device_rates": refreshed["device_rates"],
         "prior_verdicts": cleared["prior_verdicts"],
         "verdict_files_cleared": [os.path.relpath(p, _REPO)
                                   if p.startswith(_REPO) else p

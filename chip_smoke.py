@@ -181,21 +181,15 @@ def shard_placement(engine, devices, tables: bool = True) -> dict:
 def phase_device(devices) -> dict:
     import jax
 
-    from ratelimiter_tpu.engine.device_rates import get_device_rates
     from ratelimiter_tpu.engine.native_index import native_available
     from ratelimiter_tpu.ops import pallas
 
     if not native_available():
         raise SmokeError("native slot index did not load (make -C native)")
     pallas.settle_all()  # probes raise on a TPU backend
-    rates = get_device_rates()
     return {"decisions": 0, "platform": devices[0].platform,
             "kind": devices[0].device_kind, "count": len(devices),
-            "all_devices": len(jax.devices()),
-            "native_index": True, "device_rates_source": rates["source"],
-            "device_rates": {k: rates[k] for k in
-                             ("s_per_lane", "s_per_unique_sorted",
-                              "s_per_unique_unsorted")}}
+            "all_devices": len(jax.devices()), "native_index": True}
 
 
 def phase_tb_stream(devices, rng) -> dict:

@@ -1297,40 +1297,4 @@ void rl_weighted_decide(const uint8_t* bits, const int64_t* roff,
   }
 }
 
-// Split-digest layout (r5, ops/relay.py:_relay_counts_split): partition
-// uniques into singletons (count field == 1 — exact: rank_bits >= 2 so
-// the clamp sentinel is >= 3) and multi-count segments; singletons'
-// slots go out as a 3-byte little-endian plane, multis keep their
-// uwords, and uidx is remapped to singles-then-multis positions.  Two
-// passes (O(u) classify+emit, O(n) remap) replacing four numpy passes;
-// `scratch` is caller-provided int32[u] for the position map.  Returns
-// the singleton count.
-int64_t rl_split_layout(const uint32_t* uwords, int64_t u,
-                        int32_t rank_bits, const int32_t* uidx, int64_t n,
-                        uint8_t* s3, uint32_t* mwords, int32_t* uidx2,
-                        int32_t* scratch) {
-  const uint32_t rmask = (1u << rank_bits) - 1u;
-  const int shift = rank_bits + 1;
-  int64_t n_s = 0;
-  for (int64_t i = 0; i < u; i++) {
-    if (((uwords[i] >> 1) & rmask) == 1u) n_s++;
-  }
-  int64_t si = 0, mi = n_s;
-  for (int64_t i = 0; i < u; i++) {
-    uint32_t w = uwords[i];
-    if (((w >> 1) & rmask) == 1u) {
-      uint32_t s = w >> shift;
-      s3[si * 3] = static_cast<uint8_t>(s & 0xFF);
-      s3[si * 3 + 1] = static_cast<uint8_t>((s >> 8) & 0xFF);
-      s3[si * 3 + 2] = static_cast<uint8_t>((s >> 16) & 0xFF);
-      scratch[i] = static_cast<int32_t>(si++);
-    } else {
-      mwords[mi - n_s] = w;
-      scratch[i] = static_cast<int32_t>(mi++);
-    }
-  }
-  for (int64_t i = 0; i < n; i++) uidx2[i] = scratch[uidx[i]];
-  return n_s;
-}
-
 }  // extern "C"

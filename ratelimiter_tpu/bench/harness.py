@@ -113,22 +113,10 @@ def bench_end_to_end_stream(
     """
     n = len(key_stream)
     # Warm compile shapes (stream super-batch, tail, latency batch) with
-    # full untimed passes — buckets drain but throughput is unaffected.
-    # Warmup repeats until the storage's chunk-plan map stops changing
-    # shape (election -> new chunk shapes -> fresh XLA compiles), so
-    # timed passes never meet a fresh shape (same discipline as
-    # bench.py run_stream).
-    def plan_sig():
-        if storage is None:
-            return None
-        return {k: (v["kind"], v.get("schedule", v.get("chunk")))
-                for k, v in storage._chunk_plans.items()}
-
-    for i in range(4):
-        sig = plan_sig()
+    # two full untimed passes — buckets drain but throughput is
+    # unaffected.
+    for _ in range(2):
         limiter.try_acquire_many(key_stream, permits)
-        if i > 0 and plan_sig() == sig:
-            break
     limiter.try_acquire_many(key_stream[:latency_batch],
                              None if permits is None
                              else permits[:latency_batch])

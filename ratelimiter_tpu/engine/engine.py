@@ -569,64 +569,6 @@ class DeviceEngine:
                                            out_dtype,
                                            slots_sorted=slots_sorted)
 
-    def sw_relay_counts_split_dispatch(self, s3, mwords, lids, now_ms,
-                                       out_dtype):
-        return self._relay_counts_split_dispatch("sw", s3, mwords, lids,
-                                                 now_ms, out_dtype)
-
-    def tb_relay_counts_split_dispatch(self, s3, mwords, lids, now_ms,
-                                       out_dtype):
-        return self._relay_counts_split_dispatch("tb", s3, mwords, lids,
-                                                 now_ms, out_dtype)
-
-    def _relay_counts_split_dispatch(self, algo, s3, mwords, lids, now_ms,
-                                     out_dtype):
-        """Split-digest dispatch (ops/relay.py:_relay_counts_split, r5):
-        s3 uint8[S, 3] singleton slot plane (padding 0xFFFFFF), mwords
-        uint32[M] multi-count uwords (padding 0xFFFFFFFF); returns ONE
-        lazy uint8[S/8 + M*itemsize] handle: packed singleton allow
-        bits followed by the multis' count bytes."""
-        from ratelimiter_tpu.ops.relay import (
-            sw_relay_counts_split,
-            tb_relay_counts_split,
-        )
-
-        if self.journal is not None:
-            # Singleton plane: little-endian 24-bit slots (padding 0xFFFFFF
-            # decodes past num_slots — the journal filters it).
-            s3a = np.asarray(s3, dtype=np.int64)
-            self.journal.mark(
-                algo, s3a[:, 0] | (s3a[:, 1] << 8) | (s3a[:, 2] << 16))
-            self._mark_words(algo, mwords)
-
-        jdt = jnp.uint8 if out_dtype == np.uint8 else jnp.uint16
-        key = (algo, out_dtype().dtype.name, "split")
-        fn = self._relay_counts.get(key)
-        if fn is None:
-            base = (sw_relay_counts_split if algo == "sw"
-                    else tb_relay_counts_split)
-            fn = jax.jit(_step(
-                base, rank_bits=self.rank_bits, out_dtype=jdt),
-                donate_argnums=0)
-            self._relay_counts[key] = fn
-        s3 = jnp.asarray(np.ascontiguousarray(s3, dtype=np.uint8))
-        mwords = jnp.asarray(np.ascontiguousarray(mwords, dtype=np.uint32))
-        if np.ndim(lids) == 0:
-            lids = jnp.asarray(np.int32(lids))
-        else:
-            lids = jnp.asarray(np.ascontiguousarray(lids, dtype=np.int32))
-        now = jnp.int64(now_ms)
-        with self._lock:
-            if algo == "sw":
-                self.sw_packed, out = fn(
-                    self.sw_packed, self.table.device_arrays, s3, mwords,
-                    lids, now)
-            else:
-                self.tb_packed, out = fn(
-                    self.tb_packed, self.table.device_arrays, s3, mwords,
-                    lids, now)
-        return out
-
     def sw_relay_counts_resident_dispatch(self, uwords, delta_slots,
                                           delta_lids, now_ms, out_dtype,
                                           slots_sorted=False):

@@ -214,16 +214,6 @@ def _bind(lib) -> None:
     lib.rl_weighted_decide.argtypes = [
         ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
         ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p]
-    # Optional (r5): a stale prebuilt .so without the symbol must not
-    # kill the library load — split_layout falls back to numpy.
-    try:
-        lib.rl_split_layout.restype = ctypes.c_int64
-        lib.rl_split_layout.argtypes = [
-            ctypes.c_void_p, ctypes.c_int64, ctypes.c_int32,
-            ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p,
-            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p]
-    except AttributeError:
-        pass
     # Optional (r6): the fingerprint string fast path + hash routing.
     # Stale prebuilt .so => callers fall back to the packed-bytes path.
     try:
@@ -632,52 +622,6 @@ def weighted_decide(bits: np.ndarray, roff: np.ndarray, spos: np.ndarray,
                            spos.ctypes.data, uidx.ctypes.data,
                            rank.ctypes.data, len(uidx), out.ctypes.data)
     return out.view(np.bool_)
-
-
-def split_layout(uwords: np.ndarray, rank_bits: int, uidx: np.ndarray,
-                 singles: np.ndarray | None = None):
-    """Partition a digest chunk's uniques into SINGLETONS and
-    multi-count segments for the split dispatch (ops/relay.py:
-    _relay_counts_split, r5).
-
-    Returns ``(s3, mwords, uidx2, n_singles)``: the singletons' slots
-    as a uint8[S, 3] little-endian 24-bit plane, the multis' uwords
-    unchanged, and uidx remapped to singles-then-multis positions
-    (reconstruction: position < S reads an allow bit, else a count).
-    A count FIELD of 1 is an exact singleton — relay_usable() forces
-    rank_bits >= 2, so the clamp sentinel is >= 3 and can't alias 1.
-    C fast path (rl_split_layout: two GIL-free passes; ~19 ns/unique
-    all-in at 3M uniques, output allocation included); the numpy
-    fallback (~4 passes, ~46 ns/unique) is bit-identical.
-    ``singles`` lets a caller that already computed the singleton mask
-    (the election did, to price the split) pass it in (numpy path
-    only — the C pass re-classifies for ~1 ns/unique)."""
-    u = len(uwords)
-    n = len(uidx)
-    lib = _load_library()
-    if (lib is not None and hasattr(lib, "rl_split_layout")
-            and uwords.flags["C_CONTIGUOUS"] and uwords.dtype == np.uint32
-            and uidx.flags["C_CONTIGUOUS"] and uidx.dtype == np.int32):
-        s3 = np.empty((u, 3), dtype=np.uint8)
-        mwords = np.empty(max(u, 1), dtype=np.uint32)
-        uidx2 = np.empty(n, dtype=np.int32)
-        scratch = np.empty(max(u, 1), dtype=np.int32)
-        n_s = int(lib.rl_split_layout(
-            uwords.ctypes.data, u, int(rank_bits), uidx.ctypes.data, n,
-            s3.ctypes.data, mwords.ctypes.data, uidx2.ctypes.data,
-            scratch.ctypes.data))
-        return s3[:n_s], mwords[:u - n_s], uidx2, n_s
-    if singles is None:
-        rank_mask = np.uint32((1 << rank_bits) - 1)
-        singles = ((uwords >> np.uint32(1)) & rank_mask) == 1
-    n_s = int(singles.sum())
-    newpos = np.empty(u, dtype=np.int32)
-    newpos[singles] = np.arange(n_s, dtype=np.int32)
-    newpos[~singles] = np.arange(n_s, u, dtype=np.int32)
-    uidx2 = newpos[uidx]
-    s_slots = (uwords[singles] >> np.uint32(rank_bits + 1)).astype("<u4")
-    s3 = s_slots.view(np.uint8).reshape(-1, 4)[:, :3]
-    return s3, uwords[~singles], uidx2, n_s
 
 
 def shard_route(key_ids: np.ndarray, n_shards: int):

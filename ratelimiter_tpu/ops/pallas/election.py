@@ -16,9 +16,8 @@ its rule on the shapes (``supported``) holds.
 Each path registers a measure function returning ``{"pallas_s",
 "xla_s", ...shape keys...}``; the verdict (Pallas serves iff
 ``pallas_s <= margin * xla_s``) is cached in-process and on disk per
-(platform, device kind, path) next to the compile cache, like
-engine/device_rates.py — so one process pays the A/B and every later
-process reads the verdict.  ``report()`` returns every resolved
+(platform, device kind, path) next to the compile cache — so one
+process pays the A/B and every later process reads the verdict.  ``report()`` returns every resolved
 verdict with its measurements, which bench.py and bench/device_only.py
 record into BENCH_DETAIL so no path can silently run a measured-slower
 backend (bench/perf_smoke.py asserts record/verdict consistency in CI).

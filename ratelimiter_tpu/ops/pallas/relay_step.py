@@ -658,8 +658,8 @@ def _probe() -> bool:
 
 
 def _measure_ab() -> dict:
-    """Chained-step A/B at a representative digest shape (the same
-    chain-K-fetch-one-checksum method as engine/device_rates.py)."""
+    """Chained-step A/B at a representative digest shape: K steps
+    chained in one jit, one checksum fetched."""
     import time
 
     from ratelimiter_tpu.core.config import RateLimitConfig

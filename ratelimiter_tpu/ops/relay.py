@@ -180,8 +180,7 @@ def tb_relay_counts(packed, table, uwords, lids, now, *, rank_bits: int,
     max_permits fits), and the host reconstructs per-request booleans as
     ``rank < n_allowed[uidx]``.  State writes are identical to
     tb_relay_bits on the expanded batch: every valid lane is its own
-    last occurrence.  Decision/state math lives in _tb_counts_core —
-    shared with the split dispatch so the modes cannot drift.
+    last occurrence.  Decision/state math lives in _tb_counts_core.
     """
     num_slots = packed.shape[0]
     slot, count, _, valid = decode_words(uwords, rank_bits, num_slots)
@@ -211,53 +210,6 @@ def sw_relay_counts(packed, table, uwords, lids, now, *, rank_bits: int,
     return packed_new, jnp.clip(tot, 0, lim).astype(out_dtype)
 
 
-def _decode_s3(s3, num_slots):
-    """uint8[S, 3] little-endian 24-bit slot plane -> (slot i32[S],
-    valid bool[S]).  The 0xFFFFFF padding sentinel decodes to a slot
-    >= num_slots (callers gate split mode on num_slots < 2^24)."""
-    w = s3.astype(jnp.uint32)
-    slot = (w[:, 0] | (w[:, 1] << 8) | (w[:, 2] << 16)).astype(jnp.int32)
-    return slot, slot < num_slots
-
-
-def _relay_counts_split(algo_core, packed, table, s3, mwords, lids, now, *,
-                        rank_bits, out_dtype):
-    """Split-digest decision step shared by both algorithms (r5).
-
-    Unit-permit digest traffic is mostly SINGLETON uniques (uniform:
-    ~80-90% of uniques; Zipf: the tail).  A singleton needs no count
-    field on the way in (count == 1) and only an allow BIT on the way
-    out — so singles ship as a 3-byte slot plane (s3) and come back as
-    packed bits, while multi-count uniques keep the 4-byte uword and
-    the count download.  Wire vs classic digest: upload 4 -> 3 B and
-    download 1-2 B -> 1/8 B per singleton; decisions and state writes
-    are identical (tests/test_relay.py drives all three modes on the
-    same chunks).  Both lane sets decide in ONE fused body over their
-    concatenation (disjoint slots — singles and multis are different
-    uniques), and the result ships as ONE uint8 array
-    [packed singles bits | counts bytes] so the drain stays a single
-    fetch round trip.
-    """
-    num_slots = packed.shape[0]
-    slot_s, valid_s = _decode_s3(s3, num_slots)
-    slot_m, count_m, _, valid_m = decode_words(mwords, rank_bits, num_slots)
-    slot = jnp.concatenate([slot_s, slot_m])
-    count = jnp.concatenate([jnp.ones_like(slot_s, dtype=jnp.int64),
-                             count_m])
-    valid = jnp.concatenate([valid_s, valid_m])
-    n_s = s3.shape[0]
-    packed_new, n_alw = algo_core(packed, table, slot, count, valid, lids,
-                                  now)
-    bits_s = jnp.packbits(n_alw[:n_s] > 0)
-    csize = out_dtype(0).dtype.itemsize  # static (python) at trace time
-    counts_m = jnp.clip(n_alw[n_s:], 0,
-                        jnp.int64(jnp.iinfo(out_dtype).max)).astype(out_dtype)
-    if csize > 1:
-        counts_m = jax.lax.bitcast_convert_type(
-            counts_m, jnp.uint8).reshape(-1)
-    return packed_new, jnp.concatenate([bits_s, counts_m])
-
-
 def _scatter_rows(packed, slot, valid, new_rows, slots_sorted):
     """Unique-row state write: the tile sweep when the host sorted the
     uniques by slot (padding decodes to slot >= num_slots, at the
@@ -272,9 +224,8 @@ def _scatter_rows(packed, slot, valid, new_rows, slots_sorted):
 
 def _tb_counts_core(packed, table, slot, count, valid, lids, now,
                     slots_sorted: bool = False):
-    """(new_packed, n_allowed per lane) — THE token-bucket digest body.
-    tb_relay_counts (classic uwords) and the split dispatch both decide
-    through this, so the two modes cannot drift."""
+    """(new_packed, n_allowed per lane) — THE token-bucket digest body
+    of tb_relay_counts."""
     sc = jnp.where(valid, slot, 0)
     scalar_lid = jnp.ndim(lids) == 0
     lidc = lids if scalar_lid else jnp.clip(
@@ -328,20 +279,6 @@ def _sw_counts_core(packed, table, slot, count, valid, lids, now,
     new_rows = _sw_encode(curr_ws_b, curr_new, cdl_new, prev_e, prev_dl_e)
     packed_new = _scatter_rows(packed, slot, valid, new_rows, slots_sorted)
     return packed_new, tot
-
-
-def tb_relay_counts_split(packed, table, s3, mwords, lids, now, *,
-                          rank_bits: int, out_dtype=jnp.uint8):
-    return _relay_counts_split(_tb_counts_core, packed, table, s3, mwords,
-                               lids, now, rank_bits=rank_bits,
-                               out_dtype=out_dtype)
-
-
-def sw_relay_counts_split(packed, table, s3, mwords, lids, now, *,
-                          rank_bits: int, out_dtype=jnp.uint8):
-    return _relay_counts_split(_sw_counts_core, packed, table, s3, mwords,
-                               lids, now, rank_bits=rank_bits,
-                               out_dtype=out_dtype)
 
 
 def tb_relay_counts_resident(packed, lid_map, table, uwords, delta_slots,

@@ -143,9 +143,6 @@ DEFAULTS = {
     # Compile hot dispatch shapes at boot (moves 40-90s/shape jit stalls
     # out of the first requests).
     "warmup.enabled": "true",
-    # Boot-time host<->device link probe feeding the streaming loops'
-    # chunk plans (storage/tpu.py).
-    "link.probe.enabled": "true",
     # Chaos drill: inject StorageException on this fraction of storage ops
     # (0 = off) and/or add latency to every op (fault-tolerance rehearsal).
     "chaos.failure_rate": "0",
@@ -355,7 +352,7 @@ _FLOAT_KEYS = (
 )
 _BOOL_KEYS = (
     "ratelimiter.fail_open", "warmup.enabled", "replication.enabled",
-    "link.probe.enabled", "breaker.enabled", "ratelimiter.degraded.enabled",
+    "breaker.enabled", "ratelimiter.degraded.enabled",
     "ratelimiter.sidecar.enabled", "ratelimiter.orchestrator.enabled",
     "ratelimiter.orchestrator.reseed",
     "ratelimiter.microbatch.adaptive_flush",
@@ -371,7 +368,7 @@ _BOOL_TOKENS = ("1", "true", "yes", "on", "0", "false", "no", "off")
 # RATELIMITER_* env vars read directly by engine/ops modules, not through
 # this properties layer — the unknown-env scan must not warn about them.
 _ENV_DIRECT = frozenset({
-    "RATELIMITER_SORT_UNIQUES", "RATELIMITER_RATE_PROBE",
+    "RATELIMITER_SORT_UNIQUES",
     "RATELIMITER_PALLAS", "RATELIMITER_PALLAS_INTERPRET",
     "RATELIMITER_BLOCK_SCATTER", "RATELIMITER_BLOCK_SCATTER_INTERPRET",
 })

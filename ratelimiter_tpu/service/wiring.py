@@ -904,23 +904,9 @@ def build_app(props: AppProperties | None = None,
             "1 when the fused relay kernel's differential probe failed "
             "on this hardware (serving composed XLA instead)",
         ).set(1.0 if relay_step.fallback_info()["probe_failed"] else 0.0)
-        # Boot-time link probe (r5): feeds the streaming loops' chunk-plan
-        # and wire-format elections.  Best-effort — a backend without a
-        # device link (memory) or a probe failure leaves the loops on the
-        # profile-less defaults (giant growth, device-first sort policy).
-        if props.get_bool("link.probe.enabled", True):
-            if hasattr(storage, "probe_link"):
-                try:
-                    storage.probe_link()
-                except Exception as exc:  # noqa: BLE001 — degraded boot
-                    import logging
-
-                    logging.getLogger("ratelimiter").warning(
-                        "boot link probe failed (%s): streaming loops run "
-                        "on profile-less defaults", exc)
         # The router (when the orchestrator is on) becomes the storage
-        # the breaker/retry wrappers compose around — warmup and the
-        # link probe above ran against the raw device storage.
+        # the breaker/retry wrappers compose around — warmup above ran
+        # against the raw device storage.
         storage = serving
         # Leases grant against the SERVING storage (router when
         # present) so a promoted replacement receives the charges for

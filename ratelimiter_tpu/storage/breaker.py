@@ -359,7 +359,7 @@ class CircuitBreakerStorage(RateLimitStorage):
 
     # -- plumbing -------------------------------------------------------------
     def __getattr__(self, name):
-        # Non-gated surface (flush, engine, trace, probe_link, checkpoint
+        # Non-gated surface (flush, engine, trace, checkpoint
         # hooks, _batcher, ...) passes straight through, mirroring the
         # retry/chaos wrappers.
         return getattr(self._inner, name)

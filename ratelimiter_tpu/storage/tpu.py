@@ -59,20 +59,16 @@ log = get_logger("storage.tpu")
 # sort/scan and takes no cap.
 _FLAT_MAX_LANES = 1 << 19
 
-# Relay-path chunking: the first chunk probes the stream's duplicate
-# structure at the floor size; later chunks size themselves to a
-# per-dispatch wire budget at the measured bytes/request of their mode.
-# Digest chunks grow until the whole pass is a couple of dispatches
-# (dedup improves superlinearly with chunk size).  Per-request-words
-# chunks use the same 16 MB budget: fewer dispatches = fewer ~100 ms
-# round trips, and large transfers measured as fast per byte as 4 MB
-# ones in r3 (the r2 "4 MB sweet spot" did not reproduce; scenario 3
-# runs ~15% faster at 16 MB).
+# Relay-path growth schedule, the one chunk plan of the stream loops: the
+# first chunk probes the stream's duplicate structure at the floor size;
+# each later chunk sizes itself to a per-dispatch wire budget at the
+# previous chunk's bytes per request, within [_RELAY_CHUNK,
+# _RELAY_CHUNK_MAX].  Zipf dedup improves with chunk size (u/cn drops),
+# so skewed streams run a floor chunk and then one large one (a 2^21-id
+# Zipf call: 524,288 + 1,572,864); duplicate-poor 2^20-id calls run two
+# floor chunks.  The budgets date from a remote-link deployment and were
+# kept because they give the cells these shapes.
 _RELAY_CHUNK = 1 << 19
-# Chunks grow to 16M: Zipf dedup improves superlinearly with chunk size
-# (u/cn drops), so two giant digest chunks beat five pipelined 4M ones
-# even though the pipeline overlap is worse — measured both ways over the
-# pre-PR-1 remote link (ROUND_NOTES.md r3; not re-measured on an attached chip).
 _RELAY_CHUNK_MAX = 1 << 24
 _RELAY_WIRE_BUDGET_DIGEST = 16 << 20
 _RELAY_WIRE_BUDGET_WORDS = 16 << 20
@@ -97,36 +93,8 @@ _DELTA_AMORT = 4
 # best when the whole pass is a handful of dispatches.
 _RELAY_WIRE_BUDGET_WEIGHTED = 48 << 20
 
-# Link-adaptive pipelining (VERDICT r3 #1, reworked r5).  The execution model of
-# the pre-PR-1 remote link, measured (bench/profile_stream_r5.py +
-# ROUND_NOTES r5): dispatch enqueue is async and uploads of QUEUED
-# dispatches stream back-to-back, but every result fetch is its own
-# ~RTT round trip — and concurrent fetches from separate threads
-# overlap (3 chunk cycles: 688 ms fetched serially, 295 ms fetched
-# concurrently).  So the loop drains every dispatch CONCURRENTLY on a
-# small pool (the fetch wait sleeps — it does not spin — so the C walk
-# keeps the core), and a pipelined plan is a descending SCHEDULE of
-# chunk sizes: a small head chunk gets the link flowing early, big
-# middle chunks keep dedup strong, and a small tail chunk shrinks the
-# only fetch cycle nothing can hide (the last one).  Chunk sizes stay
-# pow2-aligned where the dispatch pads to pow2 (words mode pads the
-# request lane; digest pads the unique lane) so schedule chunks don't
-# ship padding.  _elect_chunk_plan ranks candidate schedules with a
-# small discrete-event simulation fed by the giant pass's measured
-# walk/host rates and dedup curve; a schedule that measures clearly
-# worse than the giant pass it replaced (> _PIPELINE_REVERT x)
-# reverts — sticky both ways, so chunk shapes stay deterministic
-# across timed passes (ROUND_NOTES r3).
-_PIPELINE_WIN_MARGIN = 0.97
-_PIPELINE_REVERT = 1.1
-# Per-dispatch transfers move at a fraction of the bulk device_put
-# rate the link probe measures (2.6 MB moved in ~85 ms against a
-# 77 MB/s bulk probe — protocol overhead per dispatch cycle).  The
-# simulator derates the probed rate by this; ranking is insensitive
-# to the exact value.
-_DISPATCH_RATE_DERATE = 0.55
-# Concurrent in-flight drains: enough to overlap every mid-schedule
-# fetch cycle, small enough to bound queued result buffers.
+# Concurrent in-flight drains: enough to overlap every in-flight fetch,
+# small enough to bound queued result buffers.
 _DRAIN_WORKERS = 4
 _DRAIN_INFLIGHT = 4
 # Per-shard stream pipelining (r8): how many chunks the routing pass may
@@ -137,23 +105,11 @@ _SHARD_LOOKAHEAD = 2
 # Undrained dispatches a single shard lane may hold before its submit
 # blocks (and flags shard.drain_saturated to the flight recorder).
 _SHARD_DRAIN_INFLIGHT = 2
-# Device step cost per dispatched lane (words/weighted: per request;
-# digest: per unique, sorted vs unsorted scatter).  The elections
-# charge these explicitly; since r5 they are PROBED at runtime per
-# (platform, device kind) and disk-cached (engine/device_rates.py,
-# VERDICT r4 #5) — these module constants are only the v5e-measured
-# fallback for profile-less paths and failed probes.
-from ratelimiter_tpu.engine.device_rates import FALLBACK_RATES as _FB_RATES
-
-_DEVICE_S_PER_LANE = _FB_RATES["s_per_lane"]
-_DEVICE_S_PER_UNIQUE_SORTED = _FB_RATES["s_per_unique_sorted"]
-_DEVICE_S_PER_UNIQUE_UNSORTED = _FB_RATES["s_per_unique_unsorted"]
-
-# Split-digest host partition cost per unique
-# (engine/native_index.py:split_layout — C path measured ~19 ns/u
-# all-in at 3M uniques, output allocation included; numpy fallback
-# ~46 ns/u); the split election charges it against the wire it saves.
-_SPLIT_HOST_S_PER_UNIQUE = 15e-9
+# Device step seconds the words-vs-digest rule charges a chunk the
+# sorted sweep serves (v5e, ROUND_NOTES r4): the relay words step per
+# lane, the sorted digest step per unique.
+_DEVICE_S_PER_LANE = 60e-9
+_DEVICE_S_PER_UNIQUE_SORTED = 25e-9
 
 # Auto-elected host-parallel partitioned index (VERDICT r5 next-round
 # #2): the C slot walk is DRAM-latency-bound and was the headline
@@ -231,81 +187,30 @@ def _wall_clock_ms() -> int:
     return time.time_ns() // 1_000_000 + _CLOCK_SKEW_MS
 
 
-def _elect_digest_mode(link_profile, u: int, cn: int, n_delta: int,
-                       digest_bpu: float, words_bpr: float,
-                       srt_ok: bool, cdt_size: int = 1,
-                       rates: dict | None = None) -> bool:
-    """Words-vs-digest election for one chunk.  With a link profile the
-    comparison is TOTAL per-side seconds — wire charged PER DIRECTION
-    (digest uploads 4 B/unique but downloads a cdt_size count per
-    unique, words uploads 4 B/request but downloads 1 BIT per request;
-    on a download-degraded link that asymmetry decides high-u/n
-    chunks — r5) plus device seconds (the digest rate depending on
-    whether the slot-sorted sweep engages).  Without a profile the device
-    is attached, so a chunk the sorted sweep serves is elected by device
-    seconds alone (per unique against per lane); any other chunk falls
-    back to the blended wire-byte constants.  cdt presence is the
-    caller's gate."""
-    if rates is None:
-        rates = _FB_RATES
-    if link_profile is None and srt_ok:
-        return u * rates["s_per_unique_sorted"] <= cn * rates["s_per_lane"]
-    if link_profile is not None:
-        up = max(link_profile[0], 1.0)
-        down = max(link_profile[2], 1.0) if len(link_profile) > 2 else up
-        dev_u = rates["s_per_unique_sorted" if srt_ok
-                      else "s_per_unique_unsorted"]
-        # digest_bpu/words_bpr carry the blended per-lane bytes (incl.
-        # the multi-tenant lid lane when not resident); split out the
-        # known download component and charge it at the download rate.
-        dig_cost = (u * ((digest_bpu - cdt_size) / up + cdt_size / down
-                         + dev_u)
-                    + (8 * n_delta / _DELTA_AMORT) / up)
-        words_cost = cn * ((words_bpr - 0.125) / up + 0.125 / down
-                           + rates["s_per_lane"])
-        return dig_cost <= words_cost
+def _elect_digest_mode(u: int, cn: int, n_delta: int, digest_bpu: float,
+                       words_bpr: float, srt_ok: bool) -> bool:
+    """Words-vs-digest election for one chunk.  A chunk the sorted sweep
+    serves is elected by device seconds alone (per unique against per
+    lane); any other chunk by wire bytes, the multi-tenant lid deltas
+    charged at 1/_DELTA_AMORT.  cdt presence is the caller's gate."""
+    if srt_ok:
+        return u * _DEVICE_S_PER_UNIQUE_SORTED <= cn * _DEVICE_S_PER_LANE
     return digest_bpu * u + 8 * n_delta / _DELTA_AMORT <= words_bpr * cn
 
 
-# Host-side cost of the slot re-sort a sorted-digest dispatch needs
-# (native rl_sort_uniques; ~48 ns/unique measured at 2.7M uniques on
-# the bench host, r5).  The sort buys DEVICE time (52 -> 25 ns/unique,
-# ROUND_NOTES r4) — worth real host CPU only where the device is on
-# the critical path or host CPU is idle anyway.
-_SORT_HOST_S_PER_UNIQUE = 50e-9
+def _grown_chunk(budget: float, wire_b: float, cn: int) -> int:
+    """The growth schedule's next chunk size: ``budget`` bytes at the
+    chunk just dispatched's bytes per request, within [_RELAY_CHUNK,
+    _RELAY_CHUNK_MAX]."""
+    return int(min(max(budget / max(wire_b / cn, 1e-3), _RELAY_CHUNK),
+                   _RELAY_CHUNK_MAX))
 
 
-def _sort_affordable(link_profile, u: int) -> bool:
-    """Whether to spend host CPU slot-sorting a digest chunk's uniques.
-
-    ``RATELIMITER_SORT_UNIQUES=always|never|auto`` (default auto, read
-    per call so tests and config reloads take effect immediately): on
-    a multi-core host the sort overlaps other cores' work, and with no
-    link profile the device is assumed local-attached (device time is
-    the scarce resource) — sort.  On a single-core host with a
-    profiled link, the chunk's upload seconds (4 B/unique / rate) must
-    comfortably exceed the sort's host seconds (~50 ns/unique) — both
-    sides scale with u, so this reduces to a ~40 MB/s link threshold:
-    below it the pass is wire-bound and the host idles through the
-    sort anyway; above it the pass is CPU-bound and the device pays
-    the unsorted scatter instead — that time rides under the link wait
-    (r5: scenario 3 spent 0.9 s/pass sorting to save device time that
-    was never on the critical path)."""
-    import os
-
-    policy = os.environ.get("RATELIMITER_SORT_UNIQUES", "auto")
-    if policy == "always":
-        return True
-    if policy == "never":
-        return False
-    try:
-        cores = len(os.sched_getaffinity(0))
-    except (AttributeError, OSError):  # pragma: no cover - non-linux
-        cores = os.cpu_count() or 1
-    if cores > 2 or link_profile is None:
-        return True
-    rate = max(link_profile[0], 1.0)
-    return 4.0 / rate > 2.0 * _SORT_HOST_S_PER_UNIQUE
+def _sort_affordable() -> bool:
+    """Whether to slot-sort a digest chunk's uniques:
+    ``RATELIMITER_SORT_UNIQUES=never`` turns the sort off (read per call
+    so tests take effect immediately); anything else sorts."""
+    return os.environ.get("RATELIMITER_SORT_UNIQUES", "auto") != "never"
 
 
 class _DrainSet:
@@ -500,141 +405,6 @@ class _ShardLane:
     def close(self) -> None:
         self.pipe.shutdown(wait=False)
         self.drain_pool.shutdown(wait=False)
-
-
-class _ChunkCursor:
-    """Chunk sizing shared by the relay and weighted streaming loops:
-    either walks a plan's fixed SCHEDULE (the last entry sizes any
-    overflow when a longer stream reuses a banded plan) or runs the
-    mutable growth chunk.  ``next_size`` consumes an entry; ``peek``
-    sizes the prefetch for the following chunk without consuming."""
-
-    __slots__ = ("sched", "chunk", "ci")
-
-    def __init__(self, plan, pipelined: bool):
-        self.sched = plan.get("schedule") if pipelined else None
-        self.chunk = (plan["chunk"] if pipelined and not self.sched
-                      else _RELAY_CHUNK)
-        self.ci = 0
-
-    def _cur(self) -> int:
-        if self.sched:
-            return (self.sched[self.ci] if self.ci < len(self.sched)
-                    else self.sched[-1])
-        return self.chunk
-
-    def next_size(self, remaining: int) -> int:
-        c = min(self._cur(), remaining)
-        if self.sched:
-            self.ci += 1
-        return c
-
-    def peek(self, remaining: int) -> int:
-        return min(self._cur(), remaining)
-
-    def grow(self, chunk: int) -> None:
-        self.chunk = chunk
-
-
-def _schedule_candidates(n: int, head: int, words_pow2: bool) -> list:
-    """Candidate chunk schedules for a pipelined stream pass.
-
-    Shape: small HEAD chunk (the link starts moving after one cheap
-    walk), big MIDDLE chunks (dedup and per-dispatch overhead
-    amortize), small descending TAIL (the last fetch cycle is the only
-    one nothing can hide — make it cheap).  All sizes are pow2 when
-    ``words_pow2`` (the words dispatch pads its request lane to pow2 —
-    a non-pow2 chunk would ship up to 2x padding); digest chunks pad
-    the UNIQUE lane instead, so their sizes are free-form."""
-    floor = _RELAY_CHUNK
-    if n < 4 * floor:
-        return []
-    cands = []
-    # pow2 halving cascade: [head, biggest pow2 <= rest, halving...].
-    # Chunks respect the growth path's _RELAY_CHUNK_MAX lane ceiling,
-    # and a sub-floor remainder folds into its predecessor: the last
-    # entry also SIZES every overflow chunk when a longer stream in the
-    # same banded plan reuses this schedule — a tiny tail entry would
-    # make that overflow drain RTT-sized crumbs.
-    sizes = [head]
-    rem = n - head
-    while rem >= floor:
-        c = 1 << (int(rem).bit_length() - 1)
-        c = min(max(min(c, rem), floor), _RELAY_CHUNK_MAX)
-        sizes.append(int(c))
-        rem -= c
-    if rem > 0:
-        _fold_tail(sizes, int(rem))
-    cands.append(sizes)
-    if not words_pow2:
-        # two-big + tail: maximum dedup, still a cheap exposed tail.
-        tail = max(floor, n // 16)
-        mid = n - head - 2 * tail
-        if mid > 2 * floor:
-            half = (mid + 1) // 2
-            if half <= _RELAY_CHUNK_MAX:
-                cands.append([head, half, mid - half, tail, tail])
-        big = n - head - tail
-        if floor < big <= _RELAY_CHUNK_MAX:
-            cands.append([head, big, tail])
-    else:
-        # equal-pow2 middle: 2M-request chunks (the r4 words plans).
-        c = 4 * floor
-        sizes2 = [head]
-        rem = n - head
-        while rem >= c:
-            sizes2.append(c)
-            rem -= c
-        if rem > 0:
-            _fold_tail(sizes2, int(rem))
-        if len(sizes2) <= 40:
-            cands.append(sizes2)
-    return cands
-
-
-def _fold_tail(sizes: list, rem: int) -> None:
-    """Fold a sub-floor remainder into a schedule's last chunk — the
-    last entry also sizes every OVERFLOW chunk when a longer stream in
-    the same banded plan reuses the schedule, so it must never be an
-    RTT-sized crumb.  If the fold would push the chunk past the
-    _RELAY_CHUNK_MAX lane ceiling, split the total in half instead
-    (both halves >= the fold target > floor)."""
-    total = sizes[-1] + rem
-    if total <= _RELAY_CHUNK_MAX:
-        sizes[-1] = total
-    else:
-        sizes[-1] = total // 2
-        sizes.append(total - total // 2)
-
-
-def _sim_schedule_wall(sizes, *, cpu_per_req: float, digest_frac: float,
-                       dedup_a: float, dedup_alpha: float, bpu_up: float,
-                       bpu_down: float, words_up: float, link_up: float,
-                       link_down: float, rtt: float,
-                       dev_per_lane: float) -> float:
-    """Predicted wall for one schedule under the measured remote-link model:
-    CPU (walk + host prep) strictly serializes on one timeline, link
-    BYTES serialize on another (uploads of queued dispatches stream
-    back-to-back; concurrent drains overlap their RTTs), each chunk's
-    fetch completes one RTT after its step's wire has cleared.  Used to
-    RANK candidate schedules — absolute accuracy is not required, the
-    revert check (measured walls) is the safety net."""
-    t_cpu = 0.0
-    link_free = 0.0
-    done = 0.0
-    for c in sizes:
-        t_cpu += c * cpu_per_req
-        if digest_frac > 0.5:
-            u = min(c, dedup_a * (c ** dedup_alpha))
-            lanes = _bucket_pow2(max(int(u), 1))
-            up_b, down_b = bpu_up * lanes, bpu_down * lanes
-        else:
-            lanes = _bucket_pow2(int(c))
-            up_b, down_b = words_up * lanes, c / 8.0
-        start = max(t_cpu, link_free)
-        link_free = start + up_b / link_up + down_b / link_down
-        done = max(done, link_free + lanes * dev_per_lane + rtt)
-    return done
 
 
 def _presorted_scatter_usable(eng, algo: str, padded: int) -> bool:
@@ -923,17 +693,10 @@ class TpuBatchedStorage(RateLimitStorage):
         # a bench can show WHERE the seconds of a pass went (e.g. a
         # multi-second fetch_s on one chunk = a mid-timing compile).
         self.stream_stats: list | None = None
-        # Link profile (upload bytes/s, round-trip s) + per-stream-shape
-        # chunk plans (VERDICT r3 #1).  With no profile the streaming
-        # loops keep their wire-budget growth schedule; with one, the
-        # first pass over a stream shape measures walk/wire and elects a
-        # pipelined split when the link is fast enough to hide the fetch
-        # chain under the walks.  Plans are cached per (kind, algo,
-        # multi, n) so every later pass runs the SAME chunk schedule —
-        # shape determinism is what keeps XLA compiles out of timed
-        # regions (ROUND_NOTES r3).
-        self._link_profile: Tuple[float, float] | None = None
-        self._chunk_plans: Dict[tuple, tuple] = {}
+        # The sharded relay loop's learned chunk size per stream shape
+        # (key kind, algo, multi-lid, banded n): a later call starts at
+        # the size the last one grew to.
+        self._sharded_chunks: Dict[tuple, int] = {}
         # Host-vs-device shard routing election (r8): None until the
         # first large sharded chunk A/Bs both (see _route_sharded).
         self._route_mode: str | None = None
@@ -1703,22 +1466,17 @@ class TpuBatchedStorage(RateLimitStorage):
         drains = _DrainSet(self._drain_pool(),
                            wait_span=lambda: self._span("drain_wait"))
 
-        # Chunk plan (VERDICT r3 #1): the first pass over this stream
-        # shape runs the wire-budget growth schedule and measures; later
-        # passes may run a fixed pipelined split instead, with eager
-        # drains so fetches ride under the worker's walk of the next
-        # chunk.  tot[...] feeds the end-of-pass election.  key_kind
-        # separates int- from str-keyed streams: their walks cost very
-        # differently, so they must not share a plan.
-        # n is BANDED into the plan key (quarter-octave) so a service
-        # with naturally jittering stream lengths reuses one plan per
-        # band instead of re-measuring every distinct n.
-        plan_key = ("relay", key_kind, algo, lid_arr is not None,
-                    _bucket_fine(n, floor=_RELAY_CHUNK))
-        with self._span("plan"):
-            plan, pipelined, tot, timed_assign, t_pass0 = self._plan_setup(
-                plan_key, assign_uniques, self._index[algo])
-            rates = self._device_rates()
+        # Cumulative walk seconds of the call, wherever each walk ran
+        # (the stream_stats records carry it).
+        walk_s = [0.0]
+        rec_lock = threading.Lock()  # drains write rec and the recorder
+
+        def timed_assign(s0, cnt, chunk):
+            with self._span("index", chunk) as timed:
+                r = assign_uniques(s0, cnt)
+            self._record_index_phases(self._index[algo])
+            walk_s[0] += timed.secs
+            return r
 
         def sortable(u):
             """Whether a digest chunk of ``u`` uniques is slot-sorted:
@@ -1730,7 +1488,7 @@ class TpuBatchedStorage(RateLimitStorage):
                         and hasattr(eng, "_relay_fused_ok")
                         and eng._relay_fused_ok(algo, _bucket_pow2(u)))
             return (u >= _SORT_UNIQUES_MIN
-                    and _sort_affordable(self._link_profile, u)
+                    and _sort_affordable()
                     and (fused_ok or _presorted_scatter_usable(
                         eng, algo, _bucket_pow2(u))))
 
@@ -1749,16 +1507,14 @@ class TpuBatchedStorage(RateLimitStorage):
 
         def walk(s0, cnt, chunk):
             """The chunk's walk and, where the chunk is sure to go to
-            the sorted digest step (no link profile), its slot sort:
-            both run on whichever thread walks, so a prefetched chunk
-            sorts behind the device.  The last field says whether the
-            uniques are sorted."""
+            the sorted digest step, its slot sort: both run on whichever
+            thread walks, so a prefetched chunk sorts behind the device.
+            The last field says whether the uniques are sorted."""
             uwords, uidx, rank, clears = timed_assign(s0, cnt, chunk)
             u = len(uwords)
-            srt = (self._link_profile is None and cdt is not None
-                   and sortable(u) and _elect_digest_mode(
-                       None, u, cnt, 0, digest_bpu, words_bpr, True,
-                       rates=rates)
+            srt = (cdt is not None and sortable(u)
+                   and _elect_digest_mode(u, cnt, 0, digest_bpu, words_bpr,
+                                          True)
                    and sort(uwords, uidx))
             return uwords, uidx, rank, clears, srt
 
@@ -1769,24 +1525,6 @@ class TpuBatchedStorage(RateLimitStorage):
                 with self._span("decide", chunk):
                     if mode == "bits":
                         got = np.unpackbits(arr)[:count].astype(bool)
-                    elif mode == "split":
-                        # [packed singleton bits | multi count bytes] ->
-                        # one per-unique counts lane, then the standard
-                        # rank compare (singleton counts are exactly
-                        # their allow bit).
-                        from ratelimiter_tpu.engine.native_index import (
-                            relay_decide,
-                        )
-
-                        uidx2, rank, u, n_s, s_pad, m_pad, cdt_l = extra
-                        csize = np.dtype(cdt_l).itemsize
-                        counts_all = np.empty(u, dtype=cdt_l)
-                        counts_all[:n_s] = np.unpackbits(
-                            arr[:s_pad // 8])[:n_s]
-                        counts_all[n_s:] = arr[
-                            s_pad // 8:s_pad // 8 + m_pad * csize].view(
-                                cdt_l)[:u - n_s]
-                        got = relay_decide(counts_all, uidx2, rank)
                     else:  # digest: reconstruct from per-unique counts
                         from ratelimiter_tpu.engine.native_index import (
                             relay_decide,
@@ -1796,7 +1534,7 @@ class TpuBatchedStorage(RateLimitStorage):
                         got = relay_decide(arr[:u], uidx, rank)
                     out[start:start + count] = got
                 n_allowed = int(got.sum())
-                with tot["_lock"]:
+                with rec_lock:
                     if rec is not None:
                         rec["fetch_s"] = round(fetch.secs, 6)
                     self._record_dispatch(algo, count, n_allowed,
@@ -1809,13 +1547,15 @@ class TpuBatchedStorage(RateLimitStorage):
                 for b in bufs:
                     self._staging.give(b)
 
-        cursor = _ChunkCursor(plan, pipelined)
+        # The growth schedule: a _RELAY_CHUNK first chunk, then
+        # _grown_chunk after each dispatch.
+        chunk_size = _RELAY_CHUNK
         start = 0
         ci = 0  # the chunk's index in this call: every span's metadata
         fut = None  # prefetched next-chunk assignment (holds pins)
         try:
             while start < n:
-                cn = cursor.next_size(n - start)
+                cn = min(chunk_size, n - start)
                 with self._span("assign", ci) as waited:
                     if fut is not None:
                         uwords, uidx, rank, clears, presorted = fut.result()
@@ -1871,93 +1611,18 @@ class TpuBatchedStorage(RateLimitStorage):
                         # below — they must never disagree.
                         srt_ok = sortable(u)
                         digest = cdt is not None and _elect_digest_mode(
-                            self._link_profile, u, cn, n_delta, digest_bpu,
-                            words_bpr, srt_ok,
-                            cdt_size=np.dtype(cdt).itemsize if cdt else 1,
-                            rates=rates)
-                        # Split-digest election (r5): singletons as a 3-byte
-                        # slot plane with BIT decisions back, multis as
-                        # classic uwords+counts — beats classic digest when
-                        # most uniques are singletons and beats words mode
-                        # at high u/n, per-direction costs compared against
-                        # whichever of the two won above.
-                        split = False
-                        n_singles = 0
-                        if (self._link_profile is not None and cdt is not None
-                                and not multi_lid and rb >= 2
-                                and eng.num_slots <= 0xFFFFFF
-                                and u >= _SORT_UNIQUES_MIN):
-                            prof = self._link_profile
-                            up_r = max(prof[0], 1.0)
-                            down_r = max(prof[2], 1.0) if len(prof) > 2 else up_r
-                            cdt_b = np.dtype(cdt).itemsize
-                            singles_mask = (((uwords >> np.uint32(1))
-                                             & np.uint32((1 << rb) - 1)) == 1)
-                            n_singles = int(singles_mask.sum())
-                            n_multi = u - n_singles
-                            cost_split = (
-                                n_singles * (3.0 / up_r + 0.125 / down_r)
-                                + n_multi * (4.0 / up_r + cdt_b / down_r)
-                                + u * (rates["s_per_unique_unsorted"]
-                                       + _SPLIT_HOST_S_PER_UNIQUE))
-                            if digest:
-                                # Classic digest uploads exactly the 4 B
-                                # uword and downloads the cdt count (the
-                                # blended digest_bpu would overcharge the
-                                # upload by 1 B at cdt_b=1).
-                                dev_u = rates["s_per_unique_sorted" if srt_ok
-                                              else "s_per_unique_unsorted"]
-                                rival = u * (4.0 / up_r + cdt_b / down_r
-                                             + dev_u)
-                            else:
-                                rival = cn * ((words_bpr - 0.125) / up_r
-                                              + 0.125 / down_r
-                                              + rates["s_per_lane"])
-                            split = cost_split < rival
+                            u, cn, n_delta, digest_bpu, words_bpr, srt_ok)
                     now = self._monotonic_now()
                     with self._span("layout", ci) as lay:
-                        if split:
-                            from ratelimiter_tpu.engine.native_index import (
-                                split_layout,
-                            )
-
-                            srt = False  # split lanes dispatch unsorted
-                            s3, mwords, uidx2, n_s = split_layout(
-                                uwords, rb, uidx, singles=singles_mask)
-                            # Quarter-octave buckets: pow2 padding at these
-                            # lane counts wastes up to ~55% of the wire the
-                            # split exists to save (2.7M singles -> 4.19M
-                            # pow2 lanes, measured); fine buckets cap the
-                            # waste at ~12% for a couple extra compile
-                            # shapes.  Both stay multiples of 8 (packbits).
-                            s_pad = _bucket_fine(n_s)
-                            m_pad = _bucket_fine(u - n_s)
-                            s3p = self._staging.take((s_pad, 3), np.uint8)
-                            s3p[:n_s] = s3
-                            s3p[n_s:] = 0xFF
-                            mw = self._staging.take((m_pad,), np.uint32)
-                            mw[:u - n_s] = mwords
-                            mw[u - n_s:] = 0xFFFFFFFF
-                            split_dispatch = (
-                                eng.sw_relay_counts_split_dispatch
-                                if algo == "sw"
-                                else eng.tb_relay_counts_split_dispatch)
-                            lay.end()
-                            with self._span("enqueue", ci):
-                                outh = split_dispatch(s3p, mw, lid, now, cdt)
-                            item = ("split", outh, start, cn,
-                                    (uidx2, rank, u, n_s, s_pad, m_pad, cdt),
-                                    lay.t0, rec, [s3p, mw], ci)
-                            digest = True  # per-unique accounting below
-                        elif digest:
+                        if digest:
                             # Slot-sorted digest: the C index sorts the uniques
                             # in place (uidx remapped — reconstruction is order-
                             # agnostic) so the device write is a tile sweep.
                             # srt_ok (shared with the election above) already
                             # gates on the sweep actually engaging — on the
                             # XLA fallback the scatter is order-blind and the
-                            # sort would be pure overhead.  With no link
-                            # profile the walk job has sorted already.
+                            # sort would be pure overhead.  The walk job
+                            # has sorted already where it could tell.
                             srt = presorted or (srt_ok
                                                 and sort(uwords, uidx))
                             size = _bucket_pow2(u)
@@ -2032,48 +1697,19 @@ class TpuBatchedStorage(RateLimitStorage):
                     if rec is not None:
                         rec["dispatch_s"] = round(
                             time.perf_counter() - lay.t0, 6)
-                # Grow the next chunk toward the wire budget at this chunk's
-                # measured bytes/request (skewed streams compact hard in
-                # digest mode, so their chunks grow to _RELAY_CHUNK_MAX and
-                # the fixed per-dispatch latency amortizes away).
-                if split:
-                    # Charge the PADDED lanes: that is what actually
-                    # ships, and the chunk-growth/election feedback
-                    # must see it.
-                    wire_b = (3.125 * _bucket_fine(n_singles)
-                              + (4.0 + np.dtype(cdt).itemsize)
-                              * _bucket_fine(u - n_singles))
-                else:
-                    wire_b = (digest_bpu * u + 8 * n_delta if digest
-                              else words_bpr * cn)
-                host_span = time.perf_counter() - waited.t1
-                with tot["_lock"]:
-                    tot["wire"] += wire_b
-                    tot["chunks"] += 1
-                    tot["host_s"] += host_span
-                    tot["cu"].append((int(cn), int(u)))
-                    tot["device_s"] += (
-                        u * rates["s_per_unique_sorted" if srt
-                                  else "s_per_unique_unsorted"]
-                        if digest else cn * rates["s_per_lane"])
-                    if digest:
-                        tot["digest_chunks"] += 1
-                        tot["bpu"] = digest_bpu
-                    else:
-                        tot["bpr"] = words_bpr
+                wire_b = (digest_bpu * u + 8 * n_delta if digest
+                          else words_bpr * cn)
                 if rec is not None:
                     rec["mode"] = item[0]
                     rec["wire_bytes"] = int(wire_b)
-                    rec["walk_s"] = round(tot["walk_s"], 6)  # cumulative
-                    rec["host_s"] = round(host_span, 6)
-                    if split:
-                        rec["singles"] = int(n_singles)
-                if not pipelined:
-                    bpr = max(wire_b / cn, 1e-3)
-                    budget = (_RELAY_WIRE_BUDGET_DIGEST if digest
-                              else _RELAY_WIRE_BUDGET_WORDS)
-                    cursor.grow(int(min(max(budget / bpr, _RELAY_CHUNK),
-                                        _RELAY_CHUNK_MAX)))
+                    rec["walk_s"] = round(walk_s[0], 6)  # cumulative
+                    rec["host_s"] = round(time.perf_counter() - waited.t1, 6)
+                # Grow the next chunk toward the wire budget at this chunk's
+                # bytes per request (skewed streams compact hard in digest
+                # mode, so their chunks grow fast).
+                chunk_size = _grown_chunk(
+                    _RELAY_WIRE_BUDGET_DIGEST if digest
+                    else _RELAY_WIRE_BUDGET_WORDS, wire_b, cn)
                 start += cn
                 ci += 1
                 if start < n:
@@ -2081,11 +1717,9 @@ class TpuBatchedStorage(RateLimitStorage):
                     # runs (GIL-free C walk) while this chunk's drain blocks
                     # in its (GIL-free) fetch on the drain pool.
                     fut = self._assign_pool().submit(
-                        walk, start, cursor.peek(n - start), ci)
-                # Concurrent drain: the fetch cycle of this chunk overlaps
-                # the next chunks' walks AND the other in-flight fetches'
-                # round trips (ROUND_NOTES r5: serial cycles 688 ms vs
-                # concurrent 295 ms for 3 chunks).
+                        walk, start, min(chunk_size, n - start), ci)
+                # Concurrent drain: this chunk's fetch overlaps the next
+                # chunk's walk and the other in-flight fetches.
                 drains.submit(drain, *item)
             drains.finish()  # propagate any drain error before returning
         finally:
@@ -2095,8 +1729,6 @@ class TpuBatchedStorage(RateLimitStorage):
                     lambda res: (res[0] >> np.uint32(rb + 1)).astype(
                         np.int32))
             drains.finish(swallow=True)  # no-op on the normal path
-        with self._span("plan"):
-            self._plan_finish(plan_key, plan, pipelined, n, tot, t_pass0)
         return out
 
     def _stream_weighted(self, algo, lid, assign_uniques, n, permits,
@@ -2133,6 +1765,7 @@ class TpuBatchedStorage(RateLimitStorage):
         r_cap = min(_WREL_MAX_R, (1 << rb) - 1)
         out = np.empty(n, dtype=bool)
         drains = _DrainSet(self._drain_pool())
+        rec_lock = threading.Lock()
 
         def drain(kind, handle, start, count, extra, t0, rec):
             with self._span("fetch") as fetch:
@@ -2165,28 +1798,29 @@ class TpuBatchedStorage(RateLimitStorage):
             out[start:start + count] = got
             dt_us = (time.perf_counter() - t0) * 1e6
             n_allowed = int(got.sum())
-            with tot["_lock"]:
+            with rec_lock:
                 if rec is not None:
                     rec["fetch_s"] = round(
                         rec.get("fetch_s", 0) + fetch.secs, 6)
                 self._record_dispatch(algo, count, n_allowed, dt_us,
                                       path=f"relay_w|{kind}", lid=lid)
 
-        # Chunk plan election — same machinery as _stream_relay (first
-        # pass measures at the growth schedule; later passes may run a
-        # fixed pipelined split with eager drains).
-        plan_key = ("weighted", key_kind, algo,
-                    _bucket_fine(n, floor=_RELAY_CHUNK))  # banded, see relay
-        plan, pipelined, tot, timed_assign, t_pass0 = self._plan_setup(
-            plan_key, assign_uniques, index)
-        rates = self._device_rates()
+        walk_s = [0.0]  # cumulative walk seconds, as in _stream_relay
 
-        cursor = _ChunkCursor(plan, pipelined)
+        def timed_assign(s0, cnt):
+            with self._span("index") as timed:
+                r = assign_uniques(s0, cnt)
+            self._record_index_phases(index)
+            walk_s[0] += timed.secs
+            return r
+
+        # The growth schedule of _stream_relay, at the weighted budget.
+        chunk_size = _RELAY_CHUNK
         start = 0
         fut = None  # prefetched next-chunk assignment (holds pins)
         try:
             while start < n:
-                cn = cursor.next_size(n - start)
+                cn = min(chunk_size, n - start)
                 t_a0 = time.perf_counter()
                 if fut is not None:
                     uwords, uidx, rank, clears = fut.result()
@@ -2230,7 +1864,6 @@ class TpuBatchedStorage(RateLimitStorage):
                                       start, cn, (uidx, rank, u), t0, rec)
                         csize = np.dtype(cdt).itemsize
                         wire_b = (5 + csize) * u_b
-                        dev_s = u_b * rates["s_per_unique_unsorted"]
                         if rec is not None:
                             rec["mode"] = "weighted_coal"
                             rec["wire_bytes"] = int(wire_b)
@@ -2291,7 +1924,6 @@ class TpuBatchedStorage(RateLimitStorage):
                                           cn, pos, t0, rec)
                         wire_b = (4 * u_b + len(perms_rank)
                                   + len(perms_rank) // 8)
-                        dev_s = cn * rates["s_per_lane"]  # scan ~ lanes
                         if rec is not None:
                             rec["mode"] = "weighted"
                             rec["wire_bytes"] = int(wire_b)
@@ -2311,31 +1943,20 @@ class TpuBatchedStorage(RateLimitStorage):
                             drains.submit(drain, "flat", bits, start + off,
                                           sl, None, t0, rec)
                         wire_b = 5.0 * cn
-                        dev_s = cn * rates["s_per_lane"]
                         if rec is not None:
                             rec["mode"] = "flat_fb"
                             rec["wire_bytes"] = int(wire_b)
-                host_span = time.perf_counter() - t_a0 - t_assign
-                with tot["_lock"]:
-                    tot["wire"] += wire_b
-                    tot["chunks"] += 1
-                    tot["host_s"] += host_span
-                    tot["cu"].append((int(cn), int(u)))
-                    tot["bpr"] = wire_b / max(cn, 1)
-                    tot["device_s"] += dev_s
                 if rec is not None:
-                    rec["walk_s"] = round(tot["walk_s"], 6)  # cumulative
-                    rec["host_s"] = round(host_span, 6)
-                if not pipelined:
-                    bpr = max(wire_b / cn, 1e-3)
-                    cursor.grow(int(min(
-                        max(_RELAY_WIRE_BUDGET_WEIGHTED / bpr,
-                            _RELAY_CHUNK), _RELAY_CHUNK_MAX)))
+                    rec["walk_s"] = round(walk_s[0], 6)  # cumulative
+                    rec["host_s"] = round(
+                        time.perf_counter() - t_a0 - t_assign, 6)
+                chunk_size = _grown_chunk(_RELAY_WIRE_BUDGET_WEIGHTED, wire_b,
+                                          cn)
                 start += cn
                 if start < n:
                     # Prefetch the next chunk's assignment (see _stream_relay).
                     fut = self._assign_pool().submit(
-                        timed_assign, start, cursor.peek(n - start))
+                        timed_assign, start, min(chunk_size, n - start))
             drains.finish()  # propagate any drain error before returning
         finally:
             if fut is not None:
@@ -2344,7 +1965,6 @@ class TpuBatchedStorage(RateLimitStorage):
                     lambda res: (res[0] >> np.uint32(rb + 1)).astype(
                         np.int32))
             drains.finish(swallow=True)  # no-op on the normal path
-        self._plan_finish(plan_key, plan, pipelined, n, tot, t_pass0)
         return out
 
     def _stream_flat(self, algo, lid, assign, n, permits, oversize,
@@ -2956,14 +2576,11 @@ class TpuBatchedStorage(RateLimitStorage):
             lane.drains.submit(drain)
 
         # Chunk sizing: learned steady-state size per stream shape (the
-        # single-device election machinery stays unused here — the
         # lanes' host work is already off the critical path, so giant
         # chunks win).
-        plan_key = ("relay_sharded", key_kind, algo, bool(multi_lid),
-                    _bucket_fine(n, floor=_RELAY_CHUNK))
-        plan = self._chunk_plans.get(plan_key)
-        chunk = (int(plan["chunk"]) if plan and plan.get("chunk")
-                 else _RELAY_CHUNK)
+        shape_key = (key_kind, algo, bool(multi_lid),
+                     _bucket_fine(n, floor=_RELAY_CHUNK))
+        chunk = self._sharded_chunks.get(shape_key, _RELAY_CHUNK)
         inflight: list = []
         ci = 0
         start = 0
@@ -2999,14 +2616,12 @@ class TpuBatchedStorage(RateLimitStorage):
                     if ctx["pack_s"]:
                         rec["pack_s"] = round(ctx["pack_s"], 6)
             if wire_b > 0 and ctx["cn"]:
-                bpr = max(wire_b / ctx["cn"], 1e-3)
                 digesty = sum(1 for m in ctx["modes"] if m == "digest")
                 mody = max(sum(1 for m in ctx["modes"] if m), 1)
-                budget = (_RELAY_WIRE_BUDGET_DIGEST
-                          if 2 * digesty >= mody
-                          else _RELAY_WIRE_BUDGET_WORDS)
-                chunk = int(min(max(budget / bpr, _RELAY_CHUNK),
-                                _RELAY_CHUNK_MAX))
+                chunk = _grown_chunk(_RELAY_WIRE_BUDGET_DIGEST
+                                     if 2 * digesty >= mody
+                                     else _RELAY_WIRE_BUDGET_WORDS,
+                                     wire_b, ctx["cn"])
 
         try:
             while start < n and not stop.is_set():
@@ -3081,8 +2696,7 @@ class TpuBatchedStorage(RateLimitStorage):
         if errors:
             errors.sort(key=lambda e: (e[0], e[1]))
             raise errors[0][2]
-        self._chunk_plans[plan_key] = {"kind": "giant", "chunk": chunk,
-                                       "passes": 3}
+        self._sharded_chunks[shape_key] = chunk
         return out
 
     def _route_sharded(self, eng, kchunk=None, h1=None, h2=None):
@@ -3307,229 +2921,6 @@ class TpuBatchedStorage(RateLimitStorage):
         if hasattr(self.engine, "warm_micro_shapes"):
             self.engine.warm_micro_shapes()
 
-    # ------------------------------------------------------------------------
-    # Link-adaptive chunk planning (VERDICT r3 #1)
-    # ------------------------------------------------------------------------
-    def set_link_profile(self, upload_bytes_per_s: float,
-                         rtt_s: float,
-                         download_bytes_per_s: float | None = None) -> None:
-        """Tell the streaming loops what the host<->device link measures
-        (bench probes it; a service can call :meth:`probe_link`).  Clears
-        cached chunk plans — they were elected for the old link.  The
-        download rate defaults to the upload rate when the caller only
-        probed one direction; the pre-PR-1 remote link degrades the two
-        independently, so callers that CAN probe both should."""
-        self._link_profile = (float(upload_bytes_per_s), float(rtt_s),
-                              float(download_bytes_per_s
-                                    if download_bytes_per_s is not None
-                                    else upload_bytes_per_s))
-        self._chunk_plans.clear()
-
-    def probe_link(self) -> Tuple[float, float, float]:
-        """Measure the link (utils/link.py — the same probe the bench
-        logs) and feed :meth:`set_link_profile`.  ~1-1.5 s on a healthy
-        link; callers gate it (boot, or a periodic health task)."""
-        from ratelimiter_tpu.utils.link import measure_link
-
-        up_bps, rtt_s, down_bps = measure_link()
-        self.set_link_profile(up_bps, rtt_s, down_bps)
-        return self._link_profile
-
-    def _elect_chunk_plan(self, key: tuple, n: int, tot: dict,
-                          wall_s: float) -> None:
-        """End-of-first-pass election for a stream shape: keep giant
-        chunks (wire-budget growth), or switch later passes to a fixed
-        descending SCHEDULE of chunk sizes.
-
-        ``tot`` holds this pass's measured totals at the giant schedule
-        (walk_s + host_s -> the pass's serial CPU rate, wire bytes,
-        per-chunk (c, u) pairs -> the dedup curve, digest_chunks ->
-        which mode the pass ran).  Candidate schedules from
-        :func:`_schedule_candidates` are ranked by
-        :func:`_sim_schedule_wall` under the measured remote-link model
-        (concurrent drains overlap fetch round trips; link bytes
-        serialize; CPU serializes); the best wins if it beats the
-        simulated giant baseline by _PIPELINE_WIN_MARGIN.  The revert
-        check (measured pipelined walls vs the giant pass's measured
-        wall) remains the safety net for simulator error.
-
-        A GIANT verdict stays provisional for a few passes: the first
-        pass of a fresh storage compiles inside its fetches and walks
-        insert-heavy — later (clean) giant passes re-elect.  A
-        pipelined verdict is sticky, and a plan reverted by
-        _maybe_revert_plan is locked giant, so the plan cannot
-        oscillate."""
-        cur = self._chunk_plans.get(key)
-        if cur is not None and (cur["kind"] != "giant" or cur.get("locked")
-                                or cur.get("passes", 0) >= 3):
-            return
-        if self._link_profile is None:
-            return
-        if n < (_RELAY_CHUNK << 2) or tot["walk_s"] <= 0:
-            return
-        prof = self._link_profile
-        up, rtt = prof[0], prof[1]
-        down = prof[2] if len(prof) > 2 else up
-        chunks = max(tot.get("chunks", 1), 1)
-        wire_s = tot["wire"] / max(up, 1.0)
-        serial_pred = (tot["walk_s"] + tot.get("host_s", 0.0) + wire_s
-                       + tot.get("device_s", 0.0) + chunks * rtt)
-        if cur is None:
-            if len(self._chunk_plans) >= 128:
-                # Bound the cache, evicting cheapest-to-lose first
-                # (ADVICE r4): giant/provisional plans cost one measuring
-                # pass to rebuild, so they go before ACTIVE pipelined
-                # plans (wiping one forces a mid-service re-measure plus
-                # fresh compile shapes) and before LOCKED plans (wiping
-                # one re-enables the oscillation its lock prevents).
-                # Only if each tier alone still exceeds the bound does
-                # the memory bound win outright.
-                self._chunk_plans = {k: v for k, v
-                                     in self._chunk_plans.items()
-                                     if v.get("locked")
-                                     or v["kind"] == "pipelined"}
-                if len(self._chunk_plans) >= 128:
-                    self._chunk_plans = {k: v for k, v
-                                         in self._chunk_plans.items()
-                                         if v.get("locked")}
-                if len(self._chunk_plans) >= 128:
-                    self._chunk_plans.clear()
-            # The very first pass over a fresh stream shape is the wrong
-            # evidence to elect from: its walk is insert/eviction-heavy
-            # (2-4x the steady hit walk) and its fetches absorb XLA
-            # compiles.  Record a provisional giant verdict; the next
-            # giant pass measures steady state and elects for real.
-            self._chunk_plans[key] = {"kind": "giant", "chunk": 0,
-                                      "ref": round(serial_pred, 4),
-                                      "passes": 1}
-            return
-        digest_frac = tot.get("digest_chunks", 0) / chunks
-        # Dedup curve u = A * c^alpha fitted from the growth schedule's
-        # most separated (chunk, uniques) pairs; digest wire AND device
-        # lanes scale with uniques, so schedules with more chunks pay
-        # A * sum(c_i^alpha) > A * n^alpha and the simulator sees it.
-        cu = [p for p in tot.get("cu", []) if p[0] > 0 and p[1] > 0]
-        alpha, a_fit = 1.0, 1.0
-        if len(cu) >= 2:
-            (c1, u1) = cu[0]
-            (c2, u2) = max(cu, key=lambda p: p[0])
-            if c2 > c1 * 1.5:
-                import math
-
-                alpha = min(max(math.log(max(u2, 1) / max(u1, 1))
-                                / math.log(c2 / c1), 0.55), 1.0)
-            a_fit = u2 / (c2 ** alpha)
-        elif cu:
-            a_fit = cu[0][1] / float(cu[0][0])
-        rates = self._device_rates()
-        bpu_up = 8.0 if tot.get("bpu", 6.0) >= 10.0 else 4.0
-        bpu_down = 2.0 if tot.get("bpu", 6.0) >= 10.0 else 1.0
-        dev_lane = rates["s_per_unique_unsorted" if digest_frac > 0.5
-                         else "s_per_lane"]
-        if key[0] == "weighted" and cu:
-            # Weighted wire = 4 B/unique words + ~1.125 B/request permits
-            # and bits: express it per UNIQUE through the giant pass's
-            # request/unique ratio so the simulator's dedup curve (the
-            # per-unique share grows subadditively as chunks shrink)
-            # applies — the words branch would wrongly see splitting as
-            # wire-neutral.  Device cost is the per-request scan, also
-            # mapped per unique.
-            r_pu = max(cu[-1][0] / max(cu[-1][1], 1), 1.0)
-            digest_frac = 1.0
-            bpu_up = 4.0 + 1.125 * r_pu
-            bpu_down = 0.125 * r_pu
-            dev_lane = rates["s_per_lane"] * r_pu
-        sim_args = dict(
-            cpu_per_req=(tot["walk_s"] + tot.get("host_s", 0.0)) / n,
-            digest_frac=digest_frac, dedup_a=a_fit, dedup_alpha=alpha,
-            # blended 6 B/unique = 4 B uword up + count back (resident
-            # lids); blended 10 = uword + 4 B lid lane up + 2 B back.
-            bpu_up=bpu_up, bpu_down=bpu_down,
-            words_up=tot.get("bpr", 4.125) - 0.125,
-            link_up=max(up * _DISPATCH_RATE_DERATE, 1.0),
-            link_down=max(down * _DISPATCH_RATE_DERATE, 1.0), rtt=rtt,
-            dev_per_lane=dev_lane)
-        giant_sim = _sim_schedule_wall([_RELAY_CHUNK, n - _RELAY_CHUNK],
-                                       **sim_args)
-        best = None
-        for sizes in _schedule_candidates(n, _RELAY_CHUNK,
-                                          words_pow2=digest_frac <= 0.5):
-            w = _sim_schedule_wall(sizes, **sim_args)
-            if best is None or w < best[0]:
-                best = (w, sizes)
-        if best is not None and best[0] < _PIPELINE_WIN_MARGIN * giant_sim:
-            # ref: the simulated baseline that justified the election.
-            # giant_wall: the MEASURED wall of the (clean, steady) giant
-            # pass that elected — the revert check compares against
-            # this, not the simulated figure (simulator error must not
-            # un-revert a plan the measurements rejected).
-            self._chunk_plans[key] = {"kind": "pipelined",
-                                      "schedule": tuple(best[1]),
-                                      "chunk": int(max(best[1])),
-                                      "ref": round(serial_pred, 4),
-                                      "giant_wall": round(wall_s, 4),
-                                      "passes": 0, "best": None}
-        else:
-            self._chunk_plans[key] = {
-                "kind": "giant", "chunk": 0, "ref": round(serial_pred, 4),
-                "passes": (cur.get("passes", 0) + 1) if cur else 1}
-
-    def _plan_setup(self, plan_key: tuple, assign_uniques, index):
-        """Shared head of the relay/weighted streaming loops: look up the
-        chunk plan, build the measurement accumulator, and wrap the
-        assign closure so the TRUE walk seconds (and ``index``'s route
-        and merge seconds, where it is partitioned) are recorded
-        wherever the walk runs (main thread or prefetch worker).  Returns
-        (plan, pipelined, tot, timed_assign, t_pass0)."""
-        plan = self._chunk_plans.get(plan_key)
-        pipelined = plan is not None and plan["kind"] == "pipelined"
-        tot = {"walk_s": 0.0, "wire": 0.0, "chunks": 0,
-               "device_s": 0.0, "digest_chunks": 0, "host_s": 0.0,
-               "cu": [], "_lock": threading.Lock()}
-
-        def timed_assign(s0, cnt, chunk=None):
-            with self._span("index", chunk) as walk:
-                r = assign_uniques(s0, cnt)
-            self._record_index_phases(index)
-            tot["walk_s"] += walk.secs
-            return r
-
-        return plan, pipelined, tot, timed_assign, time.perf_counter()
-
-    def _plan_finish(self, plan_key: tuple, plan, pipelined: bool, n: int,
-                     tot: dict, t_pass0: float) -> None:
-        """Shared tail: giant passes (re-)elect — a provisional giant
-        verdict from a compile-contaminated first pass gets corrected by
-        clean later measurements — and pipelined passes feed the revert
-        check."""
-        if pipelined:
-            self._maybe_revert_plan(plan_key,
-                                    time.perf_counter() - t_pass0)
-        else:
-            self._elect_chunk_plan(plan_key, n, tot,
-                                   time.perf_counter() - t_pass0)
-
-    def _maybe_revert_plan(self, key: tuple, wall_s: float) -> None:
-        """A pipelined plan whose BEST pass (over at least two — the
-        first re-compiles the new shapes) still measures clearly worse
-        than the MEASURED wall of the giant pass that elected it
-        reverts to giant — sticky, like the election, so chunk shapes
-        stay deterministic after.  (Comparing against the analytic
-        serial baseline instead wrongly reverted plans that beat the
-        real giant: its per-fetch fixed cost is under-calibrated.)"""
-        plan = self._chunk_plans.get(key)
-        if plan is None or plan["kind"] != "pipelined":
-            return
-        plan["passes"] += 1
-        plan["best"] = (wall_s if plan["best"] is None
-                        else min(plan["best"], wall_s))
-        ref = plan.get("giant_wall", plan["ref"])
-        if plan["passes"] >= 2 and plan["best"] > _PIPELINE_REVERT * ref:
-            # locked: a reverted shape must not be re-elected later, or
-            # the plan (and its compile shapes) could oscillate.
-            self._chunk_plans[key] = {"kind": "giant", "chunk": 0,
-                                      "ref": plan["ref"], "locked": True}
-
     @staticmethod
     def _unpin_held(index, held) -> None:
         """Release pins accumulated as a list of slot arrays — the finally
@@ -3612,7 +3003,7 @@ class TpuBatchedStorage(RateLimitStorage):
                          lid=None, **extra) -> None:
         """Latency histogram + enriched decision trace + SLO anomaly
         hook for a completed dispatch.  ``path`` names the dispatch
-        route (micro / relay|digest / relay|split / flat / sharded|...);
+        route (micro / relay|digest / flat / sharded|...);
         ``extra`` carries enrichments like the shard id.  ``lid`` (a
         single-tenant dispatch's limiter id) feeds the per-tenant usage
         ring; mixed-tenant micro batches feed it from their drainer
@@ -4038,21 +3429,6 @@ class TpuBatchedStorage(RateLimitStorage):
             pool = cf.ThreadPoolExecutor(1, thread_name_prefix="assignpf")
             self._assign_pool_obj = pool
         return pool
-
-    def _device_rates(self) -> dict:
-        """Per-lane device step rates for the elections: probed per
-        (platform, device kind) and cached (engine/device_rates.py)
-        when a link profile is set — profile-less storages never probe
-        (elections don't run without one) and use the v5e fallback."""
-        if self._link_profile is None:
-            return _FB_RATES
-        r = getattr(self, "_device_rates_obj", None)
-        if r is None:
-            from ratelimiter_tpu.engine.device_rates import get_device_rates
-
-            r = get_device_rates()
-            self._device_rates_obj = r
-        return r
 
     def _drain_pool(self):
         """Drain workers: device fetches block here CONCURRENTLY so

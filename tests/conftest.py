@@ -13,12 +13,6 @@ the virtual device count.
 
 import os
 
-# Device-rate probing (engine/device_rates.py) would spend seconds
-# compiling probe chains on the CPU backend and make election inputs
-# vary with the host — tests pin the v5e fallback rates instead; the
-# probe logic itself is unit-tested via its cache/fallback paths.
-os.environ.setdefault("RATELIMITER_RATE_PROBE", "0")
-
 flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:
     os.environ["XLA_FLAGS"] = (

@@ -557,7 +557,6 @@ def test_actuator_policies_endpoint_and_pin():
         "storage.num_slots": "4096",
         "parallel.shard": "off",
         "warmup.enabled": "false",
-        "link.probe.enabled": "false",
         "ratelimiter.control.enabled": "true",
         "ratelimiter.control.interval_ms": "60000",  # tick manually
     })

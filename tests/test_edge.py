@@ -517,7 +517,6 @@ def test_wiring_edge_disabled_without_leases():
     ctx = build_app(AppProperties({
         "storage.backend": "tpu", "storage.num_slots": "1024",
         "parallel.shard": "off", "warmup.enabled": "false",
-        "link.probe.enabled": "false",
         "ratelimiter.edge.enabled": "true",  # but leases are off
     }))
     try:
@@ -536,7 +535,6 @@ def test_wiring_edge_sessions_and_actuator():
     ctx = build_app(AppProperties({
         "storage.backend": "tpu", "storage.num_slots": "1024",
         "parallel.shard": "off", "warmup.enabled": "false",
-        "link.probe.enabled": "false",
         "ratelimiter.lease.enabled": "true",
         "ratelimiter.lease.max_bulk_budget": "4096",
         "ratelimiter.edge.enabled": "true",

@@ -41,7 +41,6 @@ def test_all_registered_meters_are_documented():
         "batcher.max_delay_ms": "0.2",
         "parallel.shard": "off",
         "warmup.enabled": "false",
-        "link.probe.enabled": "false",
         "ratelimiter.sidecar.enabled": "true",
         "ratelimiter.sidecar.port": "0",
         "ratelimiter.lease.enabled": "true",

@@ -748,35 +748,3 @@ def test_sharded_index_pins_under_concurrent_batched_assign_remove():
     for s, mine, gslots in held:
         for k in mine:
             assert ix.get((3, int(k))) is None
-
-
-def test_split_layout_c_numpy_parity():
-    """rl_split_layout (C) must emit byte-identical planes, words, and
-    remapped uidx to the numpy fallback on mixed singleton/multi
-    chunks."""
-    import unittest.mock as mock
-
-    import numpy as np
-
-    import ratelimiter_tpu.engine.native_index as ni
-
-    lib = ni._load_library()
-    if lib is None or not hasattr(lib, "rl_split_layout"):
-        import pytest
-
-        pytest.skip("rl_split_layout unavailable (stale .so?) — the "
-                    "parity check would compare numpy against numpy")
-    rng = np.random.default_rng(9)
-    u, n, rb = 50_000, 140_000, 8
-    counts = rng.integers(1, 5, u).astype(np.uint32)
-    slots = rng.permutation(1 << 22)[:u].astype(np.uint32)
-    uwords = (slots << np.uint32(rb + 1)) | (counts << np.uint32(1))
-    uidx = rng.integers(0, u, n).astype(np.int32)
-    s3c, mwc, u2c, nsc = ni.split_layout(uwords.copy(), rb, uidx.copy())
-    with mock.patch.object(ni, "_load_library", lambda: None):
-        s3n, mwn, u2n, nsn = ni.split_layout(uwords.copy(), rb,
-                                             uidx.copy())
-    assert nsc == nsn == int((counts == 1).sum())
-    np.testing.assert_array_equal(s3c, s3n)
-    np.testing.assert_array_equal(mwc, mwn)
-    np.testing.assert_array_equal(u2c, u2n)

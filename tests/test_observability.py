@@ -361,7 +361,6 @@ def test_actuator_prometheus_and_flightrecorder_endpoints():
         "batcher.max_delay_ms": "0.2",
         "parallel.shard": "off",
         "warmup.enabled": "false",
-        "link.probe.enabled": "false",
     })
     ctx = build_app(props)
     srv = make_server(ctx, port=0)

@@ -334,7 +334,6 @@ def test_unfence_actuator_endpoint():
         "storage.num_slots": "4096",
         "parallel.shard": "auto",
         "warmup.enabled": "false",
-        "link.probe.enabled": "false",
         "ratelimiter.orchestrator.enabled": "true",
         "ratelimiter.orchestrator.probe_interval_ms": "60000",
         "replication.interval_ms": "60000",
@@ -521,7 +520,6 @@ def test_build_app_serves_through_router(monkeypatch):
         "storage.num_slots": "4096",
         "parallel.shard": "auto",
         "warmup.enabled": "false",
-        "link.probe.enabled": "false",
         "ratelimiter.orchestrator.enabled": "true",
         # Park the cadences: this test drives nothing periodic.
         "ratelimiter.orchestrator.probe_interval_ms": "60000",

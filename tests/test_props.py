@@ -77,7 +77,7 @@ def test_env_override_applies_and_unknown_env_warns(
 
 def test_env_direct_keys_do_not_warn(caplog, monkeypatch, tmp_path):
     # Env vars read directly by engine/ops modules are exempt from the
-    # unknown-key scan (conftest sets RATELIMITER_RATE_PROBE already).
+    # unknown-key scan.
     monkeypatch.setenv("RATELIMITER_PALLAS", "1")
     AppProperties.load(str(tmp_path / "missing.properties"))
     assert not any("RATELIMITER_PALLAS" in rec.message

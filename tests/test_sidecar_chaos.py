@@ -143,7 +143,6 @@ def test_wiring_starts_sidecar_from_props():
     ctx = build_app(AppProperties({
         "storage.num_slots": "256",
         "warmup.enabled": "false",
-        "link.probe.enabled": "false",
         "ratelimiter.sidecar.enabled": "true",
         "ratelimiter.sidecar.port": "0",   # ephemeral
     }))

@@ -19,7 +19,7 @@ from ratelimiter_tpu.storage.tpu import _RELAY_CHUNK
 
 PREFIX = "ratelimiter.stream."
 CALLER_STAGES = ("assign", "elect", "clear", "layout", "enqueue",
-                 "drain_wait", "plan")
+                 "drain_wait")
 
 
 def _timer(st, stage):

@@ -506,7 +506,6 @@ def test_flightrecorder_http_filters_and_tenants_endpoint():
         "batcher.max_delay_ms": "0.2",
         "parallel.shard": "off",
         "warmup.enabled": "false",
-        "link.probe.enabled": "false",
         "ratelimiter.lease.enabled": "true",
     })
     ctx = build_app(props)

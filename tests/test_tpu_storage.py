@@ -234,280 +234,187 @@ def test_stream_permits_over_i32_denied_not_wrapped():
     storage.close()
 
 
-@pytest.mark.parametrize("weighted", [False, True])
-def test_chunk_plan_pipelined_preserves_decisions(monkeypatch, weighted):
-    """Link-adaptive chunk plans (VERDICT r3 #1): a pipelined plan (the
-    fast-link election outcome, forced here for determinism) runs fixed
-    chunks with eager drains — decisions must match a plan-less storage
-    pass-for-pass."""
-    import ratelimiter_tpu.storage.tpu as tpu_mod
+# The cells' chunk constants (storage/tpu.py) scaled down by this factor,
+# so a call of 2^21 / _PIN_SCALE ids cuts like a 2^21-id benchmark call.
+_PIN_SCALE = 128
 
-    monkeypatch.setattr(tpu_mod, "_RELAY_CHUNK", 256)
-    monkeypatch.setattr(tpu_mod, "_RELAY_CHUNK_MAX", 1 << 14)
+
+def _pin_scale(monkeypatch, tpu_mod):
+    for name, value in (("_RELAY_CHUNK", 1 << 19),
+                        ("_RELAY_CHUNK_MAX", 1 << 24),
+                        ("_RELAY_WIRE_BUDGET_DIGEST", 16 << 20),
+                        ("_RELAY_WIRE_BUDGET_WORDS", 16 << 20),
+                        ("_RELAY_WIRE_BUDGET_WEIGHTED", 48 << 20),
+                        ("_SORT_UNIQUES_MIN", 1 << 12)):
+        monkeypatch.setattr(tpu_mod, name, value // _PIN_SCALE)
+
+
+def _zipf_ids(rng, n, keys, s=1.1):
+    """Rank-ordered Zipf(s) ids over ``keys`` keys (id 0 the hottest)."""
+    p = np.arange(1, keys + 1, dtype=np.float64) ** -s
+    return rng.choice(keys, size=n, p=p / p.sum()).astype(np.int64)
+
+
+# family -> (path, per call: [(chunk size, mode), ...]) for the second
+# and third calls of one storage.  Zipf 4096 + 12288 and uniform
+# 2 x 4096 are the cells' 524,288 + 1,572,864 and 2 x 524,288 scaled.
+_PINNED_CHUNKS = {
+    "tb-ints-zipf": ("relay", [
+        [(4096, "digest-sorted"), (12288, "digest-sorted")],
+        [(4096, "digest-sorted"), (12288, "digest-sorted")]]),
+    "sw-ints-uniform": ("relay", [
+        [(4096, "digest-sorted"), (4096, "digest-sorted")],
+        [(4096, "digest-sorted"), (4096, "digest-sorted")]]),
+    "tb-weighted": ("relay_w", [
+        [(4096, "weighted"), (62914, "weighted"), (64062, "weighted")],
+        [(4096, "weighted"), (62914, "weighted"), (64062, "weighted")]]),
+    "tb-multi-lid": ("relay", [
+        [(4096, "digest"), (42737, "digest"), (18703, "digest")],
+        [(4096, "digest"), (58153, "digest"), (3287, "digest")]]),
+    "sw-strs": ("relay", [
+        [(4096, "bits"), (31775, "bits"), (29665, "bits")],
+        [(4096, "bits"), (31775, "bits"), (29665, "bits")]]),
+}
+
+
+@pytest.mark.parametrize("family", ["tb-ints-zipf", "sw-ints-uniform",
+                                    "tb-weighted", "tb-multi-lid",
+                                    "sw-strs"])
+def test_stream_chunk_sequence_pinned(monkeypatch, family):
+    """The stream loops' chunk sequence, pinned: each call's chunk sizes
+    and modes on the second and third calls of one storage, at the
+    cells' constants scaled down by _PIN_SCALE.  The Zipf and uniform
+    families force the sorted digest step the cells run on the chip
+    (the tile sweep serves both there); multi-lid and string keys take
+    the bytes rule; weighted runs its own loop.  A change to the growth
+    schedule or the mode rule shows here as a different sequence."""
+    import ratelimiter_tpu.storage.tpu as tpu_mod
+    from ratelimiter_tpu.engine.native_index import native_available
+
+    if not native_available():
+        pytest.skip("needs the native library")
+    _pin_scale(monkeypatch, tpu_mod)
+    if family in ("tb-ints-zipf", "sw-ints-uniform"):
+        monkeypatch.setattr(tpu_mod, "_presorted_scatter_usable",
+                            lambda eng, algo, padded: True)
+    algo = family[:2]
+    now = [T0]
+    rng = np.random.default_rng(sum(map(ord, family)))
+    zipf_keys = (1 << 20) // _PIN_SCALE
+    uniform_keys = 10_000_000 // _PIN_SCALE
+    st = TpuBatchedStorage(num_slots=1 << 17, clock_ms=lambda: now[0])
+    cfg = (RateLimitConfig(max_permits=100, window_ms=60_000,
+                           enable_local_cache=False) if algo == "sw"
+           else RateLimitConfig(max_permits=50, window_ms=60_000,
+                                refill_rate=10.0))
+    lid = st.register_limiter(algo, cfg)
+    lid2 = st.register_limiter(algo, RateLimitConfig(
+        max_permits=5, window_ms=60_000, refill_rate=1.0))
+
+    def call():
+        if family == "tb-ints-zipf":
+            return st.acquire_stream_ids(
+                algo, lid, _zipf_ids(rng, (1 << 21) // _PIN_SCALE,
+                                     zipf_keys))
+        if family == "sw-ints-uniform":
+            return st.acquire_stream_ids(
+                algo, lid, rng.integers(0, uniform_keys,
+                                        (1 << 20) // _PIN_SCALE))
+        if family == "tb-weighted":
+            n = 1 << 17
+            return st.acquire_stream_ids(
+                algo, lid, rng.integers(0, 1 << 15, n),
+                rng.integers(1, 4, n))
+        if family == "tb-multi-lid":
+            n = 1 << 16
+            return st.acquire_stream_ids(
+                algo, np.where(rng.random(n) < 0.5, lid, lid2),
+                _zipf_ids(rng, n, zipf_keys))
+        keys = [f"user:{k}" for k in rng.integers(0, uniform_keys,
+                                                  1 << 16)]
+        return st.acquire_stream_strs(algo, lid, keys)
+
+    seen = []
+    for _ in range(3):
+        st.stream_stats = stats = []
+        call()
+        seen.append([(r["path"], r["n"], r["mode"]) for r in stats])
+        now[0] += 100
+    st.close()
+    path, want = _PINNED_CHUNKS[family]
+    for got, calls in zip(seen[1:], want):
+        assert got == [(path, c, m) for c, m in calls], (family, seen)
+
+
+_BOUNDARY_C = 256
+
+
+@pytest.mark.parametrize("n", [_BOUNDARY_C - 1, _BOUNDARY_C, _BOUNDARY_C + 1,
+                               3 * _BOUNDARY_C + 5])
+@pytest.mark.parametrize("weighted", [False, True])
+def test_chunk_plan_pipelined_preserves_decisions(monkeypatch, weighted, n):
+    """Chunk boundaries of the growth schedule, with the next chunk's
+    walk prefetched: a call one short of, at, one past and well past
+    the first chunk cuts where the schedule says, and every decision of
+    every call equals the oracle's (keys repeat across boundaries)."""
+    import ratelimiter_tpu.storage.tpu as tpu_mod
+    from ratelimiter_tpu.semantics import TokenBucketOracle
+
+    c = _BOUNDARY_C
+    monkeypatch.setattr(tpu_mod, "_RELAY_CHUNK", c)
+    monkeypatch.setattr(tpu_mod, "_RELAY_CHUNK_MAX", 2 * c)
     now = [1_000_000]
     rng = np.random.default_rng(3)
-    n = 4096
-    ids = rng.integers(0, 1500, n).astype(np.int64)
-    perms = (rng.integers(1, 8, n).astype(np.int64) if weighted
-             else None)
-
-    def make(planned):
-        st = TpuBatchedStorage(num_slots=4096, clock_ms=lambda: now[0])
-        lid = st.register_limiter("tb", RateLimitConfig(
-            max_permits=20, window_ms=60_000, refill_rate=1.0))
-        if planned:  # what a fast-link election produces
-            key = (("weighted", "ints", "tb", n) if weighted
-                   else ("relay", "ints", "tb", False, n))
-            st._chunk_plans[key] = {"kind": "pipelined", "chunk": 600,
-                                    "ref": 1e9, "passes": 0, "best": None}
-        return st, lid
-
-    st_a, lid_a = make(True)
-    st_b, lid_b = make(False)
-    for _ in range(3):
-        got_a = st_a.acquire_stream_ids("tb", lid_a, ids, perms)
-        got_b = st_b.acquire_stream_ids("tb", lid_b, ids, perms)
-        np.testing.assert_array_equal(got_a, got_b)
-    # The huge ref wall keeps the plan from reverting mid-test.
-    kinds = {k[0]: v["kind"] for k, v in st_a._chunk_plans.items()}
-    want = "weighted" if weighted else "relay"
-    assert kinds.get(want) == "pipelined", st_a._chunk_plans
-    st_a.close()
-    st_b.close()
-
-
-def test_chunk_plan_election_logic():
-    """Synthetic election inputs: a CPU-bound words pass elects a
-    pipelined schedule (its wire is linear in requests — splitting is
-    free and overlaps the fetch cycles); a wire-bound DIGEST pass with
-    strong dedup keeps giant chunks on a slow link (splitting inflates
-    the per-unique wire); a pipelined pass measuring clearly worse
-    reverts (sticky)."""
-    st = TpuBatchedStorage(num_slots=1 << 12)
-    n = 1 << 24
-    # Uniform words traffic: u ~ 0.9 n, wire 4.125 B/request.
-    giant_tot = {"walk_s": 1.6, "host_s": 0.4, "wire": 4.125 * n,
-                 "giant": n - (1 << 19), "fetch_s": 1.5, "chunks": 2,
-                 "digest_chunks": 0, "bpr": 4.125, "device_s": 1.0,
-                 "cu": [(1 << 19, 480_000), (n - (1 << 19), 14_800_000)]}
-    # The FIRST measurement only records a provisional giant (fresh
-    # shapes' first passes are insert- and compile-heavy); the second
-    # elects for real.
-    st.set_link_profile(85e6, 0.107, 85e6)
-    st._elect_chunk_plan(("relay", "ints", "tb", False, n), n, giant_tot, 3.5)
-    assert st._chunk_plans[("relay", "ints", "tb", False, n)]["kind"] == "giant"
-    st._elect_chunk_plan(("relay", "ints", "tb", False, n), n, giant_tot, 3.5)
-    plan = st._chunk_plans[("relay", "ints", "tb", False, n)]
-    assert plan["kind"] == "pipelined" and plan["chunk"] >= 1 << 19, plan
-    assert sum(plan["schedule"]) >= n, plan  # schedule covers the stream
-    # Wire-bound slow-link DIGEST pass with strong dedup (u ~ c^0.6):
-    # splitting multiplies the per-unique upload — giant stays.
-    st.set_link_profile(5e6, 0.107, 5e6)
-    slow_tot = {"walk_s": 0.05, "host_s": 0.02, "wire": 8.1e6,
-                "giant": n - (1 << 19), "fetch_s": 3.0, "chunks": 2,
-                "digest_chunks": 2, "bpu": 6.0, "device_s": 0.07,
-                "cu": [(1 << 19, 150_000), (n - (1 << 19), 1_200_000)]}
-    st._elect_chunk_plan(("relay", "ints", "tb", False, n), n, slow_tot, 3.2)
-    st._elect_chunk_plan(("relay", "ints", "tb", False, n), n, slow_tot, 3.2)
-    assert st._chunk_plans[("relay", "ints", "tb", False, n)]["kind"] == "giant"
-    # Revert: pipelined passes clearly worse than the serial baseline
-    # (first pass alone is NOT enough — it pays the new shapes' compiles).
-    st.set_link_profile(85e6, 0.107)
-    st._chunk_plans.clear()
-    st._elect_chunk_plan(("relay", "ints", "tb", False, n), n, giant_tot, 0.95)
-    st._elect_chunk_plan(("relay", "ints", "tb", False, n), n, giant_tot, 0.95)
-    ref = st._chunk_plans[("relay", "ints", "tb", False, n)]["ref"]
-    st._maybe_revert_plan(("relay", "ints", "tb", False, n), 10.0)
-    assert st._chunk_plans[("relay", "ints", "tb", False, n)]["kind"] == "pipelined"
-    st._maybe_revert_plan(("relay", "ints", "tb", False, n), 2.0 * ref)
-    assert st._chunk_plans[("relay", "ints", "tb", False, n)]["kind"] == "giant"
-    # A reverted plan is LOCKED: a later clean giant pass must not
-    # re-elect it back to pipelined (shape oscillation).
-    st._elect_chunk_plan(("relay", "ints", "tb", False, n), n, giant_tot, 0.95)
-    assert st._chunk_plans[("relay", "ints", "tb", False, n)]["kind"] == "giant"
-    # Whereas a PROVISIONAL giant (compile-contaminated first pass:
-    # huge measured fetch) is re-elected once clean measurements arrive.
-    st._chunk_plans.clear()
-    dirty = dict(giant_tot, fetch_s=12.0)  # compiles inside the fetches
-    st._elect_chunk_plan(("relay", "ints", "tb", False, n), n, dirty, 13.0)
-    assert st._chunk_plans[("relay", "ints", "tb", False, n)]["kind"] == "giant"
-    st._elect_chunk_plan(("relay", "ints", "tb", False, n), n, giant_tot, 0.95)
-    assert st._chunk_plans[("relay", "ints", "tb", False, n)]["kind"] == "pipelined"
+    cfg = RateLimitConfig(max_permits=20, window_ms=60_000, refill_rate=1.0)
+    st = TpuBatchedStorage(num_slots=4096, clock_ms=lambda: now[0])
+    lid = st.register_limiter("tb", cfg)
+    oracle = TokenBucketOracle(cfg)
+    want_sizes = {c - 1: [c - 1], c: [c], c + 1: [c, 1],
+                  3 * c + 5: [c, 2 * c, 5]}[n]
+    for call in range(3):
+        ids = rng.integers(0, 40, n).astype(np.int64)
+        perms = rng.integers(1, 5, n).astype(np.int64) if weighted else None
+        st.stream_stats = stats = []
+        got = st.acquire_stream_ids("tb", lid, ids, perms)
+        assert [r["n"] for r in stats] == want_sizes, stats
+        for j, k in enumerate(ids):
+            p = int(perms[j]) if weighted else 1
+            assert got[j] == oracle.try_acquire(f"id:{k}", p,
+                                                now[0]).allowed, (call, j)
+        now[0] += 700
     st.close()
 
 
-def test_link_probe_and_profile_reset():
-    """probe_link measures once and feeds the storage profile with a
-    bandwidth that cannot be the broken-probe floor clamp, and setting
-    a new profile clears cached chunk plans (they were elected for the
-    old link)."""
-    from ratelimiter_tpu.utils.link import PROBE_BYTES
-
-    st = TpuBatchedStorage(num_slots=256)
-    prof = st.probe_link()
-    # The probe clamps up_s to >= 1e-6 s; a measurement AT the clamp
-    # (PROBE_BYTES / 1e-6) means the timing collapsed — treat as broken.
-    assert st._link_profile == prof
-    assert 0 < prof[0] < PROBE_BYTES / 1e-6
-    assert 0 < prof[1] < 60.0  # a round trip measured, under a minute
-    st._chunk_plans[("relay", "ints", "tb", False, 4096)] = {
-        "kind": "pipelined", "chunk": 512, "ref": 1.0,
-        "giant_wall": 1.2, "passes": 0, "best": None}
-    st.set_link_profile(1e9, 0.001)
-    assert st._link_profile == (1e9, 0.001, 1e9)  # down defaults to up
-    assert st._chunk_plans == {}
-    st.close()
-
-
-def test_rate_aware_mode_election():
-    """_elect_digest_mode: on fast links the sorted digest's cheaper
-    device step wins even where its wire cost loses; on slow links wire
-    dominates and the verdict matches the bytes-only fallback.  With no
-    link profile (an attached device) a chunk the sorted sweep serves is
-    elected by device seconds alone; any other falls back to bytes."""
+def test_rate_aware_mode_election(monkeypatch):
+    """_elect_digest_mode: a chunk the sorted sweep serves is elected by
+    device seconds alone (per unique against per lane); any other falls
+    back to bytes.  The cells' own chunk shapes (PERF.md §5) go to
+    digest when sorted; under the bytes rule the uniform chunk goes to
+    words and the Zipf chunk, a sixth of it distinct, stays digest."""
+    import ratelimiter_tpu.storage.tpu as tpu_mod
     from ratelimiter_tpu.storage.tpu import _elect_digest_mode
 
     dig_bpu, words_bpr = 6.0, 4.125
     u, cn = 900_000, 1_000_000  # u/n = 0.9: wire alone says words
-    assert not _elect_digest_mode(None, u, cn, 0, dig_bpu, words_bpr,
+    assert not _elect_digest_mode(u, cn, 0, dig_bpu, words_bpr,
                                   False)  # bytes-only fallback: words
-    # Attached, sweep engaged: 25 ns per unique beats 60 ns per lane.
-    assert _elect_digest_mode(None, u, cn, 0, dig_bpu, words_bpr, True)
-    assert not _elect_digest_mode(None, u, cn, 0, dig_bpu, words_bpr, True,
-                                  rates={"s_per_unique_sorted": 70e-9,
-                                         "s_per_lane": 60e-9})
-    # 85 MB/s, sorted sweep engaged: device savings flip it to digest.
-    assert _elect_digest_mode((85e6, 0.1), u, cn, 0, dig_bpu, words_bpr,
-                              True)
-    # Same link but the sweep can't engage (unsorted 52 ns): words.
-    assert not _elect_digest_mode((85e6, 0.1), u, cn, 0, dig_bpu,
-                                  words_bpr, False)
-    # 5 MB/s: wire dominates; digest only wins with real dedup.
-    assert not _elect_digest_mode((5e6, 0.1), u, cn, 0, dig_bpu,
-                                  words_bpr, True)
-    assert _elect_digest_mode((5e6, 0.1), cn // 3, cn, 0, dig_bpu,
-                              words_bpr, True)
-    # Multi-lid costs (10 B/unique vs 8.125 B/request) with the delta
+    # Sweep engaged: 25 ns per unique beats 60 ns per lane.
+    assert _elect_digest_mode(u, cn, 0, dig_bpu, words_bpr, True)
+    # Zipf chunk 1 (188,052 uniques of 1,572,864) and a uniform chunk
+    # (510,815 of 524,288).
+    for u_c, cn_c in ((188_052, 1_572_864), (510_815, 524_288)):
+        assert _elect_digest_mode(u_c, cn_c, 0, dig_bpu, words_bpr, True)
+    assert _elect_digest_mode(188_052, 1_572_864, 0, dig_bpu, words_bpr,
+                              False)
+    assert not _elect_digest_mode(510_815, 524_288, 0, dig_bpu, words_bpr,
+                                  False)
+    # A dearer sorted step per unique than per lane flips it to words.
+    monkeypatch.setattr(tpu_mod, "_DEVICE_S_PER_UNIQUE_SORTED", 70e-9)
+    assert not _elect_digest_mode(u, cn, 0, dig_bpu, words_bpr, True)
+    # Multi-lid bytes (10 B/unique vs 8.125 B/request) with the delta
     # charge: dedup-poor chunks stay words, dedup-rich ones go digest.
-    assert not _elect_digest_mode((5e6, 0.1), 950_000, cn, 950_000, 10.0,
-                                  8.125, True)
-    assert _elect_digest_mode((5e6, 0.1), cn // 3, cn, cn // 3, 10.0,
-                              8.125, True)
-
-
-def test_digest_mode_election_flips_with_device_rates():
-    """VERDICT r4 #5: the words-vs-digest election consumes the PROBED
-    device rates — on a device with a cheap per-lane words step the
-    same chunk elects words, on one with an expensive step it elects
-    digest (wire identical in both cases)."""
-    from ratelimiter_tpu.storage.tpu import _elect_digest_mode
-
-    link = (50e6, 0.1, 50e6)
-    base = {"s_per_unique_sorted": 25e-9, "s_per_unique_unsorted": 52e-9}
-    fast_lane = dict(base, s_per_lane=5e-9)
-    slow_lane = dict(base, s_per_lane=300e-9)
-    kw = dict(u=900, cn=1000, n_delta=0, digest_bpu=6.0, words_bpr=4.125,
-              srt_ok=False, cdt_size=1)
-    assert _elect_digest_mode(link, rates=slow_lane, **kw) is True
-    assert _elect_digest_mode(link, rates=fast_lane, **kw) is False
-
-
-def test_device_rates_fallback_and_cache(monkeypatch, tmp_path):
-    """RATELIMITER_RATE_PROBE=0 yields the v5e fallback constants; a
-    pre-seeded disk cache is honored without probing; both are
-    memoized per (platform, kind); a failed probe raises."""
-    import json as _json
-
-    import jax
-
-    from ratelimiter_tpu.engine import device_rates as dr
-
-    monkeypatch.setattr(dr, "_mem_cache", {})
-    monkeypatch.setenv("RATELIMITER_RATE_PROBE", "0")
-    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
-    got = dr.get_device_rates()
-    assert got["source"] == "fallback"
-    assert got["s_per_lane"] == dr.FALLBACK_RATES["s_per_lane"]
-    # Seed the disk cache as a probe artifact would; a fresh mem cache
-    # must read it instead of falling back (or probing).
-    dev = jax.devices()[0]
-    kind = getattr(dev, "device_kind", dev.platform)
-    path = dr._cache_path(dev.platform, kind)
-    assert str(tmp_path) in path
-    rates = {"s_per_lane": 1e-9, "s_per_unique_sorted": 2e-9,
-             "s_per_unique_unsorted": 3e-9, "source": "probe"}
-    import os as _os
-
-    _os.makedirs(_os.path.dirname(path), exist_ok=True)
-    with open(path, "w", encoding="utf-8") as fh:
-        _json.dump(rates, fh)
-    monkeypatch.setattr(dr, "_mem_cache", {})
-    # The opt-out beats the disk artifact (determinism pin) ...
-    assert dr.get_device_rates()["source"] == "fallback"
-    # ... and with probing allowed, the artifact is honored without
-    # re-probing.
-    monkeypatch.setenv("RATELIMITER_RATE_PROBE", "1")
-    monkeypatch.setattr(dr, "_probe", lambda: (_ for _ in ()).throw(
-        AssertionError("disk cache must prevent probing")))
-    monkeypatch.setattr(dr, "_mem_cache", {})
-    got2 = dr.get_device_rates()
-    assert got2["s_per_lane"] == 1e-9 and got2["source"] == "probe"
-    # A failed probe is an error, never a silent fallback constant.
-    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "empty"))
-    monkeypatch.setattr(dr, "_mem_cache", {})
-    monkeypatch.setattr(dr, "_probe", lambda: (_ for _ in ()).throw(
-        RuntimeError("probe failed")))
-    with pytest.raises(RuntimeError, match="probe failed"):
-        dr.get_device_rates()
-
-
-def test_schedule_candidates_invariants():
-    """Every candidate schedule covers n exactly, never emits a chunk
-    above _RELAY_CHUNK_MAX, and never ends in a sub-floor crumb (the
-    last entry sizes OVERFLOW chunks when a longer stream reuses a
-    banded plan — an RTT-sized tail entry would drain the overflow in
-    crumbs)."""
-    from ratelimiter_tpu.storage.tpu import (
-        _RELAY_CHUNK,
-        _RELAY_CHUNK_MAX,
-        _schedule_candidates,
-    )
-
-    for n in (1 << 24, (1 << 24) + 1234, 12_582_912,
-              _RELAY_CHUNK + _RELAY_CHUNK_MAX + 300_000, 1 << 26):
-        for words_pow2 in (False, True):
-            for sched in _schedule_candidates(n, _RELAY_CHUNK, words_pow2):
-                assert sum(sched) == n, (n, words_pow2, sched)
-                assert max(sched) <= _RELAY_CHUNK_MAX, sched
-                assert sched[-1] >= _RELAY_CHUNK, (n, words_pow2, sched)
-    assert _schedule_candidates(2 * _RELAY_CHUNK, _RELAY_CHUNK,
-                                False) == []  # short streams: no plan
-
-
-def test_chunk_cursor_overflow_uses_last_entry():
-    """A stream longer than its banded plan's schedule drains the
-    overflow at the LAST entry's size (never crumbs), and peek() sizes
-    the prefetch identically to the next next_size()."""
-    from ratelimiter_tpu.storage.tpu import _ChunkCursor
-
-    plan = {"kind": "pipelined", "schedule": (100, 500, 200),
-            "chunk": 500}
-    cur = _ChunkCursor(plan, True)
-    n = 1600  # 800 scheduled + 800 overflow
-    sizes, start = [], 0
-    while start < n:
-        peek = cur.peek(n - start) if sizes else None
-        c = cur.next_size(n - start)
-        if peek is not None:
-            assert peek == c
-        sizes.append(c)
-        start += c
-    assert sizes == [100, 500, 200, 200, 200, 200, 200]
-    # Legacy int-chunk plans still honor growth.
-    cur2 = _ChunkCursor({"kind": "pipelined", "chunk": 300}, True)
-    assert cur2.next_size(10_000) == 300
-    cur2.grow(700)
-    assert cur2.next_size(10_000) == 700
+    assert not _elect_digest_mode(950_000, cn, 950_000, 10.0, 8.125, False)
+    assert _elect_digest_mode(cn // 3, cn, cn // 3, 10.0, 8.125, False)
 
 
 def test_drain_set_error_propagation_and_backpressure():
