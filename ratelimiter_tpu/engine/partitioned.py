@@ -42,6 +42,7 @@ from ratelimiter_tpu.engine.native_index import (
     hash_str_keys,
     merge_ranges,
     route_ranges,
+    sort_uniques,
 )
 
 # Requests per range of the route and merge passes: a batch is cut into
@@ -81,10 +82,13 @@ class PartitionedSlotIndex:
     def close(self) -> None:
         self._pool.shutdown(wait=False)
 
-    def last_phase_s(self) -> Optional[Tuple[float, float]]:
-        """(route, merge) seconds of this thread's last vectorized call,
-        for the stream loop's ``index_route``/``index_merge`` timers;
-        None before the first."""
+    def last_phase_s(self) -> Optional[
+            Tuple[float, float, Optional[float]]]:
+        """(route, merge, sort) seconds of this thread's last vectorized
+        call, for the stream loop's ``index_route``/``index_merge``/
+        ``index_sort`` timers; sort is the slowest partition's slot sort,
+        None unless the call handed back slot-sorted uniques.  None
+        before the first call."""
         return getattr(self._tls, "phase_s", None)
 
     # -- scalar interface ------------------------------------------------------
@@ -171,14 +175,40 @@ class PartitionedSlotIndex:
             *routing, [None if res is None else res[0] for res in results],
             base)
         clears = self._clears(results)
-        self._tls.phase_s = (route_s, time.perf_counter() - t0)
+        self._tls.phase_s = (route_s, time.perf_counter() - t0, None)
         return out, clears
 
-    def _merge_uniques(self, routed, results, rank_bits):
+    def _walk_uniques(self, lanes, hashed, pinned, run, rank_bits,
+                      hold_pins, sort_slots):
+        """The *_uniques families' walk and merge.  With ``sort_slots``
+        every partition's job slot-sorts its uniques in place (its uidx
+        remapped) right after its walk, so the merge below comes out
+        sorted by global slot."""
+        sort_s = None
+        if sort_slots:
+            sort_s = [None] * self.n_parts
+            walk = run
+
+            def run(p, *args):
+                res = walk(p, *args)
+                t0 = time.perf_counter()
+                if sort_uniques(res[0], rank_bits, res[1]):
+                    sort_s[p] = time.perf_counter() - t0
+                return res
+
+        routed, results = self._walk(
+            lanes, hashed, pinned, run,
+            self._uslots_of(rank_bits) if hold_pins else None)
+        return self._merge_uniques(routed, results, rank_bits, sort_s)
+
+    def _merge_uniques(self, routed, results, rank_bits, sort_s):
         """(uwords, uidx i32[n], rank i32[n], clears) from per-partition
         (uwords, uidx, rank, evictions): uwords concatenated
         partition-major with each partition's slot base folded into the
-        slot field, uidx offset by the uniques of the partitions before."""
+        slot field, uidx offset by the uniques of the partitions before.
+        ``sort_s`` holds each partition's sort seconds (None where it
+        did not sort); the merge is sorted when every walked partition
+        sorted."""
         t0 = time.perf_counter()
         *routing, route_s = routed
         spp = self.slots_per_part
@@ -196,7 +226,12 @@ class PartitionedSlotIndex:
             *routing, [None if res is None else res[1] for res in results],
             uoff, [None if res is None else res[2] for res in results])
         clears = self._clears(results)
-        self._tls.phase_s = (route_s, time.perf_counter() - t0)
+        sorted_s = None
+        if sort_s is not None:
+            walked = [s for s, res in zip(sort_s, results) if res is not None]
+            if None not in walked:
+                sorted_s = max(walked, default=0.0)
+        self._tls.phase_s = (route_s, time.perf_counter() - t0, sorted_s)
         return uwords, uidx, rank, clears
 
     def _collect(self, futs, unpin_of):
@@ -274,22 +309,22 @@ class PartitionedSlotIndex:
     def assign_batch_ints_uniques(self, keys: np.ndarray, lid: int,
                                   rank_bits: int,
                                   pinned: Optional[Set[int]] = None,
-                                  hold_pins: bool = False):
+                                  hold_pins: bool = False,
+                                  sort_slots: bool = False):
         keys = np.ascontiguousarray(keys, dtype=np.int64)
 
         def run(p, keys_p, pins):
             return self._parts[p].assign_batch_ints_uniques(
                 keys_p, lid, rank_bits, pinned=pins, hold_pins=hold_pins)
 
-        routed, results = self._walk(
-            (keys,), True, pinned, run,
-            self._uslots_of(rank_bits) if hold_pins else None)
-        return self._merge_uniques(routed, results, rank_bits)
+        return self._walk_uniques((keys,), True, pinned, run, rank_bits,
+                                  hold_pins, sort_slots)
 
     def assign_batch_ints_multi_uniques(self, keys: np.ndarray,
                                         lids: np.ndarray, rank_bits: int,
                                         pinned: Optional[Set[int]] = None,
-                                        hold_pins: bool = False):
+                                        hold_pins: bool = False,
+                                        sort_slots: bool = False):
         keys = np.ascontiguousarray(keys, dtype=np.int64)
         lids = np.ascontiguousarray(lids, dtype=np.uint64)
 
@@ -298,10 +333,8 @@ class PartitionedSlotIndex:
                 keys_p, lids_p, rank_bits, pinned=pins,
                 hold_pins=hold_pins)
 
-        routed, results = self._walk(
-            (keys, lids), True, pinned, run,
-            self._uslots_of(rank_bits) if hold_pins else None)
-        return self._merge_uniques(routed, results, rank_bits)
+        return self._walk_uniques((keys, lids), True, pinned, run,
+                                  rank_bits, hold_pins, sort_slots)
 
     # Strings: hash the whole window ONCE natively (fingerprints straight
     # off the interned UTF-8 buffers), route by h1 — the exact quantity
@@ -332,15 +365,15 @@ class PartitionedSlotIndex:
                                   pinned: Optional[Set[int]] = None,
                                   hold_pins: bool = False,
                                   start: int = 0,
-                                  count: int | None = None):
+                                  count: int | None = None,
+                                  sort_slots: bool = False):
         def run(p, h1, h2, pins):
             return self._parts[p].assign_batch_fps_uniques(
                 h1, h2, rank_bits, pinned=pins, hold_pins=hold_pins)
 
-        routed, results = self._walk(
-            self._fps(keys, lid, start, count), False, pinned, run,
-            self._uslots_of(rank_bits) if hold_pins else None)
-        return self._merge_uniques(routed, results, rank_bits)
+        return self._walk_uniques(self._fps(keys, lid, start, count), False,
+                                  pinned, run, rank_bits, hold_pins,
+                                  sort_slots)
 
     # -- fingerprint enumeration (checkpoint/restore) --------------------------
     def dump_fp(self):

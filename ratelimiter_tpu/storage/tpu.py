@@ -507,15 +507,16 @@ class TpuBatchedStorage(RateLimitStorage):
         # Per-stage pipeline timers (r6, unconditional since the
         # observability PR): where a stream chunk's seconds go — route
         # (shard binning), pack (string hashing), index (slot walk),
-        # sort (a digest chunk's slot sort), index_route and
-        # index_merge (the partitioned index's route and merge passes
-        # inside the walk), assign (the caller's wait for the walk and,
-        # on the relay path, the sort), layout (host dispatch prep),
-        # enqueue (device dispatch call), fetch (the blocking result
-        # read), decide (host reconstruction after the fetch),
-        # drain_wait (the caller blocked on drains).  Each is fed by the
-        # span of the same name (_span), except sort, index_route and
-        # index_merge, which have no span.
+        # sort (a digest chunk's serial slot sort), index_route,
+        # index_merge and index_sort (the partitioned index's route and
+        # merge passes and its slowest partition-local slot sort, inside
+        # the walk), assign (the caller's wait for the walk and, on the
+        # relay path, the sort), layout (host dispatch prep), enqueue
+        # (device dispatch call), fetch (the blocking result read),
+        # decide (host reconstruction after the fetch), drain_wait (the
+        # caller blocked on drains).  Each is fed by the span of the
+        # same name (_span), except sort, index_route, index_merge and
+        # index_sort, which have no span.
         self._stage_timers = None
         if self._obs:
             self._stage_timers = {
@@ -523,8 +524,8 @@ class TpuBatchedStorage(RateLimitStorage):
                     f"ratelimiter.stream.{s}",
                     f"Stream pipeline {s} stage (us per chunk)")
                 for s in ("route", "pack", "index", "sort", "index_route",
-                          "index_merge", "assign", "layout", "enqueue",
-                          "fetch", "decide", "drain_wait")}
+                          "index_merge", "index_sort", "assign", "layout",
+                          "enqueue", "fetch", "decide", "drain_wait")}
         # Reusable dispatch staging buffers shared by every stream loop.
         self._staging = _StagingPool()
         if engine is not None and table is None:
@@ -1393,18 +1394,18 @@ class TpuBatchedStorage(RateLimitStorage):
             # slots, deleting the device-side sort/scan entirely.
             rb = self.engine.rank_bits
 
-            def assign_uniques(start, chunk_n):
+            def assign_uniques(start, chunk_n, **kw):
                 chunk = key_ids[start:start + chunk_n]
                 with self._evictions_cleared(algo):
                     if multi_lid:
                         return index.assign_batch_ints_multi_uniques(
                             chunk, lid_arr[start:start + chunk_n], rb,
                             pinned=self._batcher.pending_slots(algo),
-                            hold_pins=True)
+                            hold_pins=True, **kw)
                     return index.assign_batch_ints_uniques(
                         chunk, lid, rb,
                         pinned=self._batcher.pending_slots(algo),
-                        hold_pins=True)
+                        hold_pins=True, **kw)
 
             return self._stream_relay(algo, lid, assign_uniques, len(key_ids),
                                       lid_arr if multi_lid else None)
@@ -1470,20 +1471,28 @@ class TpuBatchedStorage(RateLimitStorage):
         # (the stream_stats records carry it).
         walk_s = [0.0]
         rec_lock = threading.Lock()  # drains write rec and the recorder
+        # A partitioned index sorts each partition's uniques inside its
+        # own walk job when asked; a single index leaves the sort to
+        # walk() below, on one thread.
+        index = self._index[algo]
+        parted = hasattr(index, "last_phase_s")
 
-        def timed_assign(s0, cnt, chunk):
+        def timed_assign(s0, cnt, chunk, **kw):
+            """The walk; also whether the index slot-sorted its uniques."""
             with self._span("index", chunk) as timed:
-                r = assign_uniques(s0, cnt)
-            self._record_index_phases(self._index[algo])
+                r = assign_uniques(s0, cnt, **kw)
+            presorted = self._record_index_phases(index)
             walk_s[0] += timed.secs
-            return r
+            return r, presorted
 
         def sortable(u):
             """Whether a digest chunk of ``u`` uniques is slot-sorted:
             sorting pays off when EITHER sorted device path engages —
             the tile sweep, or (scalar-lid dispatches only) the fused
             Pallas relay step the engine elects per device
-            (ops/pallas/relay_step.py)."""
+            (ops/pallas/relay_step.py).  Given a chunk's request count,
+            the upper bound on its uniques, it says whether the chunk
+            could go to the sorted step."""
             fused_ok = (not multi_lid
                         and hasattr(eng, "_relay_fused_ok")
                         and eng._relay_fused_ok(algo, _bucket_pow2(u)))
@@ -1506,11 +1515,18 @@ class TpuBatchedStorage(RateLimitStorage):
             return done
 
         def walk(s0, cnt, chunk):
-            """The chunk's walk and, where the chunk is sure to go to
-            the sorted digest step, its slot sort: both run on whichever
+            """The chunk's walk and its slot sort, both on whichever
             thread walks, so a prefetched chunk sorts behind the device.
-            The last field says whether the uniques are sorted."""
-            uwords, uidx, rank, clears = timed_assign(s0, cnt, chunk)
+            A partitioned index sorts where the chunk could go to the
+            sorted digest step (each partition in its own job); a single
+            index sorts after its walk where the chunk is sure to.  The
+            last field says whether the uniques are sorted."""
+            if parted:
+                walked, srt = timed_assign(
+                    s0, cnt, chunk,
+                    sort_slots=cdt is not None and sortable(cnt))
+                return (*walked, srt)
+            (uwords, uidx, rank, clears), _ = timed_assign(s0, cnt, chunk)
             u = len(uwords)
             srt = (cdt is not None and sortable(u)
                    and _elect_digest_mode(u, cnt, 0, digest_bpu, words_bpr,
@@ -1623,8 +1639,8 @@ class TpuBatchedStorage(RateLimitStorage):
                             # XLA fallback the scatter is order-blind and the
                             # sort would be pure overhead.  The walk job
                             # has sorted already where it could tell.
-                            srt = presorted or (srt_ok
-                                                and sort(uwords, uidx))
+                            srt = srt_ok and (presorted
+                                              or sort(uwords, uidx))
                             size = _bucket_pow2(u)
                             uw = self._staging.take((size,), np.uint32)
                             uw[:u] = uwords
@@ -2204,12 +2220,12 @@ class TpuBatchedStorage(RateLimitStorage):
                 and self.engine.relay_usable()):
             rb = self.engine.rank_bits
 
-            def assign_uniques(start, chunk_n):
+            def assign_uniques(start, chunk_n, **kw):
                 with self._evictions_cleared(algo):
                     return index.assign_batch_strs_uniques(
                         keys, lid, rb,
                         pinned=self._batcher.pending_slots(algo),
-                        hold_pins=True, start=start, count=chunk_n)
+                        hold_pins=True, start=start, count=chunk_n, **kw)
 
             return self._stream_relay(algo, lid, assign_uniques, len(keys),
                                       key_kind="strs")
@@ -3035,16 +3051,21 @@ class TpuBatchedStorage(RateLimitStorage):
             rec.anomaly("slow_dispatch", dt_us,
                         algo=algo, batch=n, path=path, **extra)
 
-    def _record_index_phases(self, index) -> None:
-        """Feed the ``index_route`` and ``index_merge`` timers from the
-        partitioned index's last call on this thread (the thread that
-        just walked); a single index has no such passes."""
+    def _record_index_phases(self, index) -> bool:
+        """Feed the ``index_route``, ``index_merge`` and ``index_sort``
+        timers from the partitioned index's last call on this thread
+        (the thread that just walked); a single index has no such
+        passes.  Returns whether that call slot-sorted its uniques."""
         phases = getattr(index, "last_phase_s", None)
-        if phases is None or self._stage_timers is None:
-            return
-        route_s, merge_s = phases()
-        self._stage_timers["index_route"].record_us(route_s * 1e6)
-        self._stage_timers["index_merge"].record_us(merge_s * 1e6)
+        if phases is None:
+            return False
+        route_s, merge_s, sort_s = phases()
+        if self._stage_timers is not None:
+            self._stage_timers["index_route"].record_us(route_s * 1e6)
+            self._stage_timers["index_merge"].record_us(merge_s * 1e6)
+            if sort_s is not None:
+                self._stage_timers["index_sort"].record_us(sort_s * 1e6)
+        return sort_s is not None
 
     def _span(self, stage: str, chunk: int | None = None) -> _Span:
         """The span of one stream stage, ``ratelimiter.stream.<stage>``

@@ -245,7 +245,7 @@ def test_partial_failure_releases_sibling_pins():
     # Ranged: partition 1 is full of unpinned keys that a long batch of
     # fresh partition-1 keys evicts, while one fresh partition-0 key
     # fails behind two pinned slots.
-    for family in ("slots", "uniques"):
+    for family in ("slots", "uniques", "uniques-sorted"):
         ix = PartitionedSlotIndex(8, n_parts=2)  # 4 slots per partition
         for k in p0[:4]:
             ix.assign((0, int(k)), hold_pin=True)
@@ -258,11 +258,95 @@ def test_partial_failure_releases_sibling_pins():
             if family == "slots":
                 ix.assign_batch_ints(batch, lid=0, hold_pins=True)
             else:
-                ix.assign_batch_ints_uniques(batch, 0, 8, hold_pins=True)
+                ix.assign_batch_ints_uniques(
+                    batch, 0, 8, hold_pins=True,
+                    sort_slots=family == "uniques-sorted")
         # Every partition-1 slot was evicted once (global slots 4..7).
         assert sorted(err.value.pending_clears.tolist()) == [4, 5, 6, 7]
         got = {ix.assign((0, int(k)))[0] for k in p1[8:12]}
         assert got == {4, 5, 6, 7}, family  # no pin left behind
+        ix.close()
+
+
+@pytest.mark.parametrize("family", ["ints_uniques", "ints_multi_uniques",
+                                    "strs_uniques"])
+def test_sort_slots_sorts_each_partition_in_its_walk(family):
+    """``sort_slots=True``: every partition slot-sorts its uniques in its
+    own walk job, so the merged digest is strictly ascending by global
+    slot, while each request's slot, count and rank, the evictions and
+    the pins held equal the unsorted call's on a twin index fed the same
+    batches.  Some batches leave partitions empty; pins are released one
+    batch late, as the stream loop releases them, so later walks evict
+    around them."""
+    from ratelimiter_tpu.engine.native_index import hash_str_keys
+    from ratelimiter_tpu.engine.partitioned import PartitionedSlotIndex
+    from ratelimiter_tpu.parallel.sharded import shard_of_int_keys
+
+    n_parts, spp, rank_bits, lid = 4, 64, 7, 3
+    ixs = [PartitionedSlotIndex(n_parts * spp, n_parts) for _ in range(2)]
+    rng = np.random.default_rng(sum(map(ord, family)))
+    pool = np.arange(2000, dtype=np.int64)
+    if family == "strs_uniques":
+        h1, _ = hash_str_keys([f"u{k}" for k in pool], lid)
+        part = (h1 % np.uint64(n_parts)).astype(np.int64)
+    else:
+        part = shard_of_int_keys(pool, n_parts)
+    # 80 keys per partition over a 64-slot partition: later batches evict.
+    by_part = [pool[part == p][:80] for p in range(n_parts)]
+    held, n_evicted = None, 0
+    for step, parts in enumerate([(0, 2), (0, 1, 2, 3), (1,), (0, 1, 2, 3),
+                                  (2, 3), (0, 1, 2, 3)]):
+        distinct = np.concatenate([rng.choice(by_part[p], 25, replace=False)
+                                   for p in parts])
+        keys = rng.choice(distinct, 3 * len(distinct))
+        lids = (keys % 3).astype(np.uint64)  # one tenant per key
+        got = []
+        for ix, sort_slots in zip(ixs, (True, False)):
+            kw = dict(hold_pins=True, sort_slots=sort_slots)
+            if family == "ints_uniques":
+                res = ix.assign_batch_ints_uniques(keys, lid, rank_bits, **kw)
+            elif family == "ints_multi_uniques":
+                res = ix.assign_batch_ints_multi_uniques(keys, lids,
+                                                         rank_bits, **kw)
+            else:
+                res = ix.assign_batch_strs_uniques(
+                    [f"u{k}" for k in keys], lid, rank_bits, **kw)
+            got.append(res)
+            assert (ix.last_phase_s()[2] is not None) == sort_slots
+        (uw_s, uidx_s, rank_s, ev_s), (uw_u, uidx_u, rank_u, ev_u) = got
+        slots = (uw_s >> np.uint32(rank_bits + 1)).astype(np.int64)
+        assert (np.diff(slots) > 0).all(), f"step {step}"
+        np.testing.assert_array_equal(uw_s[uidx_s], uw_u[uidx_u],
+                                      err_msg=f"step {step}")
+        np.testing.assert_array_equal(rank_s, rank_u, err_msg=f"step {step}")
+        np.testing.assert_array_equal(ev_s, ev_u, err_msg=f"step {step}")
+        assert sorted(uw_s.tolist()) == sorted(uw_u.tolist())
+        n_evicted += len(ev_s)
+        if held is not None:
+            for ix in ixs:
+                ix.unpin_batch(held)
+        held = slots.astype(np.int32)
+    assert n_evicted > 0  # the walks evicted around held pins
+    # Both indexes still hold the last batch's pins on the same slots: a
+    # batch of 64 fresh keys in one partition fails on both, and once
+    # unpinned, fills the same slots on both.
+    fresh = pool[part == 0][80:80 + spp]
+    fresh_keys = ([f"u{k}" for k in fresh] if family == "strs_uniques"
+                  else fresh)
+    for ix in ixs:
+        with pytest.raises(RuntimeError):
+            if family == "strs_uniques":
+                ix.assign_batch_strs_uniques(fresh_keys, lid, rank_bits)
+            else:
+                ix.assign_batch_ints_uniques(fresh_keys, lid, rank_bits)
+        ix.unpin_batch(held)
+    filled = [ix.assign_batch_ints_uniques(fresh, lid, rank_bits)
+              if family != "strs_uniques"
+              else ix.assign_batch_strs_uniques(fresh_keys, lid, rank_bits)
+              for ix in ixs]
+    np.testing.assert_array_equal(filled[0][3], filled[1][3])
+    assert len(filled[0][0]) == spp
+    for ix in ixs:
         ix.close()
 
 
@@ -371,8 +455,8 @@ def test_ranged_walk_matches_reference_composition(family, n, skew,
                 np.asarray(g, dtype=np.int64), np.asarray(w, np.int64),
                 err_msg=f"chunk {chunk}")
         evicted.append(len(want[-1]))
-        route_s, merge_s = ix.last_phase_s()
-        assert route_s >= 0 and merge_s >= 0
+        route_s, merge_s, sort_s = ix.last_phase_s()
+        assert route_s >= 0 and merge_s >= 0 and sort_s is None
     if skew == "uniform" and n >= 100:
         assert evicted[2] > 0, evicted  # the table was small enough
     ix.close()

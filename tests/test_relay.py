@@ -670,6 +670,71 @@ def test_sorted_digest_stream_matches_unsorted(monkeypatch):
         np.testing.assert_array_equal(a, b)
 
 
+@pytest.mark.parametrize("host_parallel", [4, 0])
+def test_partitioned_sorted_digest_stream_matches_unsorted(monkeypatch,
+                                                           host_parallel):
+    """The sorted digest stream on a partitioned index: each partition
+    slot-sorts its uniques inside its own walk job, so no chunk takes the
+    serial sort (``sort`` counts 0, ``index_sort`` one per chunk, the
+    prefetched chunk's too), and the decisions equal the never-sort
+    run's and the oracle's.  A single index (``host_parallel=0``) keeps
+    the serial sort, one per chunk."""
+    import ratelimiter_tpu.storage.tpu as tpu_mod
+    from ratelimiter_tpu.engine.native_index import native_available
+    from ratelimiter_tpu.semantics import TokenBucketOracle
+    from ratelimiter_tpu.storage import TpuBatchedStorage
+
+    if not native_available():
+        pytest.skip("needs the native library")
+    rng = np.random.default_rng(8)
+    n = 1 << 15
+    # Zipf-ish duplication with > 4096 uniques per chunk.
+    ids = rng.integers(0, 12_000, n).astype(np.int64)
+    cfg = RateLimitConfig(max_permits=5, window_ms=60_000, refill_rate=1.0)
+    # Force the sorted path on CPU, as in the test above, and cut the
+    # first chunk short so the second walks on the prefetch worker.
+    monkeypatch.setattr(tpu_mod, "_presorted_scatter_usable",
+                        lambda eng, algo, padded: True)
+    monkeypatch.setattr(tpu_mod, "_RELAY_CHUNK", 1 << 13)
+    calls = 2
+
+    def run(sort_min):
+        monkeypatch.setattr(tpu_mod, "_SORT_UNIQUES_MIN", sort_min)
+        now = [1_000_000]
+        st = TpuBatchedStorage(num_slots=1 << 15,
+                               host_parallel=host_parallel,
+                               clock_ms=lambda: now[0])
+        assert st._host_parallel == host_parallel
+        lid = st.register_limiter("tb", cfg)
+        st.stream_stats = stats = []
+        outs = []
+        for _ in range(calls):
+            outs.append(st.acquire_stream_ids("tb", lid, ids, None))
+            now[0] += 700
+        meters = st.registry.meters()
+        counts = {s: meters[f"ratelimiter.stream.{s}"].count()
+                  for s in ("sort", "index_sort")}
+        st.close()
+        return outs, [r["mode"] for r in stats], counts
+
+    outs, modes, counts = run(1 << 12)  # sorting active
+    never, never_modes, never_counts = run(1 << 62)  # never sorts
+    assert len(modes) == 2 * calls  # two chunks per call
+    assert modes == ["digest-sorted"] * len(modes)
+    assert "digest-sorted" not in never_modes
+    assert never_counts == {"sort": 0, "index_sort": 0}
+    want = ({"sort": 0, "index_sort": len(modes)} if host_parallel
+            else {"sort": len(modes), "index_sort": 0})
+    assert counts == want
+    oracle = TokenBucketOracle(cfg)
+    now = 1_000_000
+    for c, (a, b) in enumerate(zip(outs, never)):
+        np.testing.assert_array_equal(a, b, err_msg=f"call {c}")
+        expect = [oracle.try_acquire(f"id:{k}", 1, now).allowed for k in ids]
+        np.testing.assert_array_equal(a, expect, err_msg=f"call {c}")
+        now += 700
+
+
 @pytest.mark.parametrize("algo", ["sw", "tb"])
 def test_uniform_chunk_sorted_digest_matches_words_mode(monkeypatch, algo):
     """A chunk of distinct keys (u/n = 1, the uniform cell's shape) with
