@@ -2,8 +2,7 @@
 
 The measured elections — ``pallas.relay_fused_live`` vs the lowered
 micro step, block-scatter vs dense one-hot, device-journal placement,
-the staged micro-step combine, and the sharded route (host vs device
-counting sort) — persist their verdicts on disk so production boots
+the staged micro-step combine — persist their verdicts on disk so production boots
 skip the probe.  Verdicts go stale: a runtime upgrade, a new BLAS, or a
 changed kernel can flip a winner, and a stale verdict silently pins the
 loser.  This sweep:
@@ -11,9 +10,7 @@ loser.  This sweep:
 1. snapshots then DELETES every persisted verdict (``pallas_elect_*``)
    in the compile cache directory (utils/compile_cache.py), so the next
    dispatch of each path re-measures;
-2. re-runs ``bench/sharded_scaling.py`` (a fresh storage per shard
-   count re-elects ``sharded.route_elect`` at runtime — that election
-   is never disk-cached);
+2. re-runs ``bench/sharded_scaling.py``;
 3. runs ``bench.py`` for a full round (its in-process dispatches
    re-elect every pallas path) and writes the
    refreshed round to ``BENCH_r06.json`` in the same shape as prior
@@ -130,10 +127,7 @@ def main() -> None:
     print(f"re-measured elections on {refreshed['platform']}: "
           f"{sorted(refreshed['verdicts'])}", file=sys.stderr)
 
-    # Fresh storages re-elect the route per boot; nothing persisted to
-    # clear for this one, the rerun IS the refresh.
-    print("re-running sharded_scaling (route re-election)...",
-          file=sys.stderr)
+    print("re-running sharded_scaling...", file=sys.stderr)
     sharded = _run([os.path.join(_REPO, "bench", "sharded_scaling.py")],
                    timeout=900, cpu=True)
     if args.skip_bench:

@@ -21,8 +21,8 @@ shard barriered on the slowest sibling's layout, the multi-device
 launch rendezvoused all devices, lanes padded to the busiest shard —
 was replaced by fully independent per-shard pipelines (storage/tpu.py
 ``_stream_relay_sharded`` + ``_ShardLane``; per-shard single-device
-dispatches via ``ShardedDeviceEngine.relay_shard_dispatch``), with
-routing electable onto the mesh (``build_route_count``).  The gate for
+dispatches via ``ShardedDeviceEngine.relay_shard_dispatch``), routed
+by the host C router.  The gate for
 this curve staying monotone is bench/perf_smoke.py in verify.sh.
 
 Invoked by bench.py in a subprocess (it must force the CPU backend before

@@ -140,20 +140,14 @@ def oracle_decisions(oracle, keys, now_ms: int) -> np.ndarray:
 
 def make_storage(devices, num_keys: int, clock):
     """A TpuBatchedStorage sized for ``num_keys``: single-device engine on
-    one chip, ShardedDeviceEngine over a mesh of ``devices`` otherwise."""
+    one chip, the program's sharded storage over ``devices`` otherwise."""
     from ratelimiter_tpu.ops.pallas.block_scatter import align_slots
-    from ratelimiter_tpu.storage import TpuBatchedStorage
+    from ratelimiter_tpu.storage import TpuBatchedStorage, sharded_storage
 
     num_slots = align_slots(int(num_keys * 1.25))
     if len(devices) == 1:
         return TpuBatchedStorage(num_slots=num_slots, clock_ms=clock)
-    from ratelimiter_tpu.engine.state import LimiterTable
-    from ratelimiter_tpu.parallel import ShardedDeviceEngine, make_mesh
-
-    engine = ShardedDeviceEngine(
-        slots_per_shard=align_slots(-(-num_slots // len(devices))),
-        table=LimiterTable(), mesh=make_mesh(list(devices)))
-    return TpuBatchedStorage(engine=engine, clock_ms=clock)
+    return sharded_storage(num_slots, devices, clock_ms=clock)
 
 
 def shard_placement(engine, devices, tables: bool = True) -> dict:

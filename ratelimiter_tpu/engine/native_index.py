@@ -430,12 +430,8 @@ def shard_route_gather(key_ids: np.ndarray, n_shards: int):
     memory pass per chunk on 1-core hosts.  None off-native (callers
     fall back to shard_route/_route_chunk + numpy gather).
 
-    Since r8 this is the HOST side of a measured routing election: the
-    on-mesh route-and-count pass (parallel/sharded.py:build_route_count,
-    bit-identical binning) is the other side, and the storage serves
-    whichever measured faster (``RATELIMITER_DEVICE_ROUTE``,
-    ARCHITECTURE §6c) — on CPU containers this C pass wins; on a real
-    slice the binning moves to the mesh."""
+    The sharded relay stream routes every int chunk with it (an on-mesh
+    route pass lost to it on a 2x2 v5e host and was deleted; PERF.md)."""
     lib = _load_library()
     if lib is None or not hasattr(lib, "rl_shard_route2"):
         return None
@@ -454,8 +450,8 @@ def shard_route_gather(key_ids: np.ndarray, n_shards: int):
 def route_hashes_gather(h1: np.ndarray, h2: np.ndarray, n_shards: int):
     """Fused fingerprint routing + gather: (shard, order, counts,
     h1_sorted, h2_sorted) in one C pass; numpy fallback bit-identical.
-    Host side of the r8 routing election for STRING streams (the
-    on-mesh pass bins by the same h1 stream — see shard_route_gather)."""
+    The sharded relay stream's route for STRING streams (by the h1
+    stream, as ``shard_of_key``'s string branch)."""
     n = len(h1)
     lib = _load_library()
     if lib is not None and hasattr(lib, "rl_route_hashes2"):

@@ -80,60 +80,6 @@ def shard_of_int_keys(key_ids, n_shards: int):
     return (x % np.uint64(n_shards)).astype(np.int64)
 
 
-def _splitmix64_device(x):
-    """The splitmix64 finalizer as device math (u64 lanes) — must stay
-    bit-identical to :func:`shard_of_int_keys` and to the C router
-    (native/slot_index.cpp:rl_shard_route*): the route-and-count pass
-    below bins by it, and host and device routing MUST agree on every
-    key's shard (tests/test_sharded.py pins the parity)."""
-    x = x.astype(jnp.uint64)
-    x = x + jnp.uint64(0x9E3779B97F4A7C15)
-    x = (x ^ (x >> jnp.uint64(30))) * jnp.uint64(0xBF58476D1CE4E5B9)
-    x = (x ^ (x >> jnp.uint64(27))) * jnp.uint64(0x94D049BB133111EB)
-    return x ^ (x >> jnp.uint64(31))
-
-
-def build_route_count(mesh, n_shards: int, int_keys: bool):
-    """shard_map route-and-count pass: bin a replicated key chunk by the
-    deterministic shard hash ON THE MESH (r8, ROADMAP item 1).
-
-    Each shard receives the whole chunk (one replicated upload — on a
-    real slice the broadcast rides ICI, where bandwidth is free relative
-    to the host), hashes it (splitmix64 for int keys; string keys arrive
-    pre-hashed as their fingerprint h1 stream, exactly what
-    ``shard_of_key``'s string branch computes), and emits
-
-    - ``counts`` i32[n_shards] — how many of the chunk's keys it owns,
-    - ``pos``   i32[n_shards, n] — the arrival-order positions of its
-      own keys, compacted left, ``-1`` padding (so the all-one-shard
-      edge case is representable: one full row, seven empty ones).
-
-    The host turns ``pos`` rows back into the exact (shard, order,
-    counts) contract of the C router (``rl_shard_route2``); parity is
-    pinned bit-for-bit by tests.  Which router serves is a measured
-    election (storage layer) — on a CPU container the host C pass wins
-    (the "device" shares its core); on a real slice the device does the
-    O(n) binning where the mesh is real.
-    """
-
-    def local_route(keys):
-        idx = jax.lax.axis_index(SHARD_AXIS).astype(jnp.int32)
-        h = (_splitmix64_device(keys) if int_keys
-             else keys.astype(jnp.uint64))
-        mine = (h % jnp.uint64(n_shards)).astype(jnp.int32) == idx
-        cnt = jnp.sum(mine.astype(jnp.int32))
-        pos = jnp.nonzero(mine, size=keys.shape[0],
-                          fill_value=-1)[0].astype(jnp.int32)
-        return cnt[None], pos[None]
-
-    return shard_map(
-        local_route,
-        mesh=mesh,
-        in_specs=(P(),),
-        out_specs=(P(SHARD_AXIS), P(SHARD_AXIS)),
-    )
-
-
 def shard_of_key(key, n_shards: int) -> int:
     """Deterministic, process-independent key -> shard hash, so a multi-host
     router and this engine always agree.  Int user keys use the vectorizable
@@ -467,7 +413,6 @@ class ShardedDeviceEngine:
         # TableArrays instance, which is rebuilt on any config change) so
         # per-shard dispatches never re-ship the table per call.
         self._table_parts: tuple = (None, {})
-        self._route_fns: dict = {}
 
         def zero_parts(lanes):
             return [
@@ -565,12 +510,18 @@ class ShardedDeviceEngine:
                 per_dev[shard] = tab
             return tab
 
+    def shard_state_shape(self, algo: str) -> tuple:
+        """``(slots_per_shard, lanes)``: one shard's packed state, the
+        table a per-shard step writes."""
+        return tuple(self._parts[algo][0].shape[1:])
+
     def _shard_relay_fn(self, algo: str, flavor: str, lids_scalar: bool,
-                        out_dtype):
+                        out_dtype, slots_sorted: bool = False):
         from ratelimiter_tpu.ops import relay as relay_ops
 
         key = ("shard_relay", algo, flavor, lids_scalar,
-               None if out_dtype is None else np.dtype(out_dtype).name)
+               None if out_dtype is None else np.dtype(out_dtype).name,
+               slots_sorted)
         fn = self._scan_fns.get(key)
         if fn is None:
             if flavor == "bits":
@@ -583,7 +534,8 @@ class ShardedDeviceEngine:
                 jdt = (jnp.uint8 if np.dtype(out_dtype) == np.uint8
                        else jnp.uint16)
                 local = functools.partial(base, rank_bits=self.rank_bits,
-                                          out_dtype=jdt)
+                                          out_dtype=jdt,
+                                          slots_sorted=slots_sorted)
 
             def stepped(state, table, words, lids, now):
                 st, out = local(state[0], table, words, lids, now)
@@ -594,14 +546,20 @@ class ShardedDeviceEngine:
         return fn
 
     def relay_shard_dispatch(self, algo: str, shard: int, flavor: str,
-                             words, lids, now_ms: int, out_dtype=None):
+                             words, lids, now_ms: int, out_dtype=None,
+                             slots_sorted: bool = False):
         """ONE shard's relay step as an independent single-device XLA
         execution on that shard's own device (r8) — the per-shard stream
         pipelines' dispatch.  ``words`` carries LOCAL slot ids in the
         same word layout as the mesh-wide relay (``rank_bits``); padding
-        is 0xFFFFFFFF.  Only this shard's lock is held: sibling shards
-        dispatch, drain and assemble concurrently.  Returns the lazy
-        per-shard handle (uint8 bits or per-unique counts)."""
+        is 0xFFFFFFFF.  ``slots_sorted`` (a counts dispatch whose
+        uniques the host sorted by local slot): the step writes its rows
+        with ``scatter_rows_presorted``, the tile sweep wherever
+        ``block_scatter`` supports the shard's table and padded uniques,
+        else XLA's row write — the one-chip digest step's rule.  Only
+        this shard's lock is held: sibling shards dispatch, drain and
+        assemble concurrently.  Returns the lazy per-shard handle (uint8
+        bits or per-unique counts)."""
         self._mark_words_shard(algo, shard, words)
         dev = self._devices[shard]
         words_dev = jax.device_put(
@@ -612,7 +570,8 @@ class ShardedDeviceEngine:
         else:
             lids_dev = jax.device_put(
                 np.ascontiguousarray(lids, dtype=np.int32), dev)
-        fn = self._shard_relay_fn(algo, flavor, lids_scalar, out_dtype)
+        fn = self._shard_relay_fn(algo, flavor, lids_scalar, out_dtype,
+                                  bool(slots_sorted))
         tab = self._table_for(shard)
         now = jnp.int64(now_ms)
         with self._shard_locks[shard]:
@@ -667,43 +626,6 @@ class ShardedDeviceEngine:
                >> np.uint64(self.rank_bits + 1)).astype(np.int64)
         base = shard * self.slots_per_shard
         j.mark(algo, np.where(loc < self.slots_per_shard, loc + base, -1))
-
-    def route_on_device(self, key_ids=None, hashes=None):
-        """(shard, order, counts) for one chunk via the on-mesh
-        route-and-count pass (:func:`build_route_count`) — the same
-        contract as the host C router, so the storage's measured route
-        election can swap them freely.  ``key_ids`` i64 int keys, or
-        ``hashes`` u64 fingerprint h1 for string traffic."""
-        int_keys = hashes is None
-        arr = np.ascontiguousarray(
-            key_ids if int_keys else hashes,
-            dtype=np.int64 if int_keys else np.uint64)
-        n = len(arr)
-        size = _bucket(n, floor=1 << 14)
-        if size != n:
-            # Padding keys bin somewhere; their positions (>= n) are
-            # dropped below.
-            arr = np.concatenate(
-                [arr, np.zeros(size - n, dtype=arr.dtype)])
-        fn = self._route_fns.get(int_keys)
-        if fn is None:
-            fn = jax.jit(build_route_count(self.mesh, self.n_shards,
-                                           int_keys))
-            self._route_fns[int_keys] = fn
-        cnt, pos = fn(jnp.asarray(arr))
-        pos = np.asarray(pos)
-        del cnt  # padded-row counts; recomputed over valid positions
-        valid = (pos >= 0) & (pos < n)
-        counts = valid.sum(axis=1).astype(np.int64)
-        order = np.empty(n, dtype=np.int64)
-        shard = np.empty(n, dtype=np.int32)
-        off = 0
-        for s in range(self.n_shards):
-            sel = pos[s][valid[s]]
-            order[off:off + len(sel)] = sel
-            shard[sel] = s
-            off += len(sel)
-        return shard, order, counts
 
     # -- dirty-slot journal hooks (per-shard replication) ----------------------
     # Same host/device split as DeviceEngine's hooks: a device journal

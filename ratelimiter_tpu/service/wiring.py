@@ -27,6 +27,7 @@ from ratelimiter_tpu.storage import (
     InMemoryStorage,
     RateLimitStorage,
     TpuBatchedStorage,
+    sharded_storage,
 )
 
 
@@ -248,24 +249,12 @@ def build_storage(props: AppProperties, meter_registry=None) -> RateLimitStorage
     if backend == "tpu":
         num_slots = props.get_int("storage.num_slots", 1 << 20)
         shard = (props.get("parallel.shard") or "auto").lower()
-        engine = None
+        devices = []
         if shard in ("auto", "true", "on"):
             import jax
 
             devices = jax.devices()
-            if len(devices) > 1 and shard != "off":
-                from ratelimiter_tpu.engine.state import LimiterTable
-                from ratelimiter_tpu.parallel import ShardedDeviceEngine, make_mesh
-
-                mesh = make_mesh(devices)
-                engine = ShardedDeviceEngine(
-                    slots_per_shard=max(num_slots // len(devices), 1),
-                    table=LimiterTable(capacity=props.get_int(
-                        "ratelimiter.table.capacity", 64)),
-                    mesh=mesh,
-                )
-        return TpuBatchedStorage(
-            num_slots=num_slots,
+        kw = dict(
             max_batch=props.get_int("batcher.max_batch", 8192),
             max_delay_ms=props.get_float("batcher.max_delay_ms", 0.5),
             max_inflight=props.get_int("batcher.max_inflight", 4),
@@ -276,7 +265,6 @@ def build_storage(props: AppProperties, meter_registry=None) -> RateLimitStorage
                                       65536),
             queue_deadline_ms=props.get_float(
                 "ratelimiter.overload.deadline_ms", 1000.0),
-            engine=engine,
             meter_registry=meter_registry,
             # Observability (ARCHITECTURE §13): 1-in-N full-trace
             # sampling + the slow-dispatch anomaly threshold.
@@ -304,10 +292,17 @@ def build_storage(props: AppProperties, meter_registry=None) -> RateLimitStorage
                 "ratelimiter.telemetry.max_clients", 1024),
             lineage_capacity=props.get_int(
                 "ratelimiter.obs.lineage_capacity", 256),
-            # Pre-sized policy table (an implicit mid-traffic grow
-            # recompiles the device step — engine/state.py:_grow).
-            table_capacity=props.get_int("ratelimiter.table.capacity", 64),
         )
+        # Pre-sized policy table (an implicit mid-traffic grow
+        # recompiles the device step — engine/state.py:_grow).
+        capacity = props.get_int("ratelimiter.table.capacity", 64)
+        if len(devices) > 1:
+            from ratelimiter_tpu.engine.state import LimiterTable
+
+            return sharded_storage(num_slots, devices,
+                                   LimiterTable(capacity=capacity), **kw)
+        return TpuBatchedStorage(num_slots=num_slots,
+                                 table_capacity=capacity, **kw)
     raise ValueError(f"unknown storage.backend: {backend!r}")
 
 

@@ -9,7 +9,7 @@ from ratelimiter_tpu.storage.errors import (
 )
 from ratelimiter_tpu.storage.memory import InMemoryStorage
 from ratelimiter_tpu.storage.retry import RetryingStorage
-from ratelimiter_tpu.storage.tpu import TpuBatchedStorage
+from ratelimiter_tpu.storage.tpu import TpuBatchedStorage, sharded_storage
 
 __all__ = [
     "CircuitBreakerStorage",
@@ -23,4 +23,5 @@ __all__ = [
     "TpuBatchedStorage",
     "RetryPolicy",
     "StorageException",
+    "sharded_storage",
 ]

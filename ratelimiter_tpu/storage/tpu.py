@@ -270,7 +270,8 @@ class _Span:
     """One stage of a stream pass, on the profiler's clock and in the
     stage's timer: a ``jax.profiler.TraceAnnotation`` named
     ``ratelimiter.stream.<stage>`` (written only while a profiler
-    session is active; ``chunk`` rides along as its metadata) around
+    session is active; ``chunk``, and on a shard lane ``shard``, ride
+    along as its metadata) around
     the interval the stage's timer records (``timer`` None: a span with
     no timer, or observability off).  ``end()`` closes it early, where
     the next stage starts inside the same block; ``t0``/``t1`` keep the
@@ -278,9 +279,14 @@ class _Span:
 
     __slots__ = ("_ann", "_timer", "t0", "t1")
 
-    def __init__(self, name: str, timer, chunk: int | None):
-        self._ann = (TraceAnnotation(name) if chunk is None
-                     else TraceAnnotation(name, chunk=chunk))
+    def __init__(self, name: str, timer, chunk: int | None,
+                 shard: int | None = None):
+        if chunk is None:
+            self._ann = TraceAnnotation(name)
+        elif shard is None:
+            self._ann = TraceAnnotation(name, chunk=chunk)
+        else:
+            self._ann = TraceAnnotation(name, chunk=chunk, shard=shard)
         self._timer = timer
         self.t1 = None
 
@@ -370,16 +376,21 @@ class _ShardLane:
       its lock);
     - ``drains`` — the shard's own bounded drain queue on its own
       fetch worker; past the in-flight bound, submit blocks THIS lane
-      only and flags saturation to the flight recorder.
+      only and flags saturation to the flight recorder;
+    - ``n_lanes`` / ``n_uniques`` — the shard's counters of requests
+      and distinct keys walked (``ratelimiter.stream.shard_lanes.<s>``,
+      ``ratelimiter.stream.shard_uniques.<s>``; None without a
+      registry), the skew a hot key puts on its shard.
 
     Chunk N+1 of shard A assembles while chunk N of shard B is still in
     flight — the inversion fix for r05's (remote link, before PR 1) sharded_scaling curve.
     """
 
     __slots__ = ("shard", "pipe", "drain_pool", "staging", "drains",
-                 "saturated")
+                 "saturated", "n_lanes", "n_uniques")
 
-    def __init__(self, shard: int, recorder=None, inflight: int | None = None):
+    def __init__(self, shard: int, recorder=None, inflight: int | None = None,
+                 registry=None):
         import concurrent.futures as cf
 
         if inflight is None:
@@ -392,6 +403,14 @@ class _ShardLane:
             1, thread_name_prefix=f"shard{shard}-drain")
         self.staging = _StagingPool(max_bytes=64 << 20)
         self.saturated = 0
+        self.n_lanes = self.n_uniques = None
+        if registry is not None:
+            self.n_lanes = registry.counter(
+                f"ratelimiter.stream.shard_lanes.{shard}",
+                f"Stream requests routed to shard {shard}")
+            self.n_uniques = registry.counter(
+                f"ratelimiter.stream.shard_uniques.{shard}",
+                f"Distinct keys per chunk summed, shard {shard}")
 
         def on_block():
             self.saturated += 1
@@ -514,9 +533,12 @@ class TpuBatchedStorage(RateLimitStorage):
         # relay path, the sort), layout (host dispatch prep), enqueue
         # (device dispatch call), fetch (the blocking result read),
         # decide (host reconstruction after the fetch), drain_wait (the
-        # caller blocked on drains).  Each is fed by the span of the
-        # same name (_span), except sort, index_route, index_merge and
-        # index_sort, which have no span.
+        # caller blocked on drains); on a sharded engine also shard (one
+        # shard lane's whole job for one chunk) and shard_straggle (per
+        # chunk with two or more shards, the slowest lane's job minus
+        # the median lane's).  Each is fed by the span of the same name
+        # (_span), except sort, index_route, index_merge, index_sort and
+        # shard_straggle, which have no span.
         self._stage_timers = None
         if self._obs:
             self._stage_timers = {
@@ -525,7 +547,8 @@ class TpuBatchedStorage(RateLimitStorage):
                     f"Stream pipeline {s} stage (us per chunk)")
                 for s in ("route", "pack", "index", "sort", "index_route",
                           "index_merge", "index_sort", "assign", "layout",
-                          "enqueue", "fetch", "decide", "drain_wait")}
+                          "enqueue", "fetch", "decide", "drain_wait",
+                          "shard", "shard_straggle")}
         # Reusable dispatch staging buffers shared by every stream loop.
         self._staging = _StagingPool()
         if engine is not None and table is None:
@@ -698,9 +721,6 @@ class TpuBatchedStorage(RateLimitStorage):
         # (key kind, algo, multi-lid, banded n): a later call starts at
         # the size the last one grew to.
         self._sharded_chunks: Dict[tuple, int] = {}
-        # Host-vs-device shard routing election (r8): None until the
-        # first large sharded chunk A/Bs both (see _route_sharded).
-        self._route_mode: str | None = None
         # Batch timestamps are clamped monotonically non-decreasing: a wall
         # clock stepping backwards (NTP) must not roll windows backwards —
         # the slot model keeps only (curr, prev) buckets, and a regressed
@@ -2405,19 +2425,18 @@ class TpuBatchedStorage(RateLimitStorage):
         """Sharded relay streaming over fully independent per-shard
         pipelines (r8; ROADMAP item 1).
 
-        Per chunk the main thread does ONE routing pass (host C router
-        or the on-mesh route-and-count pass, whichever the measured
-        election picked — :meth:`_route_sharded`) and hands each shard
-        its contiguous slice; from there everything is per-shard: slot
-        assignment, eviction clears, layout into the lane's own staging
-        buffer, a SINGLE-DEVICE dispatch on the shard's own device
-        (``ShardedDeviceEngine.relay_shard_dispatch``), and a bounded
-        per-lane drain queue.  There is no cross-shard barrier anywhere;
-        the only ordering constraint is per-shard stream order, enforced
-        by each lane's FIFO worker — which is also the clear path: a
-        shard's eviction clears enter its device stream ahead of the
-        dispatch that reuses those slots, and a key never migrates
-        shards, so nothing else needs ordering.
+        Per chunk the main thread does ONE routing pass (the host C
+        router, :meth:`_route_host`) and hands each shard its contiguous
+        slice; from there everything is per-shard: slot assignment and
+        the slot sort of its uniques, eviction clears, layout into the
+        lane's own staging buffer, a SINGLE-DEVICE dispatch on the
+        shard's own device (``ShardedDeviceEngine.relay_shard_dispatch``),
+        and a bounded per-lane drain queue.  There is no cross-shard
+        barrier anywhere; the only ordering constraint is per-shard
+        stream order, enforced by each lane's FIFO worker — which is
+        also the clear path: a shard's eviction clears enter its device
+        stream ahead of the dispatch that reuses those slots, and a key
+        never migrates shards, so nothing else needs ordering.
 
         The r6/r7 loop instead barriered every chunk into one mesh-wide
         shard_map dispatch: every shard waited for the slowest sibling's
@@ -2426,21 +2445,35 @@ class TpuBatchedStorage(RateLimitStorage):
         the result anti-scaling 19.5M -> 4.3M decisions/s from 1 -> 8
         shards on the CPU mesh.
 
-        Mode (digest vs words) is elected PER SHARD from that shard's
-        own dedup ratio; every dispatch records its route as
-        ``sharded|digest`` / ``sharded|words`` with its shard id in the
-        decision trace and latency histograms, per-shard stage seconds
-        feed the ``ratelimiter.stream.*`` timers (``route`` is the new
-        binning stage), and a lane whose drain queue blocks flags
+        The device step is the one-chip relay loop's: a shard whose
+        digest the tile sweep can write (``block_scatter.enabled`` on
+        the shard's table and padded uniques) slot-sorts its uniques in
+        its own job, right after its walk (the same C sort a partition
+        of the one-chip index runs), and dispatches the sorted step;
+        any other shard keeps XLA's row write.  Digest or words is
+        elected PER SHARD by the one-chip rule, ``_elect_digest_mode``,
+        from that shard's uniques and lanes.  Every dispatch records its
+        route as ``sharded|digest-sorted`` / ``sharded|digest`` /
+        ``sharded|words`` with its shard id in the decision trace and
+        latency histograms.  Each lane's job is the span
+        ``ratelimiter.stream.shard`` (shard and chunk as its metadata;
+        its ``index``, ``layout`` and ``enqueue`` spans nest in it);
+        per chunk with two or more shards the ``shard_straggle`` timer
+        takes the slowest job's seconds minus the median job's; the
+        per-shard counters ``shard_lanes.<s>`` and ``shard_uniques.<s>``
+        count what each lane walked; ``route`` stays the caller's
+        binning stage; and a lane whose drain queue blocks flags
         ``shard.drain_saturated`` to the flight recorder.  Decisions are
-        bit-identical to the r7 loop and to the flat single-device
-        oracle on the same per-key request order (per-key order is
-        per-shard order)."""
+        bit-identical to the single-device stream and to the oracle on
+        the same per-key request order (per-key order is per-shard
+        order)."""
         from ratelimiter_tpu.engine.native_index import (
             hash_str_keys,
             relay_decide_pos,
             rebuild_words_into,
+            sort_uniques,
         )
+        from ratelimiter_tpu.ops.pallas import block_scatter
         from ratelimiter_tpu.ops.relay import rebuild_words, wire_costs
         from ratelimiter_tpu.parallel.sharded import _bucket
 
@@ -2449,6 +2482,7 @@ class TpuBatchedStorage(RateLimitStorage):
         rb = eng.rank_bits
         cdt = eng.counts_dtype()
         digest_bpu, words_bpr = wire_costs(multi_lid)
+        shard_shape = eng.shard_state_shape(algo)
         n = len(key_ids)
         out = np.empty(n, dtype=bool)
         if n == 0:
@@ -2463,12 +2497,21 @@ class TpuBatchedStorage(RateLimitStorage):
                 errors.append((ci, s, exc))
             stop.set()
 
-        def shard_task(ci, s, start, now, keys_s, h1_s, h2_s, pos_s, l_s,
-                       pins_s, ctx):
-            """Everything one shard does for one chunk, on its lane's
-            FIFO worker.  Never raises: failures land in ``errors`` and
-            set ``stop`` (sibling lanes stop dispatching; evictions an
-            already-applied assignment made are still cleared)."""
+        def shard_task(ci, s, *args):
+            """One shard lane's job for one chunk, on the lane's FIFO
+            worker, as the ``shard`` span; its seconds feed the chunk's
+            straggle (``finalize``)."""
+            with self._span("shard", ci, s) as job:
+                shard_job(ci, s, *args)
+            ctx = args[-1]
+            ctx["job"][s] = job.secs
+
+        def shard_job(ci, s, start, now, keys_s, h1_s, h2_s, pos_s, l_s,
+                      pins_s, ctx):
+            """Everything one shard does for one chunk.  Never raises:
+            failures land in ``errors`` and set ``stop`` (sibling lanes
+            stop dispatching; evictions an already-applied assignment
+            made are still cleared)."""
             if stop.is_set():
                 return
             lane = lanes[s]
@@ -2478,7 +2521,7 @@ class TpuBatchedStorage(RateLimitStorage):
             pinned_local = None
             dispatched = False
             try:
-                with self._span("index") as walk:
+                with self._span("index", ci, s) as walk:
                     try:
                         if key_kind != "ints":
                             uw, uidx, rank, ev = sub.assign_batch_fps_uniques(
@@ -2499,21 +2542,32 @@ class TpuBatchedStorage(RateLimitStorage):
                         if len(pc):
                             self._clear_shard(algo, s, pc)
                         raise
+                    pinned_local = (uw >> np.uint32(rb + 1)).astype(
+                        np.int32)
+                    # The mode and, for a digest the sweep can write,
+                    # the slot sort, in this job right after the walk.
+                    u = len(uw)
+                    u_pad = _bucket(max(u, 1))
+                    srt_ok = (cdt is not None
+                              and u >= _SORT_UNIQUES_MIN
+                              and _sort_affordable()
+                              and block_scatter.enabled(shard_shape,
+                                                        u_pad))
+                    digest = cdt is not None and _elect_digest_mode(
+                        u, ns, 0, digest_bpu, words_bpr, srt_ok)
+                    srt = digest and srt_ok and sort_uniques(uw, rb, uidx)
                 ctx["walk"][s] = walk.secs
                 if len(ev):
                     # Stream-order clear path: this lane is a FIFO, so
                     # the clear precedes this chunk's dispatch in this
                     # shard's device stream.
                     self._clear_shard(algo, s, ev)
-                u = len(uw)
                 ctx["u"][s] = u
-                pinned_local = (uw >> np.uint32(rb + 1)).astype(np.int32)
-                with self._span("layout") as lay:
-                    digest = (cdt is not None
-                              and digest_bpu * _bucket(max(u, 1))
-                              <= words_bpr * ns)
+                if lane.n_lanes is not None:
+                    lane.n_lanes.add(ns)
+                    lane.n_uniques.add(u)
+                with self._span("layout", ci, s) as lay:
                     if digest:
-                        u_pad = _bucket(max(u, 1))
                         buf = lane.staging.take((u_pad,), np.uint32)
                         buf[:u] = uw
                         buf[u:] = 0xFFFFFFFF
@@ -2536,15 +2590,17 @@ class TpuBatchedStorage(RateLimitStorage):
                             lid_lane = np.zeros(b_pad, dtype=np.int32)
                             lid_lane[:ns] = l_s
                         ctx["wire"][s] = words_bpr * ns
-                    mode = "digest" if digest else "words"
+                    mode = (("digest-sorted" if srt else "digest")
+                            if digest else "words")
                     ctx["modes"][s] = mode
                 ctx["layout"][s] = lay.secs
                 if stop.is_set():  # a sibling failed after our assign
                     return
-                with self._span("enqueue") as enq:
+                with self._span("enqueue", ci, s) as enq:
                     if digest:
                         handle = eng.relay_shard_dispatch(
-                            algo, s, "counts", buf, lid_lane, now, cdt)
+                            algo, s, "counts", buf, lid_lane, now, cdt,
+                            slots_sorted=srt)
                     else:
                         handle = eng.relay_shard_dispatch(
                             algo, s, "bits", buf, lid_lane, now)
@@ -2566,9 +2622,9 @@ class TpuBatchedStorage(RateLimitStorage):
                       rank=rank, pos_s=pos_s, ns=ns, s=s, start=start,
                       t0=t0, ctx=ctx):
                 try:
-                    with self._span("fetch") as fetch:
+                    with self._span("fetch", ci, s) as fetch:
                         arr = np.asarray(handle)
-                    if mode == "digest":
+                    if mode != "words":
                         # Fused reconstruct + unscatter straight into the
                         # output suffix (one C pass).
                         alw = relay_decide_pos(arr[:u], uidx, rank, pos_s,
@@ -2602,12 +2658,16 @@ class TpuBatchedStorage(RateLimitStorage):
         start = 0
 
         def finalize(ctx):
-            """Join one chunk's shard tasks, fold its per-shard seconds
-            into the chunk record, and re-learn the chunk size from its
-            measured bytes/request."""
+            """Join one chunk's shard tasks, record its straggle, fold
+            its per-shard seconds into the chunk record, and re-learn
+            the chunk size from its measured bytes/request."""
             nonlocal chunk
             for f in ctx["futs"]:
                 f.result()  # tasks never raise; surfaces executor faults
+            jobs = ctx["job"][~np.isnan(ctx["job"])]
+            if len(jobs) >= 2 and self._stage_timers is not None:
+                self._stage_timers["shard_straggle"].record_us(
+                    (jobs.max() - np.median(jobs)) * 1e6)
             wire_b = float(ctx["wire"].sum())
             rec = ctx["rec"]
             if rec is not None:
@@ -2632,7 +2692,8 @@ class TpuBatchedStorage(RateLimitStorage):
                     if ctx["pack_s"]:
                         rec["pack_s"] = round(ctx["pack_s"], 6)
             if wire_b > 0 and ctx["cn"]:
-                digesty = sum(1 for m in ctx["modes"] if m == "digest")
+                digesty = sum(1 for m in ctx["modes"]
+                              if m and m != "words")
                 mody = max(sum(1 for m in ctx["modes"] if m), 1)
                 chunk = _grown_chunk(_RELAY_WIRE_BUDGET_DIGEST
                                      if 2 * digesty >= mody
@@ -2645,10 +2706,9 @@ class TpuBatchedStorage(RateLimitStorage):
                 pack_s = 0.0
                 h1st = h2st = kst = None
                 if key_kind == "ints":
-                    with self._span("route") as route:
-                        kchunk = key_ids[start:start + cn]
-                        shard, order, counts, kst = self._route_sharded(
-                            eng, kchunk=kchunk)
+                    with self._span("route", ci) as route:
+                        shard, order, counts, kst = self._route_host(
+                            key_ids[start:start + cn], None, None, n_sh)
                 else:
                     with self._span("pack") as pack:
                         fp = hash_str_keys(key_ids, lid, start, cn)
@@ -2657,9 +2717,9 @@ class TpuBatchedStorage(RateLimitStorage):
                                 "native string hashing unavailable "
                                 "mid-stream (mutated key list?)")
                     pack_s = pack.secs
-                    with self._span("route") as route:
+                    with self._span("route", ci) as route:
                         shard, order, counts, h1st, h2st = (
-                            self._route_sharded(eng, h1=fp[0], h2=fp[1]))
+                            self._route_host(None, fp[0], fp[1], n_sh))
                 route_s = route.secs
                 offs = np.zeros(n_sh + 1, dtype=np.int64)
                 np.cumsum(counts, out=offs[1:])
@@ -2671,6 +2731,7 @@ class TpuBatchedStorage(RateLimitStorage):
                     "cn": cn, "rec": rec, "lock": threading.Lock(),
                     "walk": np.zeros(n_sh), "layout": np.zeros(n_sh),
                     "enq": np.zeros(n_sh), "wire": np.zeros(n_sh),
+                    "job": np.full(n_sh, np.nan),
                     "u": np.zeros(n_sh, np.int64),
                     "modes": [None] * n_sh, "shard_n": counts,
                     "route_s": route_s, "pack_s": pack_s, "futs": [],
@@ -2715,65 +2776,13 @@ class TpuBatchedStorage(RateLimitStorage):
         self._sharded_chunks[shape_key] = chunk
         return out
 
-    def _route_sharded(self, eng, kchunk=None, h1=None, h2=None):
-        """One chunk's shard routing: ``(shard, order, counts, gathered
-        keys)`` for int traffic, ``(..., h1_sorted, h2_sorted)`` for
-        string traffic.  Host C router (``rl_shard_route2`` /
-        ``rl_route_hashes2``) vs the on-mesh route-and-count pass
-        (parallel/sharded.py:build_route_count) is a MEASURED election —
-        ``RATELIMITER_DEVICE_ROUTE=on|off|auto`` (auto A/Bs both once
-        per storage on the first large chunk and reports the verdict to
-        the flight recorder).  On a CPU container the host pass wins
-        (the "device" shares the core); on a real slice the device does
-        the O(n) binning where the mesh is real, and the losing pass
-        never serves."""
-        ints = h1 is None
-        n = len(kchunk) if ints else len(h1)
-        mode = self._route_mode
-        if mode is None:
-            env = os.environ.get("RATELIMITER_DEVICE_ROUTE",
-                                 "auto").lower()
-            if env in ("1", "on", "device"):
-                mode = self._route_mode = "device"
-            elif env in ("0", "off", "host"):
-                mode = self._route_mode = "host"
-            elif n < (1 << 16):
-                mode = "host"  # too small to measure; not sticky
-            else:
-                t0 = time.perf_counter()
-                host = self._route_host(kchunk, h1, h2, eng.n_shards)
-                host_s = time.perf_counter() - t0
-                # Warm the device pass (compile + first transfer) so the
-                # election compares steady-state costs, not a one-time
-                # jit — the service pays the compile once per geometry.
-                (eng.route_on_device(key_ids=kchunk) if ints
-                 else eng.route_on_device(hashes=h1))
-                t0 = time.perf_counter()
-                dev = (eng.route_on_device(key_ids=kchunk) if ints
-                       else eng.route_on_device(hashes=h1))
-                # Charge the device side the gather the host router
-                # fuses in (the per-shard slices need sorted keys).
-                _ = kchunk[dev[1]] if ints else h1[dev[1]]
-                dev_s = time.perf_counter() - t0
-                self._route_mode = ("device" if dev_s < host_s
-                                    else "host")
-                if self._recorder is not None:
-                    self._recorder.record(
-                        "sharded.route_elect",
-                        host_s=round(host_s, 6),
-                        device_s=round(dev_s, 6),
-                        elected=self._route_mode, n=int(n))
-                return host
-        if mode == "device":
-            if ints:
-                shard, order, counts = eng.route_on_device(key_ids=kchunk)
-                return shard, order, counts, kchunk[order]
-            shard, order, counts = eng.route_on_device(hashes=h1)
-            return shard, order, counts, h1[order], h2[order]
-        return self._route_host(kchunk, h1, h2, eng.n_shards)
-
     @staticmethod
     def _route_host(kchunk, h1, h2, n_sh):
+        """One chunk's shard routing on the host, in one C pass:
+        ``(shard, order, counts, keys in shard order)`` for int keys
+        (``kchunk``), ``(..., h1 sorted, h2 sorted)`` for string
+        fingerprints.  The on-mesh route pass lost to it on a 2x2 v5e
+        host at the stream's chunk sizes and was deleted (PERF.md)."""
         if h1 is None:
             from ratelimiter_tpu.engine.native_index import (
                 shard_route_gather,
@@ -2811,7 +2820,9 @@ class TpuBatchedStorage(RateLimitStorage):
         :class:`_ShardLane`)."""
         lanes = getattr(self, "_shard_lanes_obj", None)
         if lanes is None:
-            lanes = [_ShardLane(s, recorder=self._recorder)
+            lanes = [_ShardLane(s, recorder=self._recorder,
+                                registry=self.registry if self._obs
+                                else None)
                      for s in range(n_sh)]
             self._shard_lanes_obj = lanes
         return lanes
@@ -3067,13 +3078,14 @@ class TpuBatchedStorage(RateLimitStorage):
                 self._stage_timers["index_sort"].record_us(sort_s * 1e6)
         return sort_s is not None
 
-    def _span(self, stage: str, chunk: int | None = None) -> _Span:
+    def _span(self, stage: str, chunk: int | None = None,
+              shard: int | None = None) -> _Span:
         """The span of one stream stage, ``ratelimiter.stream.<stage>``
         (ARCHITECTURE §13a), feeding the stage's timer where it has one
         (no timer with observability off)."""
         t = self._stage_timers
         return _Span(f"ratelimiter.stream.{stage}",
-                     None if t is None else t.get(stage), chunk)
+                     None if t is None else t.get(stage), chunk, shard)
 
     def _stream_rec(self, path: str, **fields):
         """One optional per-chunk instrumentation record: appends to
@@ -3527,3 +3539,26 @@ class TpuBatchedStorage(RateLimitStorage):
                 self._serving.invalidate_slots(algo, [evicted])
             self._batcher.add_clear(algo, evicted)
         return slot
+
+
+def sharded_storage(num_slots: int, devices, table: LimiterTable | None = None,
+                    **storage_kw) -> TpuBatchedStorage:
+    """The sharded deployment's storage: a :class:`TpuBatchedStorage`
+    over a :class:`ShardedDeviceEngine` with one shard per device of
+    ``devices`` (a 1-D mesh in their order; shard ``s``'s state lives on
+    ``devices[s]``).  A table of ``num_slots`` slots in all is cut into
+    ``align_slots(ceil(num_slots / len(devices)))`` slots per shard,
+    whole tiles of the tile sweep, so every shard's digest step can take
+    the sorted row write.  ``storage_kw`` go to the storage.  The one
+    factory of a sharded storage: the service, the chip smoke and the
+    benchmark build it here; a storage built without an engine never
+    shards."""
+    from ratelimiter_tpu.ops.pallas.block_scatter import align_slots
+    from ratelimiter_tpu.parallel import ShardedDeviceEngine, make_mesh
+
+    devices = list(devices)
+    engine = ShardedDeviceEngine(
+        slots_per_shard=align_slots(-(-int(num_slots) // len(devices))),
+        table=table if table is not None else LimiterTable(),
+        mesh=make_mesh(devices))
+    return TpuBatchedStorage(engine=engine, **storage_kw)

@@ -178,57 +178,55 @@ def test_sharded_str_stream_matches_single_device():
     st_single.close()
 
 
-def test_device_route_count_matches_host_router():
-    """The on-mesh route-and-count pass (build_route_count, r8) must bin
-    bit-identically to the host router — (shard, order, counts) — for
-    int keys (splitmix64) and string fingerprints (h1), including the
-    empty-shard, all-one-shard and empty-chunk edge cases."""
+def test_host_route_matches_shard_hash():
+    """The host C router, the one route of the sharded stream, bins int
+    keys by the splitmix shard hash and string fingerprints by their h1
+    stream — exactly what ``shard_of_key`` computes for scalar traffic —
+    in arrival order within each shard, including the all-one-shard,
+    empty-shard and empty-chunk edge cases."""
     import numpy as np
 
-    from ratelimiter_tpu.engine.native_index import route_hashes_gather
+    from ratelimiter_tpu.engine.native_index import fnv_fingerprint_h1
     from ratelimiter_tpu.parallel.sharded import shard_of_int_keys
-    from ratelimiter_tpu.storage.tpu import _route_chunk
 
-    engine = ShardedDeviceEngine(slots_per_shard=32, table=LimiterTable())
-    n_sh = engine.n_shards
+    route = TpuBatchedStorage._route_host
+    n_sh = 8
     rng = np.random.default_rng(11)
 
-    # Int keys (negative ids wrap through uint64 exactly like the host).
+    def expect(shard, order, counts, want):
+        np.testing.assert_array_equal(shard, want)
+        np.testing.assert_array_equal(order,
+                                      np.argsort(want, kind="stable"))
+        np.testing.assert_array_equal(counts,
+                                      np.bincount(want, minlength=n_sh))
+
+    # Int keys (negative ids wrap through uint64 like the scalar hash).
     keys = rng.integers(-(1 << 62), 1 << 62, 4096).astype(np.int64)
-    h_shard, h_order, h_counts = _route_chunk(keys, n_sh)
-    d_shard, d_order, d_counts = engine.route_on_device(key_ids=keys)
-    np.testing.assert_array_equal(h_shard, d_shard)
-    np.testing.assert_array_equal(h_order, d_order)
-    np.testing.assert_array_equal(h_counts, d_counts)
+    shard, order, counts, kst = route(keys, None, None, n_sh)
+    expect(shard, order, counts, shard_of_int_keys(keys, n_sh))
+    np.testing.assert_array_equal(kst, keys[order])
+    for k in keys[:64].tolist():
+        assert shard_of_key(k, n_sh) == shard_of_int_keys(
+            np.asarray([k]), n_sh)[0]
 
-    # String fingerprints: route by the h1 stream, exactly as
-    # shard_of_key's string branch does.
-    h1 = rng.integers(0, 1 << 63, 2048).astype(np.uint64) * np.uint64(3)
-    h2 = rng.integers(0, 1 << 63, 2048).astype(np.uint64)
-    hs, ho, hc, h1s, h2s = route_hashes_gather(h1, h2, n_sh)
-    ds, do, dc = engine.route_on_device(hashes=h1)
-    np.testing.assert_array_equal(hs, ds)
-    np.testing.assert_array_equal(ho, do)
-    np.testing.assert_array_equal(hc, dc)
-    np.testing.assert_array_equal(h1s, h1[do])
-    np.testing.assert_array_equal(h2s, h2[do])
+    # String fingerprints route by h1, as shard_of_key's string branch.
+    names = [f"user-{i}" for i in range(512)]
+    h1 = np.asarray([fnv_fingerprint_h1(n.encode(), 0) for n in names],
+                    dtype=np.uint64)
+    h2 = rng.integers(0, 1 << 63, len(names)).astype(np.uint64)
+    shard, order, counts, h1s, h2s = route(None, h1, h2, n_sh)
+    expect(shard, order, counts,
+           np.asarray([shard_of_key((0, n), n_sh) for n in names]))
+    np.testing.assert_array_equal(h1s, h1[order])
+    np.testing.assert_array_equal(h2s, h2[order])
 
-    # All-one-shard: every key identical -> one full row, rest empty.
+    # All-one-shard, a chunk smaller than the mesh, an empty chunk.
     k1 = np.full(300, 424242, dtype=np.int64)
-    tgt = int(shard_of_int_keys(k1[:1], n_sh)[0])
-    s1, o1, c1 = engine.route_on_device(key_ids=k1)
-    assert c1[tgt] == 300 and c1.sum() == 300
-    np.testing.assert_array_equal(o1, np.arange(300))
-    np.testing.assert_array_equal(s1, np.full(300, tgt))
-
-    # Empty shards exist in a tiny chunk (n < n_shards).
+    expect(*route(k1, None, None, n_sh)[:3], shard_of_int_keys(k1, n_sh))
     k2 = np.asarray([7], dtype=np.int64)
-    s2, o2, c2 = engine.route_on_device(key_ids=k2)
+    s2, o2, c2, _ = route(k2, None, None, n_sh)
     assert c2.sum() == 1 and (c2 == 0).sum() == n_sh - 1
-
-    # Empty chunk.
-    s0, o0, c0 = engine.route_on_device(
-        key_ids=np.asarray([], dtype=np.int64))
+    s0, o0, c0, _ = route(np.asarray([], dtype=np.int64), None, None, n_sh)
     assert len(s0) == 0 and len(o0) == 0 and c0.sum() == 0
 
 
@@ -304,46 +302,43 @@ def test_sharded_pipelined_stream_matches_single_device_multichunk(
     st_single.close()
 
 
-def test_sharded_route_election_records_verdict(monkeypatch):
-    """RATELIMITER_DEVICE_ROUTE=auto must A/B the host router against
-    the on-mesh pass once, serve the winner, and report the verdict to
-    the flight recorder; forcing either side must produce identical
-    decisions."""
-    import os
-
+def test_sharded_stream_routes_every_chunk_on_host(monkeypatch):
+    """The route pass is a rule, not an election: every chunk of a
+    large sharded stream is binned once by the host router on the
+    caller (one ``route`` span per chunk, no election verdict in the
+    flight recorder), and the stream decides as the single-device one."""
     from ratelimiter_tpu.storage import tpu as tpu_mod
 
-    monkeypatch.delenv("RATELIMITER_DEVICE_ROUTE", raising=False)
+    monkeypatch.setattr(tpu_mod, "_RELAY_CHUNK", 1 << 16)
+    monkeypatch.setattr(tpu_mod, "_RELAY_CHUNK_MAX", 1 << 16)
     rng = np.random.default_rng(29)
-    ids = rng.zipf(1.2, size=70_000).astype(np.int64) % 10_000
+    ids = rng.zipf(1.2, size=200_000).astype(np.int64) % 10_000
     cfg = RateLimitConfig(max_permits=50, window_ms=60_000,
                           refill_rate=10.0)
+    calls = []
+    real = TpuBatchedStorage._route_host
 
-    def run(route_env):
-        if route_env is None:
-            monkeypatch.delenv("RATELIMITER_DEVICE_ROUTE", raising=False)
-        else:
-            monkeypatch.setenv("RATELIMITER_DEVICE_ROUTE", route_env)
-        clock = FakeClock()
-        engine = ShardedDeviceEngine(slots_per_shard=4096,
-                                     table=LimiterTable())
-        st = TpuBatchedStorage(engine=engine, clock_ms=clock)
-        lid = st.register_limiter("tb", cfg)
-        out = st.acquire_stream_ids("tb", lid, ids, None)
-        mode = st._route_mode
+    def counted(*args):
+        calls.append(len(args[0]))
+        return real(*args)
+
+    monkeypatch.setattr(TpuBatchedStorage, "_route_host",
+                        staticmethod(counted))
+    engine = ShardedDeviceEngine(slots_per_shard=4096, table=LimiterTable())
+    st = TpuBatchedStorage(engine=engine, clock_ms=FakeClock())
+    single = TpuBatchedStorage(num_slots=1 << 16, clock_ms=FakeClock())
+    try:
+        got = st.acquire_stream_ids("tb", st.register_limiter("tb", cfg),
+                                    ids, None)
+        want = single.acquire_stream_ids(
+            "tb", single.register_limiter("tb", cfg), ids, None)
+        route = st.registry.meters()["ratelimiter.stream.route"]
         events = [e for e in st._recorder.events()
                   if e.get("kind") == "sharded.route_elect"]
+    finally:
         st.close()
-        return out, mode, events
-
-    auto, auto_mode, auto_events = run(None)
-    assert auto_mode in ("host", "device")
-    assert auto_events, "election verdict missing from flight recorder"
-    assert auto_events[-1]["elected"] == auto_mode
-    assert auto_events[-1]["host_s"] > 0 and auto_events[-1]["device_s"] > 0
-
-    forced_host, host_mode, _ = run("off")
-    forced_dev, dev_mode, _ = run("on")
-    assert host_mode == "host" and dev_mode == "device"
-    np.testing.assert_array_equal(auto, forced_host)
-    np.testing.assert_array_equal(auto, forced_dev)
+        single.close()
+    np.testing.assert_array_equal(got, want)
+    assert calls == [1 << 16] * 3 + [200_000 - 3 * (1 << 16)]
+    assert route.count() == len(calls)
+    assert events == []

@@ -98,6 +98,7 @@ def test_fused_relay_compiles(compile_for, algo, lanes):
 @pytest.mark.parametrize("rows,lanes,updates", [
     (12_500_224, 6, 1 << 19),   # sw-api-10m-uniform: 0.5 GB table
     (1_250_048, 4, 1 << 18),    # tb-burst-1m-zipf
+    (312_576, 4, 1 << 16),      # tb-burst-1m-zipf.stream-4chip: a shard
 ])
 def test_block_scatter_compiles(compile_for, rows, lanes, updates):
     """The tile sweep at the stream cells' widths.  The table enters and
