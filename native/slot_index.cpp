@@ -588,6 +588,21 @@ void scatter_range(const uint8_t* part, int64_t a, int64_t b,
   }
 }
 
+// keys_sorted[j] = keys[i] and order[j] = i, with j = cursors[p]++,
+// for each request i of [a, b) in partition p = part[i].
+void scatter_order_range(const uint8_t* part, int64_t a, int64_t b,
+                         int32_t n_parts, const int64_t* cursors,
+                         const int64_t* keys, int64_t* keys_sorted,
+                         int64_t* order) {
+  int64_t cur[256];
+  for (int32_t p = 0; p < n_parts; p++) cur[p] = cursors[p];
+  for (int64_t i = a; i < b; i++) {
+    const int64_t j = cur[part[i]]++;
+    keys_sorted[j] = keys[i];
+    order[j] = i;
+  }
+}
+
 // dst0[i] = src0[p][j] + add0[p] and dst1[i] = src1[p][j] for each
 // request i of [a, b), where p = part[i] and j = cursors[p]++ is its
 // position in that partition's walk; src1/dst1 may be null.
@@ -764,37 +779,6 @@ void rl_route_hashes(const uint64_t* h1s, int64_t n, int32_t n_shards,
     acc += out_counts[s];
   }
   for (int64_t i = 0; i < n; i++) out_order[off[out_shard[i]]++] = i;
-}
-
-// Fused route + gather (r6): same as rl_shard_route but the second
-// pass also emits the keys in shard-sorted order — on the 1-core bench
-// host the separate numpy fancy-gather was a whole extra memory pass
-// per chunk.
-void rl_shard_route2(const int64_t* keys, int64_t n, int32_t n_shards,
-                     int32_t* out_shard, int64_t* out_order,
-                     int64_t* out_counts, int64_t* out_keys_sorted) {
-  for (int32_t s = 0; s < n_shards; s++) out_counts[s] = 0;
-  const uint64_t ns = static_cast<uint64_t>(n_shards);
-  for (int64_t i = 0; i < n; i++) {
-    uint64_t x = static_cast<uint64_t>(keys[i]) + 0x9E3779B97F4A7C15ULL;
-    x = (x ^ (x >> 30)) * 0xBF58476D1CE4E5B9ULL;
-    x = (x ^ (x >> 27)) * 0x94D049BB133111EBULL;
-    x = x ^ (x >> 31);
-    int32_t s = static_cast<int32_t>(x % ns);
-    out_shard[i] = s;
-    out_counts[s]++;
-  }
-  std::vector<int64_t> off(n_shards);
-  int64_t acc = 0;
-  for (int32_t s = 0; s < n_shards; s++) {
-    off[s] = acc;
-    acc += out_counts[s];
-  }
-  for (int64_t i = 0; i < n; i++) {
-    int64_t p = off[out_shard[i]]++;
-    out_order[p] = i;
-    out_keys_sorted[p] = keys[i];
-  }
 }
 
 // Fused fingerprint route + gather (string streams): shard = h1 %
@@ -1087,6 +1071,43 @@ void rl_route_ranges(const uint64_t* keys, int32_t hashed, int32_t n_parts,
       cursors[p] = offs[p] + local[static_cast<int64_t>(r) * n_parts + p];
     scatter_range(part, bounds[r], bounds[r + 1], n_parts, cursors, keys,
                   dst0, src1, dst1);
+  });
+}
+
+// The sharded relay loop's route of one chunk of int keys
+// (storage/tpu.py:_route_host), over request ranges as rl_route_ranges
+// (at most 256 shards): each range writes its requests' shards
+// (splitmix64, as rl_shard_route) into part[] and counts them; prefix
+// sums give local[r][s] and offs[0..n_shards]; then each range writes
+// its keys to keys_sorted and their own request indices to order at
+// those cursors.  Each shard's slice of both keeps arrival order, as
+// rl_shard_route's stable counting sort.
+void rl_shard_route_ranges(const int64_t* keys, int32_t n_shards,
+                           const int64_t* bounds, int32_t n_ranges,
+                           uint8_t* part, int64_t* local, int64_t* offs,
+                           int64_t* keys_sorted, int64_t* order) {
+  const uint64_t* ukeys = reinterpret_cast<const uint64_t*>(keys);
+  on_ranges(n_ranges, [&](int32_t r) {
+    route_range(ukeys, bounds[r], bounds[r + 1], n_shards, true, part,
+                local + static_cast<int64_t>(r) * n_shards);
+  });
+  std::vector<int64_t> total(n_shards, 0);
+  for (int32_t r = 0; r < n_ranges; r++) {
+    for (int32_t s = 0; s < n_shards; s++) {
+      int64_t& c = local[static_cast<int64_t>(r) * n_shards + s];
+      const int64_t count = c;
+      c = total[s];
+      total[s] += count;
+    }
+  }
+  offs[0] = 0;
+  for (int32_t s = 0; s < n_shards; s++) offs[s + 1] = offs[s] + total[s];
+  on_ranges(n_ranges, [&](int32_t r) {
+    int64_t cursors[256];
+    for (int32_t s = 0; s < n_shards; s++)
+      cursors[s] = offs[s] + local[static_cast<int64_t>(r) * n_shards + s];
+    scatter_order_range(part, bounds[r], bounds[r + 1], n_shards, cursors,
+                        keys, keys_sorted, order);
   });
 }
 

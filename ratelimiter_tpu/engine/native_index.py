@@ -199,6 +199,10 @@ def _bind(lib) -> None:
         ctypes.c_void_p, ctypes.c_int32, ctypes.c_void_p, ctypes.c_int32,
         ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
         ctypes.c_void_p, ctypes.c_void_p]
+    lib.rl_shard_route_ranges.argtypes = [
+        ctypes.c_void_p, ctypes.c_int32, ctypes.c_void_p, ctypes.c_int32,
+        ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+        ctypes.c_void_p]
     lib.rl_sort_uniques.restype = ctypes.c_int32
     lib.rl_sort_uniques.argtypes = [
         ctypes.c_void_p, ctypes.c_int64, ctypes.c_int32, ctypes.c_void_p,
@@ -228,10 +232,6 @@ def _bind(lib) -> None:
         lib.rl_route_hashes.argtypes = [
             ctypes.c_void_p, ctypes.c_int64, ctypes.c_int32,
             ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p]
-        lib.rl_shard_route2.argtypes = [
-            ctypes.c_void_p, ctypes.c_int64, ctypes.c_int32,
-            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
-            ctypes.c_void_p]
         lib.rl_route_hashes2.argtypes = [
             ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,
             ctypes.c_int32, ctypes.c_void_p, ctypes.c_void_p,
@@ -421,30 +421,6 @@ def hash_str_keys(keys, seed: int, start: int = 0,
                             offs.ctypes.data, n, seed & _U64,
                             h1.ctypes.data, h2.ctypes.data)
     return h1[:n], h2[:n]
-
-
-def shard_route_gather(key_ids: np.ndarray, n_shards: int):
-    """Fused shard routing + key gather: (shard i32[n], order i64[n],
-    counts i64[n_shards], keys_sorted i64[n]) in one C pass — the
-    separate numpy fancy-gather of the sorted keys was a whole extra
-    memory pass per chunk on 1-core hosts.  None off-native (callers
-    fall back to shard_route/_route_chunk + numpy gather).
-
-    The sharded relay stream routes every int chunk with it (an on-mesh
-    route pass lost to it on a 2x2 v5e host and was deleted; PERF.md)."""
-    lib = _load_library()
-    if lib is None or not hasattr(lib, "rl_shard_route2"):
-        return None
-    key_ids = np.ascontiguousarray(key_ids, dtype=np.int64)
-    n = len(key_ids)
-    shard = np.empty(n, dtype=np.int32)
-    order = np.empty(n, dtype=np.int64)
-    counts = np.empty(n_shards, dtype=np.int64)
-    kst = np.empty(n, dtype=np.int64)
-    lib.rl_shard_route2(key_ids.ctypes.data, n, int(n_shards),
-                        shard.ctypes.data, order.ctypes.data,
-                        counts.ctypes.data, kst.ctypes.data)
-    return shard, order, counts, kst
 
 
 def route_hashes_gather(h1: np.ndarray, h2: np.ndarray, n_shards: int):
@@ -690,6 +666,40 @@ def route_ranges(lanes, hashed: bool, n_parts: int, bounds: np.ndarray):
         lanes[1].ctypes.data if len(lanes) > 1 else None,
         copies[1].ctypes.data if len(lanes) > 1 else None)
     return part, local, offs, copies
+
+
+def shard_route_ranges(keys: np.ndarray, n_shards: int, bounds: np.ndarray,
+                       part: np.ndarray, local: np.ndarray,
+                       offs: np.ndarray, keys_sorted: np.ndarray,
+                       order: np.ndarray) -> None:
+    """Shard route of an int64 key batch over request ranges, into the
+    caller's buffers: the sharded relay loop's route of one chunk
+    (``storage/tpu.py:_route_host``).
+
+    Every key goes to the shard :func:`shard_route` gives it.  Writes
+    each request's shard (``part``, uint8[n]), each range's first
+    position in every shard (``local``, int64[ranges, n_shards]), the
+    shards' offsets (``offs``, int64[n_shards + 1]), and, shard-major
+    with each shard's slice in arrival order, the keys
+    (``keys_sorted``, int64[n]) and their request indices (``order``,
+    int64[n])."""
+    n = len(keys)
+    if not 0 < n_shards <= 256:
+        raise ValueError("1..256 shards")
+    _require(keys, np.int64)
+    _check_bounds(bounds, n)
+    _require(part, np.uint8, n)
+    _require(local, np.int64)
+    _require(offs, np.int64, n_shards + 1)
+    _require(keys_sorted, np.int64, n)
+    _require(order, np.int64, n)
+    if local.shape != (len(bounds) - 1, n_shards):
+        raise ValueError("local needs one row per range, one column per "
+                         "shard")
+    _load_library().rl_shard_route_ranges(
+        keys.ctypes.data, n_shards, bounds.ctypes.data, len(bounds) - 1,
+        part.ctypes.data, local.ctypes.data, offs.ctypes.data,
+        keys_sorted.ctypes.data, order.ctypes.data)
 
 
 def _lane_ptrs(srcs, counts) -> np.ndarray:

@@ -105,6 +105,14 @@ _SHARD_LOOKAHEAD = 2
 # Undrained dispatches a single shard lane may hold before its submit
 # blocks (and flags shard.drain_saturated to the flight recorder).
 _SHARD_DRAIN_INFLIGHT = 2
+# The sharded loop's route of a chunk runs over at most this many
+# request ranges, side by side, one per _RANGE_GRAIN requests
+# (engine/partitioned.py); the caller's lanes and chips wait for it.
+_ROUTE_RANGES = 8
+# Route buffer sets the storage keeps between calls: every set of a call
+# of up to four chunks (a sharded stream's first call, before its chunk
+# size is learned, cuts four; later calls of the same shape cut one).
+_ROUTE_SETS_KEPT = 4
 # Device step seconds the words-vs-digest rule charges a chunk the
 # sorted sweep serves (v5e, ROUND_NOTES r4): the relay words step per
 # lane, the sorted digest step per unique.
@@ -354,6 +362,74 @@ class _StagingPool:
                 return  # over budget: let the GC have it
             self._free.setdefault(key, []).append(arr)
             self._bytes += arr.nbytes
+
+
+class _RouteSet:
+    """One chunk's route buffers (:meth:`TpuBatchedStorage._route_host`),
+    each at least as long as the chunk: its keys in shard order, their
+    indices in the chunk, each key's shard, each range's first position
+    in every shard, and the shards' offsets."""
+
+    __slots__ = ("keys", "order", "part", "local", "offs")
+
+    def __init__(self, cap: int, n_sh: int):
+        self.keys = np.empty(cap, dtype=np.int64)
+        self.order = np.empty(cap, dtype=np.int64)
+        self.part = np.empty(cap, dtype=np.uint8)
+        self.local = np.empty((_ROUTE_RANGES, n_sh), dtype=np.int64)
+        self.offs = np.empty(n_sh + 1, dtype=np.int64)
+
+    def __len__(self) -> int:
+        return len(self.keys)
+
+
+class _RoutePool:
+    """The route buffer sets the sharded relay loop reuses across calls.
+
+    Routing a 2^21-id chunk into fresh arrays faults in ~34 MB of new
+    pages, so the route's cost would follow the process's page-fault and
+    allocator state (PERF.md §7.6).  ``take`` hands out the smallest free set that
+    holds the chunk, or allocates one sized to it; ``give`` takes a
+    call's sets back once nothing reads them any more (every lane's job
+    joined and every drain finished) and keeps the
+    ``_ROUTE_SETS_KEPT`` largest.  Counters
+    ``ratelimiter.stream.route_buffers.reused`` / ``.allocated`` (None
+    without a registry) count the takes of each kind."""
+
+    __slots__ = ("_n_sh", "_free", "_lock", "reused", "allocated")
+
+    def __init__(self, n_sh: int, registry=None):
+        self._n_sh = n_sh
+        self._free: list = []
+        self._lock = threading.Lock()
+        self.reused = self.allocated = None
+        if registry is not None:
+            self.reused = registry.counter(
+                "ratelimiter.stream.route_buffers.reused",
+                "Sharded stream chunks routed into a kept buffer set")
+            self.allocated = registry.counter(
+                "ratelimiter.stream.route_buffers.allocated",
+                "Sharded stream chunks routed into a new buffer set")
+
+    def take(self, n: int) -> _RouteSet:
+        with self._lock:
+            fits = [b for b in self._free if len(b) >= n]
+            best = min(fits, key=len) if fits else None
+            if best is not None:
+                self._free.remove(best)
+        if best is not None:
+            if self.reused is not None:
+                self.reused.add(1)
+            return best
+        if self.allocated is not None:
+            self.allocated.add(1)
+        return _RouteSet(n, self._n_sh)
+
+    def give(self, sets) -> None:
+        with self._lock:
+            self._free.extend(sets)
+            self._free.sort(key=len, reverse=True)
+            del self._free[_ROUTE_SETS_KEPT:]
 
 
 class _ShardLane:
@@ -2426,12 +2502,15 @@ class TpuBatchedStorage(RateLimitStorage):
         pipelines (r8; ROADMAP item 1).
 
         Per chunk the main thread does ONE routing pass (the host C
-        router, :meth:`_route_host`) and hands each shard its contiguous
-        slice; from there everything is per-shard: slot assignment and
-        the slot sort of its uniques, eviction clears, layout into the
-        lane's own staging buffer, a SINGLE-DEVICE dispatch on the
-        shard's own device (``ShardedDeviceEngine.relay_shard_dispatch``),
-        and a bounded per-lane drain queue.  There is no cross-shard
+        router, :meth:`_route_host`, in parallel request ranges into a
+        buffer set of the storage's :class:`_RoutePool`, given back when
+        the call's drains have finished) and hands each shard its
+        contiguous slice; from there everything is per-shard: slot
+        assignment and the slot sort of its uniques, eviction clears,
+        layout into the lane's own staging buffer, a SINGLE-DEVICE
+        dispatch on the shard's own device
+        (``ShardedDeviceEngine.relay_shard_dispatch``), and a bounded
+        per-lane drain queue.  There is no cross-shard
         barrier anywhere; the only ordering constraint is per-shard
         stream order, enforced by each lane's FIFO worker — which is
         also the clear path: a shard's eviction clears enter its device
@@ -2488,6 +2567,8 @@ class TpuBatchedStorage(RateLimitStorage):
         if n == 0:
             return out
         lanes = self._shard_lanes(n_sh)
+        route_pool = self._route_pool(n_sh)
+        held: list = []  # this call's route sets, given back at its end
         stop = threading.Event()
         errors: list = []  # (chunk_i, shard, exc); first in stream order wins
         err_lock = threading.Lock()
@@ -2707,8 +2788,11 @@ class TpuBatchedStorage(RateLimitStorage):
                 h1st = h2st = kst = None
                 if key_kind == "ints":
                     with self._span("route", ci) as route:
-                        shard, order, counts, kst = self._route_host(
-                            key_ids[start:start + cn], None, None, n_sh)
+                        bufs = route_pool.take(cn)
+                        held.append(bufs)
+                        order, counts, kst = self._route_host(
+                            key_ids[start:start + cn], None, None, n_sh,
+                            bufs)
                 else:
                     with self._span("pack") as pack:
                         fp = hash_str_keys(key_ids, lid, start, cn)
@@ -2718,8 +2802,8 @@ class TpuBatchedStorage(RateLimitStorage):
                                 "mid-stream (mutated key list?)")
                     pack_s = pack.secs
                     with self._span("route", ci) as route:
-                        shard, order, counts, h1st, h2st = (
-                            self._route_host(None, fp[0], fp[1], n_sh))
+                        order, counts, h1st, h2st = self._route_host(
+                            None, fp[0], fp[1], n_sh)
                 route_s = route.secs
                 offs = np.zeros(n_sh + 1, dtype=np.int64)
                 np.cumsum(counts, out=offs[1:])
@@ -2736,6 +2820,7 @@ class TpuBatchedStorage(RateLimitStorage):
                     "modes": [None] * n_sh, "shard_n": counts,
                     "route_s": route_s, "pack_s": pack_s, "futs": [],
                 }
+                inflight.append(ctx)  # before submitting: joined on error
                 for s in range(n_sh):
                     lo, hi = int(offs[s]), int(offs[s + 1])
                     if lo == hi:
@@ -2749,7 +2834,6 @@ class TpuBatchedStorage(RateLimitStorage):
                         pos_s,
                         l_chunk[pos_s] if multi_lid else None,
                         pins.get(s), ctx))
-                inflight.append(ctx)
                 start += cn
                 ci += 1
                 # Bounded look-ahead: route at most _SHARD_LOOKAHEAD
@@ -2770,6 +2854,9 @@ class TpuBatchedStorage(RateLimitStorage):
                     pass
             for lane in lanes:
                 lane.drains.finish(swallow=True)  # no-op when healthy
+            # Every job that read a set's keys is joined and every drain
+            # that read its order has finished.
+            route_pool.give(held)
         if errors:
             errors.sort(key=lambda e: (e[0], e[1]))
             raise errors[0][2]
@@ -2777,25 +2864,45 @@ class TpuBatchedStorage(RateLimitStorage):
         return out
 
     @staticmethod
-    def _route_host(kchunk, h1, h2, n_sh):
-        """One chunk's shard routing on the host, in one C pass:
-        ``(shard, order, counts, keys in shard order)`` for int keys
-        (``kchunk``), ``(..., h1 sorted, h2 sorted)`` for string
-        fingerprints.  The on-mesh route pass lost to it on a 2x2 v5e
-        host at the stream's chunk sizes and was deleted (PERF.md)."""
-        if h1 is None:
-            from ratelimiter_tpu.engine.native_index import (
-                shard_route_gather,
-            )
+    def _route_host(kchunk, h1, h2, n_sh, bufs=None):
+        """One chunk's shard routing on the host: ``(order, counts, keys
+        in shard order)`` for int keys (``kchunk``), ``(order, counts, h1
+        sorted, h2 sorted)`` for string fingerprints; ``order`` holds
+        each shard's request indices in arrival order, shard-major.
 
-            r2 = shard_route_gather(kchunk, n_sh)
-            if r2 is not None:  # fused route+gather, one C pass
-                return r2
-            shard, order, counts = _route_chunk(kchunk, n_sh)
-            return shard, order, counts, kchunk[order]
-        from ratelimiter_tpu.engine.native_index import route_hashes_gather
+        Int keys route in parallel request ranges (at most
+        ``_ROUTE_RANGES``, one per ``_RANGE_GRAIN`` keys) into ``bufs``,
+        a :class:`_RouteSet` at least as long as the chunk (None: a new
+        one), and the outputs are views of it; without the native
+        library, numpy routes them.  The on-mesh route pass lost to the
+        host on a 2x2 v5e host at the stream's chunk sizes and was
+        deleted (PERF.md)."""
+        from ratelimiter_tpu.engine.native_index import (
+            native_available,
+            route_hashes_gather,
+            shard_route_ranges,
+        )
 
-        return route_hashes_gather(h1, h2, n_sh)
+        if h1 is not None:
+            _, order, counts, h1s, h2s = route_hashes_gather(h1, h2, n_sh)
+            return order, counts, h1s, h2s
+        from ratelimiter_tpu.engine.partitioned import _RANGE_GRAIN
+
+        kchunk = np.ascontiguousarray(kchunk, dtype=np.int64)
+        n = len(kchunk)
+        if bufs is None:
+            bufs = _RouteSet(n, n_sh)
+        order, kst = bufs.order[:n], bufs.keys[:n]
+        if n_sh <= 256 and native_available():  # a uint8 shard lane
+            n_ranges = max(1, min(_ROUTE_RANGES, -(-n // _RANGE_GRAIN)))
+            bounds = np.arange(n_ranges + 1, dtype=np.int64) * n // n_ranges
+            shard_route_ranges(kchunk, n_sh, bounds, bufs.part[:n],
+                               bufs.local[:n_ranges], bufs.offs, kst, order)
+            return order, np.diff(bufs.offs), kst
+        _, o, counts = _route_chunk(kchunk, n_sh)
+        order[:] = o
+        np.take(kchunk, o, out=kst)
+        return order, counts, kst
 
     def _clear_shard(self, algo: str, s: int, local_slots) -> None:
         """Per-shard eviction clears (r8): zero LOCAL slots in shard
@@ -2814,6 +2921,16 @@ class TpuBatchedStorage(RateLimitStorage):
             self.engine.clear_shard(algo, s, local_slots)
             base = s * self.engine.slots_per_shard
             known[np.asarray(local_slots, dtype=np.int64) + base] = False
+
+    def _route_pool(self, n_sh: int) -> _RoutePool:
+        """The sharded loop's route buffer pool (lazily created; see
+        :class:`_RoutePool`)."""
+        pool = getattr(self, "_route_pool_obj", None)
+        if pool is None:
+            pool = _RoutePool(n_sh, registry=self.registry if self._obs
+                              else None)
+            self._route_pool_obj = pool
+        return pool
 
     def _shard_lanes(self, n_sh: int):
         """The per-shard pipeline lanes (lazily created; see

@@ -468,9 +468,8 @@ def test_rebuild_words_into_matches_numpy():
 
 
 def test_shard_route_matches_numpy_reference():
-    """rl_shard_route / rl_shard_route2 vs the numpy reference
-    (splitmix hash + stable argsort): identical shard ids, order,
-    counts — and the fused gather emits exactly keys[order]."""
+    """rl_shard_route vs the numpy reference (splitmix hash + stable
+    argsort): identical shard ids, order, counts."""
     import ratelimiter_tpu.engine.native_index as ni
     from ratelimiter_tpu.parallel.sharded import shard_of_int_keys
 
@@ -485,12 +484,46 @@ def test_shard_route_matches_numpy_reference():
         np.testing.assert_array_equal(r[0], want_shard)
         np.testing.assert_array_equal(r[1], want_order)
         np.testing.assert_array_equal(r[2], want_counts)
-        r2 = ni.shard_route_gather(keys, n_shards)
-        assert r2 is not None
-        np.testing.assert_array_equal(r2[0], want_shard)
-        np.testing.assert_array_equal(r2[1], want_order)
-        np.testing.assert_array_equal(r2[2], want_counts)
-        np.testing.assert_array_equal(r2[3], keys[want_order])
+
+
+@pytest.mark.parametrize("n", [0, 2, 70_000])
+@pytest.mark.parametrize("n_ranges", [1, 3, 8])
+@pytest.mark.parametrize("n_shards", [1, 2, 3, 4, 8])
+def test_shard_route_ranges_matches_numpy_reference(n_shards, n_ranges, n):
+    """The sharded loop's ranged route into reused buffers vs the numpy
+    reference (shard_of_int_keys + stable argsort + keys[order] +
+    bincount), for power-of-two and other shard counts, batches shorter
+    than the range count (empty ranges) and empty ones: the same shard
+    per key, each shard's keys and request indices in arrival order,
+    the same counts, and each range's first position in every shard.
+    Stale buffer contents and a longer buffer than the batch change
+    nothing."""
+    import ratelimiter_tpu.engine.native_index as ni
+    from ratelimiter_tpu.parallel.sharded import shard_of_int_keys
+
+    rng = np.random.default_rng(1000 * n_shards + 10 * n_ranges + n % 7)
+    keys = rng.integers(-(1 << 62), 1 << 62, size=n)
+    want_shard = shard_of_int_keys(keys, n_shards)
+    want_order = np.argsort(want_shard, kind="stable")
+    want_counts = np.bincount(want_shard, minlength=n_shards)
+    bounds = np.arange(n_ranges + 1, dtype=np.int64) * n // n_ranges
+    cap = n + 5
+    part = np.full(cap, 0xAB, dtype=np.uint8)
+    local = np.full((n_ranges, n_shards), -7, dtype=np.int64)
+    offs = np.full(n_shards + 1, -7, dtype=np.int64)
+    kst = np.full(cap, -7, dtype=np.int64)
+    order = np.full(cap, -7, dtype=np.int64)
+    ni.shard_route_ranges(keys, n_shards, bounds, part[:n], local, offs,
+                          kst[:n], order[:n])
+    np.testing.assert_array_equal(part[:n], want_shard)
+    np.testing.assert_array_equal(order[:n], want_order)
+    np.testing.assert_array_equal(kst[:n], keys[want_order])
+    np.testing.assert_array_equal(np.diff(offs), want_counts)
+    assert offs[0] == 0
+    for r in range(n_ranges):
+        np.testing.assert_array_equal(local[r], np.bincount(
+            want_shard[:bounds[r]], minlength=n_shards))
+    assert (kst[n:] == -7).all() and (order[n:] == -7).all()
 
 
 def test_route_hashes_gather_matches_numpy():

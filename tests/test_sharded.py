@@ -9,6 +9,7 @@
 import random
 
 import numpy as np
+import pytest
 
 import jax
 
@@ -125,7 +126,6 @@ def test_native_shard_route_matches_numpy():
     """The C routing pass must be bit-identical to shard_of_int_keys +
     stable argsort (scalar and stream paths must agree on shards)."""
     import numpy as np
-    import pytest
 
     from ratelimiter_tpu.engine.native_index import shard_route
     from ratelimiter_tpu.parallel.sharded import shard_of_int_keys
@@ -193,8 +193,9 @@ def test_host_route_matches_shard_hash():
     n_sh = 8
     rng = np.random.default_rng(11)
 
-    def expect(shard, order, counts, want):
-        np.testing.assert_array_equal(shard, want)
+    def expect(order, counts, want):
+        # The stable order and the counts place every request in its
+        # shard's slice, in arrival order.
         np.testing.assert_array_equal(order,
                                       np.argsort(want, kind="stable"))
         np.testing.assert_array_equal(counts,
@@ -202,32 +203,48 @@ def test_host_route_matches_shard_hash():
 
     # Int keys (negative ids wrap through uint64 like the scalar hash).
     keys = rng.integers(-(1 << 62), 1 << 62, 4096).astype(np.int64)
-    shard, order, counts, kst = route(keys, None, None, n_sh)
-    expect(shard, order, counts, shard_of_int_keys(keys, n_sh))
+    order, counts, kst = route(keys, None, None, n_sh)
+    expect(order, counts, shard_of_int_keys(keys, n_sh))
     np.testing.assert_array_equal(kst, keys[order])
     for k in keys[:64].tolist():
         assert shard_of_key(k, n_sh) == shard_of_int_keys(
             np.asarray([k]), n_sh)[0]
+
+    # Into a longer, used buffer set: the outputs are views of it, and
+    # the numpy route (no native library) writes the same.
+    from ratelimiter_tpu.engine import native_index
+    from ratelimiter_tpu.storage.tpu import _RouteSet
+
+    bufs = _RouteSet(5000, n_sh)
+    route(keys[::-1].copy(), None, None, n_sh, bufs)
+    for native in (True, False):
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(native_index, "native_available", lambda: native)
+            order, counts, kst = route(keys, None, None, n_sh, bufs)
+        assert np.shares_memory(order, bufs.order)
+        assert np.shares_memory(kst, bufs.keys)
+        expect(order, counts, shard_of_int_keys(keys, n_sh))
+        np.testing.assert_array_equal(kst, keys[order])
 
     # String fingerprints route by h1, as shard_of_key's string branch.
     names = [f"user-{i}" for i in range(512)]
     h1 = np.asarray([fnv_fingerprint_h1(n.encode(), 0) for n in names],
                     dtype=np.uint64)
     h2 = rng.integers(0, 1 << 63, len(names)).astype(np.uint64)
-    shard, order, counts, h1s, h2s = route(None, h1, h2, n_sh)
-    expect(shard, order, counts,
+    order, counts, h1s, h2s = route(None, h1, h2, n_sh)
+    expect(order, counts,
            np.asarray([shard_of_key((0, n), n_sh) for n in names]))
     np.testing.assert_array_equal(h1s, h1[order])
     np.testing.assert_array_equal(h2s, h2[order])
 
     # All-one-shard, a chunk smaller than the mesh, an empty chunk.
     k1 = np.full(300, 424242, dtype=np.int64)
-    expect(*route(k1, None, None, n_sh)[:3], shard_of_int_keys(k1, n_sh))
+    expect(*route(k1, None, None, n_sh)[:2], shard_of_int_keys(k1, n_sh))
     k2 = np.asarray([7], dtype=np.int64)
-    s2, o2, c2, _ = route(k2, None, None, n_sh)
+    o2, c2, _ = route(k2, None, None, n_sh)
     assert c2.sum() == 1 and (c2 == 0).sum() == n_sh - 1
-    s0, o0, c0, _ = route(np.asarray([], dtype=np.int64), None, None, n_sh)
-    assert len(s0) == 0 and len(o0) == 0 and c0.sum() == 0
+    o0, c0, _ = route(np.asarray([], dtype=np.int64), None, None, n_sh)
+    assert len(o0) == 0 and c0.sum() == 0
 
 
 def test_sharded_stream_pipelining_invariant_under_concurrency(monkeypatch):

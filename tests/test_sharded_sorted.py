@@ -225,3 +225,84 @@ def test_single_device_stream_has_no_shard_lanes():
                                  "ratelimiter.stream.shard_straggle"}
     assert all(m.count() == 0 for m in shard_meters.values())
     assert meters["ratelimiter.stream.index"].count() > 0
+
+
+def _route_buffers(meters):
+    return tuple(meters[f"ratelimiter.stream.route_buffers.{k}"].count()
+                 for k in ("reused", "allocated"))
+
+
+def test_sharded_route_buffers_reused_across_calls(monkeypatch):
+    """Consecutive calls on one sharded storage route into buffer sets
+    kept from earlier calls: a call of four chunks (two in flight
+    beyond the oldest) gives its four sets back and the next call takes
+    all four again; a call whose chunks outgrow every kept set
+    allocates new ones.  Every call decides as the single-device
+    storage on the same ids."""
+    monkeypatch.setattr(tpu_mod, "_RELAY_CHUNK", 4096)
+    monkeypatch.setattr(tpu_mod, "_RELAY_CHUNK_MAX", 4096)
+    now = [T0]
+    sharded = sharded_storage(5000, _devices(), clock_ms=lambda: now[0])
+    single = TpuBatchedStorage(num_slots=1 << 13, clock_ms=lambda: now[0])
+    lid_a = sharded.register_limiter("tb", CFG)
+    lid_b = single.register_limiter("tb", CFG)
+    calls = _zipf_calls(31, 2, 1 << 14) + _zipf_calls(32, 1, 1 << 15)
+    seen = []
+    try:
+        for c, ids in enumerate(calls):
+            if c == 2:  # larger chunks than any kept set holds
+                monkeypatch.setattr(tpu_mod, "_RELAY_CHUNK", 8192)
+                monkeypatch.setattr(tpu_mod, "_RELAY_CHUNK_MAX", 8192)
+            got = sharded.acquire_stream_ids("tb", lid_a, ids, None)
+            want = single.acquire_stream_ids("tb", lid_b, ids, None)
+            np.testing.assert_array_equal(got, want, err_msg=f"call {c}")
+            seen.append(_route_buffers(sharded.registry.meters()))
+            now[0] += 100
+    finally:
+        sharded.close()
+        single.close()
+    assert seen[0] == (0, 4)
+    assert seen[1] == (4, 4)
+    assert seen[2][0] >= 4 and seen[2][1] > 4
+    assert sum(seen[2]) == 4 + 4 + 4  # one take per chunk
+
+
+def test_sharded_route_buffers_given_back_after_a_lane_fails(monkeypatch):
+    """A call whose shard dispatch fails still gives back every route
+    set it took, once its lanes and drains are done; the next call
+    takes them again and decides as a fresh single-device storage on
+    keys the failed call never touched."""
+    monkeypatch.setattr(tpu_mod, "_RELAY_CHUNK", 4096)
+    monkeypatch.setattr(tpu_mod, "_RELAY_CHUNK_MAX", 4096)
+    now = [T0]
+    sharded = sharded_storage(20000, _devices(), clock_ms=lambda: now[0])
+    single = TpuBatchedStorage(num_slots=1 << 13, clock_ms=lambda: now[0])
+    lid_a = sharded.register_limiter("tb", CFG)
+    lid_b = single.register_limiter("tb", CFG)
+    eng = sharded.engine
+    real = eng.relay_shard_dispatch
+    n_calls = [0]
+
+    def fail_second(*args, **kw):
+        n_calls[0] += 1
+        if n_calls[0] == 2:
+            raise RuntimeError("injected sharded dispatch")
+        return real(*args, **kw)
+
+    monkeypatch.setattr(eng, "relay_shard_dispatch", fail_second)
+    first, second = _zipf_calls(33, 2, 1 << 14)
+    second = second + KEYS  # keys the failed call never touched
+    try:
+        with pytest.raises(RuntimeError, match="injected sharded dispatch"):
+            sharded.acquire_stream_ids("tb", lid_a, first, None)
+        reused, taken = _route_buffers(sharded.registry.meters())
+        assert reused == 0 and taken >= 1
+        now[0] += 100
+        got = sharded.acquire_stream_ids("tb", lid_a, second, None)
+        want = single.acquire_stream_ids("tb", lid_b, second, None)
+        after = _route_buffers(sharded.registry.meters())
+    finally:
+        sharded.close()
+        single.close()
+    np.testing.assert_array_equal(got, want)
+    assert after == (min(taken, 4), taken + 4 - min(taken, 4))
